@@ -59,14 +59,11 @@ PTC006 GC token conservation: with refill and idle-bucket GC events in
 GC semantics (the bucket-lifecycle layer, ROADMAP item 4): a clean
 ``gc`` event models the engine's reclaim-with-tombstone — the node may
 collect the bucket only when its local view is FULL (tokens == limit:
-the IsZero predicate), and the collection drops every OTHER replica's
-lane copy (recoverable from its writer via the join) while the node's
-OWN lane survives (the engine's directory tombstone, re-seeded at
-re-creation). Takes mirror the kernel's over-capacity forfeit
-(bucket.go:211-213 / ops/take.py): dropping a peer's lane copy can
-push the local view past capacity, and the next take forfeits the
-excess into its own taken lane — without the clamp even correct GC
-would over-admit. The two seeded lifecycle mutations:
+the IsZero predicate) and it holds no nonzero lane of another replica
+(an acknowledged remote write its writer will not ship again), and the
+node's OWN lane survives (the engine's directory tombstone, re-seeded
+at re-creation). Takes mirror the kernel's over-capacity forfeit
+(bucket.go:211-213 / ops/take.py). The two seeded lifecycle mutations:
 ``gc-drops-admitted-tokens`` collects the OWN lane too (the naive
 zero-everything reclaim — a stale peer echo then absorbs post-reclaim
 spend and the conservation bound breaks), and
@@ -325,9 +322,9 @@ class Node:
 
     def gc(self, sem: Semantics) -> bool:
         """One idle-bucket reclaim attempt under ``sem.gc`` law. Clean
-        ("iszero"): collect only when the local view is full, dropping
-        every OTHER lane copy (recoverable from its writer via the join)
-        and keeping the OWN lane (the engine's tombstone re-seed).
+        ("iszero"): collect only when the local view is full and every
+        OTHER lane is zero, keeping the OWN lane (the engine's tombstone
+        re-seed).
         "always": collect regardless and drop the own lane too (naive).
         "deaf": clean collect, then ignore the bucket's incoming state.
         """
@@ -339,10 +336,10 @@ class Node:
             return True
         if tokens < self.limit:
             return False  # IsZero predicate: not reconstructible yet
-        for s in range(self.n):
-            if s != self.slot:
-                self.added[s] = 0
-                self.taken[s] = 0
+        if any(
+            self.added[s] or self.taken[s] for s in range(self.n) if s != self.slot
+        ):
+            return False  # remote lanes are acknowledged writes: keep them
         if sem.gc == "deaf":
             self.deaf = True
         return True

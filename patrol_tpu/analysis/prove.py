@@ -124,8 +124,12 @@ TRANSPARENT_PRIMS = {
 }
 
 # Call-like primitives whose sub-jaxpr maps invars/outvars 1:1 — recurse
-# with the taint mapped through.
-_CALL_PRIMS = {"pjit", "closed_call", "core_call", "custom_jvp_call",
+# with the taint mapped through. JAX 0.9 names a nested jit "jit"; left
+# unrecognized it fell to the conservative control-flow branch, which
+# taints every operand — a gather's row indices included, so the inlined
+# negative-index normalization (lt/add/select_n on the rows) read as
+# arithmetic on a merged plane.
+_CALL_PRIMS = {"jit", "closed_call", "core_call", "custom_jvp_call",
                "custom_vjp_call", "custom_vjp_call_jaxpr", "remat"}
 
 # Host round-trips / side channels: never allowed in a kernel root,
@@ -1462,9 +1466,6 @@ def _model_pallas_interpret(
     from patrol_tpu.ops.merge import MergeBatch, merge_batch
 
     import jax.numpy as jnp
-
-    if not pallas_merge.available():  # pragma: no cover - env without pallas
-        return []
 
     findings: List[Finding] = []
     cfg = LimiterConfig(buckets=pallas_merge.ROWS_PER_BLOCK, nodes=2)

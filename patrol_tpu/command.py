@@ -174,13 +174,12 @@ class Command:
             n = ckpt.restore(self.checkpoint_dir, engine)
             log.info("checkpoint restored", extra={"buckets": n, "dir": self.checkpoint_dir})
 
+        warmup_s = None
         if self.warmup:
             t0 = asyncio.get_running_loop().time()
             await asyncio.get_running_loop().run_in_executor(None, engine.warmup)
-            log.info(
-                "kernels warmed",
-                extra={"seconds": round(asyncio.get_running_loop().time() - t0, 2)},
-            )
+            warmup_s = asyncio.get_running_loop().time() - t0
+            log.info("kernels warmed", extra={"seconds": round(warmup_s, 2)})
         log.debug(
             "peers",
             extra={
@@ -199,6 +198,10 @@ class Command:
                 "engine_evictions": engine.evictions,
                 "engine_scalar_dropped": engine.scalar_dropped,
                 "engine_pending_completions": engine.pending_completions,
+                # Work not yet applied: queued rows, and a drained tick
+                # still compiling or dispatching.
+                "engine_backlog": engine.backlog(),
+                "engine_tick_busy": engine.tick_busy,
                 "engine_hosted_buckets": engine.hosted_buckets,
                 "engine_host_takes": engine.host_takes,
                 "engine_promotions": engine.promotions,
@@ -221,6 +224,9 @@ class Command:
                 # stage) — the /debug/vars view; /metrics exposes the
                 # full cumulative-bucket form of the same histograms.
                 "histograms": hist_mod.HISTOGRAMS.snapshot(),
+                # The backend, the selected kernels, device memory and
+                # the boot's warm-up time (None = warm-up off).
+                "device": {**engine.device_vars(), "warmup_s": warmup_s},
             }
 
         api = API(repo, log=log, stats=stats)

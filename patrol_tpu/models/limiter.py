@@ -81,10 +81,14 @@ SMALL = LimiterConfig(buckets=1024, nodes=8)
 def init_state(config: LimiterConfig, device=None) -> LimiterState:
     """Zero state: every bucket empty, which reads as full-at-capacity on
     first take (value = capacity + 0 − 0), matching the reference's lazy
-    capacity init (bucket.go:194-196)."""
-    pn = jnp.zeros((config.buckets, config.nodes, 2), dtype=jnp.int64)
-    elapsed = jnp.zeros((config.buckets,), dtype=jnp.int64)
-    state = LimiterState(pn=pn, elapsed=elapsed)
-    if device is not None:
-        state = jax.device_put(state, device)
-    return state
+    capacity init (bucket.go:194-196).
+
+    ``device`` is a device or sharding for both planes, or a
+    ``LimiterState`` of one per plane. The zeros are made where they
+    live: a sharded state never passes through one device (at 4M x 256
+    the whole state is 16.4 GB, more than one v5e holds)."""
+    per_plane = device if isinstance(device, LimiterState) else LimiterState(device, device)
+    return LimiterState(
+        pn=jnp.zeros((config.buckets, config.nodes, 2), jnp.int64, device=per_plane.pn),
+        elapsed=jnp.zeros((config.buckets,), jnp.int64, device=per_plane.elapsed),
+    )

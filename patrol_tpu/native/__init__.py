@@ -1,14 +1,20 @@
 """ctypes bindings for the native host network path (patrol_host.cpp).
 
-Builds ``libpatrolhost.so`` with g++ on first use (cached beside the
-source; no pybind11 in this environment — plain C ABI + ctypes + numpy).
-Falls back gracefully: :func:`load` returns None when no compiler is
-available, and callers use the pure-Python asyncio path instead.
+Builds ``libpatrolhost-<hash>.so`` with g++ on first use, beside the
+sources (no pybind11 in this environment — plain C ABI + ctypes + numpy).
+The name carries a hash of the sources' content and the build command, so
+a checkout never loads a binary its sources no longer describe; the
+binaries are build outputs, never committed. :func:`load` returns None
+when no compiler is available; ``auto`` callers then take the pure-Python
+path, and an explicit ``native`` request fails.
 """
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import fcntl
+import hashlib
 import logging
 import os
 import subprocess
@@ -163,7 +169,19 @@ _SRC_HTTP = os.path.join(_HERE, "patrol_http.cpp")
 # ASan-instrumented build under LD_PRELOAD=libasan without dirtying the
 # packaged .so.
 _LIB_OVERRIDE = os.environ.get("PATROL_NATIVE_LIB")
-_LIB = _LIB_OVERRIDE or os.path.join(_HERE, "libpatrolhost.so")
+_BUILD = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread"]
+
+
+def _content_lib() -> str:
+    """The build's output path, named after what it is built from."""
+    h = hashlib.sha256(" ".join(_BUILD).encode())
+    for src in (_SRC, _SRC_HTTP):
+        with open(src, "rb") as f:
+            h.update(f.read())
+    return os.path.join(_HERE, f"libpatrolhost-{h.hexdigest()[:16]}.so")
+
+
+_LIB = _LIB_OVERRIDE or _content_lib()
 
 _mu = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -179,26 +197,35 @@ _i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
 
 
 def _build() -> bool:
+    if os.path.exists(_LIB):
+        return True
     if _LIB_OVERRIDE:
         # Caller supplied the binary (possibly sanitizer-instrumented);
         # never overwrite it with a plain build.
-        return os.path.exists(_LIB)
-    srcs = [_SRC, _SRC_HTTP]
-    if os.path.exists(_LIB) and all(
-        os.path.getmtime(_LIB) >= os.path.getmtime(s) for s in srcs
-    ):
-        return True
-    try:
-        subprocess.run(
-            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
-             "-o", _LIB, *srcs],
-            check=True,
-            capture_output=True,
-            timeout=120,
-        )
-        return True
-    except (subprocess.SubprocessError, FileNotFoundError) as exc:
-        log.warning("native build failed, using pure-python path: %s", exc)
+        return False
+    # Processes starting together (test workers, a server and its
+    # driver) build once: the first holds the lock, the rest find the
+    # finished library; a rename makes it appear whole or not at all.
+    with open(os.path.join(_HERE, ".build.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if os.path.exists(_LIB):
+            return True
+        tmp = f"{_LIB}.{os.getpid()}.tmp"
+        try:
+            subprocess.run(
+                [*_BUILD, "-o", tmp, _SRC, _SRC_HTTP],
+                check=True,
+                capture_output=True,
+                timeout=300,
+            )
+            os.replace(tmp, _LIB)
+            return True
+        except subprocess.CalledProcessError as exc:
+            log.error("native build failed: %s", exc.stderr.decode(errors="replace")[-2000:])
+        except (subprocess.SubprocessError, FileNotFoundError) as exc:
+            log.error("native build failed: %s", exc)
+        with contextlib.suppress(FileNotFoundError):
+            os.remove(tmp)
         return False
 
 

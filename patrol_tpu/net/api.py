@@ -260,7 +260,10 @@ class API:
         same-bucket entries into one take-n row (runtime/engine.py).
         Per-entry fields ride the query value, ','-separated, so the
         request needs no body (both fronts drain but ignore bodies, like
-        /take); names percent-encode ',' and '&'. rate/count parse
+        /take). Entries are concurrent takes: a device-resident bucket's
+        same-(rate, count) entries fold into one take-n row, so entries
+        interleaving counts on one bucket apply grouped by count, in
+        order of each count's first entry; names percent-encode ',' and '&'. rate/count parse
         exactly like /take: malformed rate ⇒ zero Rate (unconditional
         429), bad/zero count ⇒ 1 (api.go:60-65). The response status is
         200 whenever the batch parsed — per-entry outcomes live in the

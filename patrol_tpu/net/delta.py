@@ -549,6 +549,7 @@ class DeltaPlane:
             )
         max_slots = self.rep.slots.max_slots
         data_live = False
+        received = []  # (peer state, interval seq, entries): acked below
         with self._mu:
             for i in range(len(lengths)):
                 if lengths[i] <= 0:
@@ -564,14 +565,12 @@ class DeltaPlane:
                     st.unacked.pop(int(walk.acks[i, k]), None)
                 if n_acks and tr.enabled:
                     tr.record(trace_mod.EV_DELTA_ACK, 0, n_acks)
-                if walk.seq[i]:
-                    st.pending_acks.append(int(walk.seq[i]))
                 cnt = int(walk.count[i])
+                received.append((st, int(walk.seq[i]), cnt))
                 if cnt:
                     st.last_rx_data_ns = time.perf_counter_ns()
                     data_live = True
                 self.rx_packets += 1
-                self.rx_deltas += cnt
                 # Python-path parity for the per-entry error counter:
                 # out-of-range slots and control-channel names are
                 # counted (and never folded — the engine's entry filter
@@ -593,6 +592,18 @@ class DeltaPlane:
             hist.RX_APPLY.record(time.perf_counter_ns() - t0_ns)
         elif release is not None:
             release()
+        self._acknowledge(received)
+
+    def _acknowledge(self, received) -> None:
+        """Ack and count intervals once the engine has taken them in: an
+        ack must never run ahead of the ingest it promises (the writer
+        stops re-shipping on it), and a settled ``rx_deltas`` then means
+        every counted entry is queued or applied."""
+        with self._mu:
+            for st, seq, cnt in received:
+                if seq:
+                    st.pending_acks.append(seq)
+                self.rx_deltas += cnt
 
     def on_packet(self, data: bytes, addr: Addr) -> bool:
         """Decode + ingest one delta datagram. False ⇒ malformed (counted;
@@ -624,17 +635,20 @@ class DeltaPlane:
                 tr = trace_mod.TRACE
                 if tr.enabled:
                     tr.record(trace_mod.EV_DELTA_ACK, 0, len(pkt.acks))
-            if pkt.seq:
-                st.pending_acks.append(pkt.seq)
             if pkt.entries:
                 st.last_rx_data_ns = time.perf_counter_ns()
             self.rx_packets += 1
-            self.rx_deltas += len(pkt.entries)
         # Acking needs a pacing tick even on nodes that ship no deltas.
         self.start()
         repo = getattr(self.rep, "repo", None)
-        if repo is None or not pkt.entries:
-            return True
+        if repo is not None and pkt.entries:
+            self._ingest_entries(repo, pkt, t0)
+        self._acknowledge([(st, pkt.seq, len(pkt.entries))])
+        return True
+
+    def _ingest_entries(self, repo, pkt, t0: int) -> None:
+        """Hand a decoded interval's entries to the engine (bad slots and
+        control names counted as rx errors, never ingested)."""
         max_slots = self.rep.slots.max_slots
         names: List[str] = []
         slots: List[int] = []
@@ -655,7 +669,6 @@ class DeltaPlane:
         if names:
             repo.engine.ingest_interval(names, slots, caps, added, taken, elapsed)
             hist.RX_APPLY.record(time.perf_counter_ns() - t0)
-        return True
 
     # -- observability -------------------------------------------------------
 

@@ -172,7 +172,8 @@ class NativeReplicator:
         if RAW_INGEST:
             try:
                 self._rx_ring = native.RxRing(
-                    n_planes=4, max_batch=512, row=native.RX_RING_ROW
+                    n_planes=4, max_batch=ingest_ops.RAW_MAX_PLANES,
+                    row=native.RX_RING_ROW,
                 )
             except (OSError, RuntimeError):  # pragma: no cover - no lib
                 self._rx_ring = None

@@ -42,10 +42,10 @@ Kernel forms, same pattern as ops/pallas_merge.py:
   ``lax.scan`` over entry ordinals + one scatter-max). The production
   path on every backend today.
 * :func:`decode_fold_raw_pallas` — the Pallas twin sharing the same
-  decode core inside a ``pallas_call`` (interpret-capable on CPU; a
-  compile probe gates the native path, which current Mosaic rejects —
-  byte-granular gathers and scalar VMEM stores are not lowerable, the
-  same verdict BENCH_r02 pinned for the scatter-merge kernel).
+  decode core inside a ``pallas_call``, run in interpret mode only: the
+  engine never dispatches it, since Mosaic cannot lower byte-granular
+  gathers and scalar VMEM stores (the verdict BENCH_r02 pinned for the
+  scatter-merge kernel).
 
 Algebra: the fold leg is the identical lattice join as
 ``ops/delta.delta_fold`` (elementwise int64 max, ``mode="drop"``
@@ -74,6 +74,10 @@ from patrol_tpu.ops.merge import FOLD_PAD_ROW
 # Framing constants, mirrored from ops/wire.py (the codec is the spec;
 # these are the offsets its struct layout implies).
 RAW_PLANE_BYTES = wire.DELTA_PACKET_SIZE  # 8192: the rx ring row width
+# The rx ring's recvmmsg batch bound: a raw dispatch pads its plane count
+# to a power of two up to this, so 1..RAW_MAX_PLANES are the shapes the
+# engine's warm-up compiles.
+RAW_MAX_PLANES = 512
 _BASE = 32  # envelope: 25-byte v1 header + 7-byte reserved name
 _HEAD = 8  # version u8 | sender_slot u16 | seq u32 | n_acks u8
 _ACK = 4
@@ -527,21 +531,13 @@ decode_fold_raw_jit = partial(jax.jit, donate_argnums=0)(decode_fold_raw)
 
 
 # ---------------------------------------------------------------------------
-# Pallas twin — same decode core inside a pallas_call (interpret-capable
-# on CPU; the native probe gates compiled use, and current Mosaic rejects
-# byte-granular gathers the same way it rejected the scatter-merge
-# kernel's scalar VMEM stores, BENCH_r02/pallas_merge.py notes).
+# Pallas twin — same decode core inside a pallas_call, run in interpret
+# mode only (the engine dispatches the XLA form): Mosaic rejects
+# byte-granular gathers the same way it rejects the scatter-merge kernel
+# (pallas_merge.py notes). Kept as the differential check on the shared
+# decode core.
 
-try:
-    from jax.experimental import pallas as pl  # noqa: F401
-
-    _PALLAS_OK = True
-except Exception:  # pragma: no cover - env without pallas
-    _PALLAS_OK = False
-
-
-def available() -> bool:
-    return _PALLAS_OK
+from jax.experimental import pallas as pl  # noqa: E402,F401
 
 
 def decode_fold_raw_pallas(
@@ -559,8 +555,6 @@ def decode_fold_raw_pallas(
     :func:`_decode_fold_core` verbatim so the differential sweep pinning
     it against the XLA path is a check on the pallas_call plumbing, not
     a second decoder implementation to drift."""
-    if not _PALLAS_OK:  # pragma: no cover - env without pallas
-        raise RuntimeError("pallas unavailable")
     P, E = rows.shape
 
     def kernel(
@@ -599,46 +593,3 @@ def decode_fold_raw_pallas(
         interpret=interpret,
     )(planes, lengths, entry_off, rows, hosted, state.pn, state.elapsed)
     return (LimiterState(pn=outs[0], elapsed=outs[1]), *outs[2:])
-
-
-_native_probe: "bool | None" = None
-
-
-def native_available() -> bool:
-    """Compiled (non-interpret) Pallas path usable on this backend,
-    proven by a one-time tiny probe — same honesty contract as
-    pallas_merge.native_available: interpret mode exists everywhere but
-    is slower than the XLA path, so only a real accelerator lowering
-    counts, and only if Mosaic actually accepts the kernel."""
-    global _native_probe
-    if not _PALLAS_OK:
-        return False
-    try:
-        if jax.default_backend() in ("cpu",):
-            return False
-    except Exception:  # pragma: no cover - backend init failure
-        return False
-    if _native_probe is None:
-        try:
-            from patrol_tpu.models.limiter import LimiterConfig, init_state
-
-            st = init_state(LimiterConfig(buckets=8, nodes=2))
-            planes = jnp.zeros((1, 128), jnp.uint8)
-            e = max_entries(128)
-            decode_fold_raw_pallas(
-                st, planes, jnp.zeros(1, jnp.int32),
-                jnp.zeros((1, e), jnp.int32),
-                jnp.zeros((1, e), jnp.int32),
-                jnp.zeros((1, e), jnp.bool_),
-                interpret=False,
-            )[0].pn.block_until_ready()
-            _native_probe = True
-        except Exception as exc:  # pragma: no cover - backend-specific
-            import logging
-
-            logging.getLogger("patrol.ingest").warning(
-                "pallas decode_fold_raw rejected by backend, using XLA: %s",
-                str(exc).splitlines()[0] if str(exc) else type(exc).__name__,
-            )
-            _native_probe = False
-    return _native_probe

@@ -9,10 +9,9 @@ lazily re-creating it later is observation-equivalent: the very first
 take against the fresh row sees the same ``have``/``admitted``/
 ``remaining`` the old row would have produced, because the old row's
 entire history is subsumed by "full at capacity". Cold state can
-therefore be *dropped*, not archived, and the dropped state re-enters
-through the existing max-lattice join when peers still hold copies
-(delta-mutation CRDTs, arXiv:1410.2803: zero lanes are the join's bottom
-element, so re-entry is exact by construction).
+therefore be *dropped*, not archived (zero lanes are the join's bottom
+element, delta-mutation CRDTs arXiv:1410.2803, so later state re-enters
+exactly through the max-lattice join).
 
 The refill arithmetic below mirrors :func:`patrol_tpu.ops.take.take_batch`
 **step for step** (float64 grant, floor quantization, capacity clamp):
@@ -25,11 +24,13 @@ this kernel's ``PROVE_ROOTS`` entry (patrol_tpu/ops/obligations.py).
 
 What the engine keeps when it reclaims: the bucket's OWN PN lane (and
 its refill clock: ``elapsed``/``created``) goes into a compact host-side
-tombstone (runtime/directory.py) and re-seeds the row on re-creation —
-the own lane is the one join-decomposition only this node can
-regenerate, while every other lane is recoverable from its writer via
-the normal join. The probe therefore returns the own-lane values next to
-the verdict so the sweep reads each candidate exactly once.
+tombstone (runtime/directory.py) and re-seeds the row on re-creation.
+A row that holds any nonzero REMOTE lane is never reclaimable: those
+lanes are acknowledged writes of other nodes, which their writers do
+not ship again, and a partial join can read full (a lane with positive
+balance landed before the lanes that spend it) — dropping it would lose
+the spend for good. The probe therefore returns the own-lane values
+next to the verdict so the sweep reads each candidate exactly once.
 """
 
 from __future__ import annotations
@@ -42,7 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from patrol_tpu.models.limiter import ADDED, TAKEN, NANO, LimiterState
-from patrol_tpu.ops.take import _GRANT_CLIP
+from patrol_tpu.ops.take import _GRANT_CLIP, refill_grant_nt
 
 
 class LifecycleProbe(NamedTuple):
@@ -79,9 +80,9 @@ def lifecycle_probe(
     reconstruction-equivalent to a fresh full bucket too). A zero or
     unknown rate projects no grant, so such rows reclaim only when the
     standing balance already covers capacity — conservative, never a
-    token lost.
+    token lost. A row with a nonzero remote lane is never full (module
+    docstring).
     """
-    i64 = jnp.int64
     rows = probe.rows
 
     pn_rows = state.pn[rows]  # [K, N, 2] gather
@@ -98,12 +99,12 @@ def lifecycle_probe(
     interval = probe.per_ns // safe_freq
     rate_zero = (freq == 0) | (probe.per_ns == 0) | (interval == 0)
     safe_interval = jnp.where(interval == 0, 1, interval)
-    grant_tokens = delta.astype(jnp.float64) / safe_interval.astype(jnp.float64)
-    grant_f = jnp.where(rate_zero, 0.0, grant_tokens * float(NANO))
-    grant_nt = jnp.floor(jnp.clip(grant_f, 0.0, _GRANT_CLIP)).astype(i64)
+    grant_nt = jnp.where(rate_zero, 0, refill_grant_nt(delta, safe_interval))
 
     missing_nt = probe.cap_base_nt - tokens_nt
-    full = (probe.cap_base_nt > 0) & (grant_nt >= missing_nt)
+    lane = jnp.arange(pn_rows.shape[1])
+    remote = jnp.any((pn_rows != 0).any(axis=-1) & (lane != node_slot), axis=-1)
+    full = (probe.cap_base_nt > 0) & (grant_nt >= missing_nt) & ~remote
     return LifecycleView(
         full=full,
         own_added_nt=pn_rows[:, node_slot, ADDED],
@@ -127,11 +128,14 @@ def host_lifecycle_full(
     created_ns,
     now_ns,
     per_ns,
+    remote=False,
 ) -> np.ndarray:
     """Numpy reference twin of the kernel's verdict, for host-resident
     lanes (the fast-path buckets GC evaluates under ``_host_mu`` without
     a device hop) and for tests. Same expressions, same operation order —
-    any divergence from the kernel is a bug the lifecycle tests pin."""
+    any divergence from the kernel is a bug the lifecycle tests pin.
+    ``remote``: the row holds a nonzero lane of another node (never
+    full)."""
     sum_added_nt = np.asarray(sum_added_nt, np.int64)
     sum_taken_nt = np.asarray(sum_taken_nt, np.int64)
     elapsed_ns = np.asarray(elapsed_ns, np.int64)
@@ -155,7 +159,7 @@ def host_lifecycle_full(
     )
     grant_nt = np.floor(np.clip(grant_f, 0.0, _GRANT_CLIP)).astype(np.int64)
     missing_nt = cap_base_nt - tokens_nt
-    return (cap_base_nt > 0) & (grant_nt >= missing_nt)
+    return (cap_base_nt > 0) & (grant_nt >= missing_nt) & ~np.asarray(remote)
 
 
 def host_reconstructed_nt(

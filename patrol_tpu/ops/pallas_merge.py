@@ -29,8 +29,10 @@ Safety notes baked into the host-side preparation (:func:`prepare`):
 
 Verified against the XLA scatter path in interpret mode (tests) and usable
 on CPU the same way; selected on TPU via PATROL_MERGE_KERNEL=auto|pallas
-— behind a compile probe (:func:`native_available`). On the current
-jax 0.9.0 / v5e Mosaic the probe fails and the engine stays on the XLA
+— behind a compile of the engine's real state shape
+(:func:`compile_error`). On the installed jax 0.9.0 Mosaic refuses it for
+v5e ("cannot statically prove that index in dimension 0 is a multiple of
+1024"), so ``pallas`` fails at engine start and ``auto`` stays on the XLA
 scatter, which r3 measured honestly at ~130-215 ns per scatter *update*
 regardless of window size (scripts/probe_scatter.py). The full r3 kernel
 exploration, so the next Mosaic bump can be retried with data:
@@ -169,13 +171,8 @@ def _kernel(
     )
 
 
-try:  # pallas is TPU/CPU-interpret capable; degrade gracefully elsewhere
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _PALLAS_OK = True
-except Exception:  # pragma: no cover
-    _PALLAS_OK = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def prepare(
@@ -315,60 +312,38 @@ def merge_batch_pallas(
     )
 
 
-def available() -> bool:
-    """Pallas importable (interpret-mode capable on CPU — tests use this)."""
-    return _PALLAS_OK
+def compile_error(state: LimiterState) -> "str | None":
+    """Compile the native (non-interpret) kernel for ``state``'s exact
+    shape, dtype and placement, without running it: ``None`` when the
+    backend's compiler accepts it, else the compiler's message.
 
+    The engine asks this once, at start, for its real state — a probe at
+    some other shape proves nothing about the one the engine serves
+    (Mosaic's tiling checks depend on it). Interpret mode exists on CPU
+    but is orders of magnitude slower than the XLA scatter, so a CPU
+    backend never counts."""
+    if jax.default_backend() == "cpu":
+        return "the cpu backend has no native pallas lowering"
 
-_native_probe: "bool | None" = None
+    def spec(shape, dtype, like=None):
+        sharding = getattr(like, "sharding", None)
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-
-def native_available() -> bool:
-    """Pallas compiled path usable on the current backend, proven by a
-    one-time tiny compile probe (cached).
-
-    Interpret mode exists on CPU but is orders of magnitude slower than
-    the XLA scatter, so only an accelerator backend counts — and an
-    accelerator only counts if Mosaic actually accepts the kernel: real
-    v5e rejects the per-delta scalar VMEM read-modify-writes ("Cannot
-    store scalars to VMEM", BENCH_r02), so without the probe an explicit
-    PATROL_MERGE_KERNEL=pallas would crash the engine tick. Measured
-    verdict on hardware (bench.py pallas-compare, r2): the XLA scatter
-    merges K=131072 in ~20-40µs — at or under one engine tick — so the
-    scatter path stays the TPU default and this kernel is selected only
-    where a future Mosaic accepts it AND the batch is block-sparse."""
-    global _native_probe
-    if not _PALLAS_OK:
-        return False
+    i32 = jnp.int32
+    args = (
+        LimiterState(
+            pn=spec(state.pn.shape, state.pn.dtype, state.pn),
+            elapsed=spec(state.elapsed.shape, state.elapsed.dtype, state.elapsed),
+        ),
+        spec((1,), i32), spec((1,), i32), spec((1,), i32),
+        spec((1,), i32), spec((1,), i32),
+        spec((1, 2), i32), spec((1, 2), i32), spec((1, 2), i32),
+    )
     try:
-        if jax.default_backend() in ("cpu",):
-            return False
-    except Exception:  # pragma: no cover - backend init failure
-        return False
-    if _native_probe is None:
-        try:
-            probe = LimiterState(
-                pn=jnp.zeros((ROWS_PER_BLOCK, 8, 2), jnp.int64),
-                elapsed=jnp.zeros((ROWS_PER_BLOCK,), jnp.int64),
-            )
-            merge_batch_pallas(
-                probe,
-                np.zeros(1, np.int64),
-                np.zeros(1, np.int64),
-                np.ones(1, np.int64),
-                np.zeros(1, np.int64),
-                np.zeros(1, np.int64),
-            ).pn.block_until_ready()
-            _native_probe = True
-        except Exception as exc:
-            import logging
-
-            logging.getLogger("patrol.pallas").warning(
-                "pallas merge kernel rejected by backend, using XLA scatter: %s",
-                str(exc).splitlines()[0] if str(exc) else type(exc).__name__,
-            )
-            _native_probe = False
-    return _native_probe
+        _merge_pallas_device.lower(*args).compile()
+    except Exception as exc:  # the compiler's refusal, verbatim
+        return f"{type(exc).__name__}: {exc}"
+    return None
 
 
 # auto-mode knobs (PATROL_MERGE_KERNEL=auto): pallas wins when the batch is
@@ -383,11 +358,12 @@ AUTO_BLOCK_FRAC = float(_os.environ.get("PATROL_PALLAS_BLOCK_FRAC", "0.25"))
 
 
 def auto_pick(rows: np.ndarray, num_buckets: int) -> bool:
-    """The PATROL_MERGE_KERNEL=auto heuristic (docstring contract): use the
-    pallas block-sparse kernel iff it can run natively, the batch is big
-    enough to amortize launch, and it touches a small fraction of the
-    state's 512-row blocks."""
-    if len(rows) < AUTO_MIN_BATCH or not native_available():
+    """The PATROL_MERGE_KERNEL=auto per-batch heuristic, asked only once
+    :func:`compile_error` has accepted the kernel for the engine's state:
+    use the pallas block-sparse kernel iff the batch is big enough to
+    amortize launch and touches a small fraction of the state's 512-row
+    blocks."""
+    if len(rows) < AUTO_MIN_BATCH:
         return False
     touched = len(np.unique(np.asarray(rows) // ROWS_PER_BLOCK))
     total = max(1, (num_buckets + ROWS_PER_BLOCK - 1) // ROWS_PER_BLOCK)

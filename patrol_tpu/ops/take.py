@@ -12,14 +12,18 @@ running the reference's sequential takes at the same timestamp, where only
 the first take refills (delta becomes 0 for the rest).
 
 Fixed-point arithmetic notes: state is int64 nanotokens; the refill grant is
-computed in float64 exactly as the reference does (``float64(d) /
-float64(interval)``, bucket.go:130-143) then floor-quantized to nanotokens,
-so host oracle and device kernel agree bit-for-bit on CPU and to float64
-precision on TPU.
+the reference's float64 expression (``float64(d) / float64(interval)``,
+bucket.go:130-143, times 1e9, floored) evaluated by :func:`refill_grant_nt`
+in int64 operations that round exactly as IEEE-754 does. The TPU has no
+float64 unit: XLA's emulation differed from the host in 995 of 4096 seeded
+grid rows on a v5e (chip_smoke.py's refill phase), and buckets move between
+the host tiers (``HostLanes``, the C++ store) and device rows, so the two
+must agree bit for bit on every backend.
 """
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import NamedTuple
 
@@ -32,6 +36,93 @@ from patrol_tpu.models.limiter import ADDED, TAKEN, NANO, LimiterState
 # cast defined; any realistic grant is far below (and the capacity cap is
 # applied after, in exact int64).
 _GRANT_CLIP = float(2**62)
+_MANT = 53  # float64 significand bits, the implicit one included
+
+
+def host_refill_grant_nt(delta: int, freq: int, per_ns: int) -> int:
+    """The host twin of :func:`refill_grant_nt` (and of the C++ store's
+    ``hls_take_locked``): the reference's float64 refill in nanotokens,
+    before the capacity clamp. ``interval`` is the truncating per/freq."""
+    interval = per_ns // freq if freq else 0
+    if freq == 0 or per_ns == 0 or interval == 0:
+        return 0
+    grant_f = (float(delta) / float(interval)) * float(NANO)
+    return int(math.floor(min(max(grant_f, 0.0), _GRANT_CLIP)))
+
+
+def _bitlen(x: jax.Array) -> jax.Array:
+    """Significant bits of int64 ``x >= 0`` (0 for 0), by binary search."""
+    n = jnp.zeros_like(x)
+    for sh in (32, 16, 8, 4, 2, 1):
+        t = x >> sh
+        nz = t != 0
+        x = jnp.where(nz, t, x)
+        n = n + jnp.where(nz, sh, 0)
+    return n + (x != 0)
+
+
+def _round_even(m, low, half, sticky, e):
+    """Round significand ``m`` (discarded bits ``low`` against ``half``,
+    plus a sticky bit) to nearest, ties to even; renormalize a carry out
+    of 53 bits. → (m in [2^52, 2^53), e)."""
+    up = (low > half) | ((low == half) & (sticky | ((m & 1) == 1)))
+    m = m + up
+    carry = m == (1 << _MANT)
+    return jnp.where(carry, 1 << (_MANT - 1), m), e + carry
+
+
+def _to_double(x: jax.Array):
+    """int64 ``x > 0`` as the nearest float64 ``m · 2^e``, m normalized."""
+    n = _bitlen(x)
+    up = jnp.maximum(_MANT - n, 0)
+    dn = jnp.maximum(n - _MANT, 0)  # 0..10 bits fall off above 2^53
+    m = (x << up) >> dn
+    low = x & ((jnp.int64(1) << dn) - 1)
+    half = jnp.where(dn > 0, jnp.int64(1) << jnp.maximum(dn - 1, 0), 1)
+    return _round_even(m, low, half, False, n - _MANT)
+
+
+@jax.jit  # one executable for eager callers; inlined under an outer jit
+def refill_grant_nt(delta: jax.Array, interval: jax.Array) -> jax.Array:
+    """``floor(clip((float64(delta) / float64(interval)) · 1e9, 0, 2^62))``
+    for int64 ``delta >= 0`` and ``interval >= 1``, bit-identical to IEEE
+    float64 (:func:`host_refill_grant_nt`) but in int64 operations only:
+    each of the three float operations is carried out on integer
+    significands and rounded to nearest-even, as IEEE prescribes."""
+    i64 = jnp.int64
+    ma, ea = _to_double(jnp.maximum(delta, 1))
+    mb, eb = _to_double(interval)
+    # q = floor(ma · 2^55 / mb) ∈ [2^54, 2^56) by restoring division; the
+    # remainder is the sticky bit of the quotient.
+    d0 = ma >= mb
+    rem = ma - jnp.where(d0, mb, 0)
+
+    def step(_, carry):
+        q, rem = carry
+        rem = rem << 1
+        bit = rem >= mb
+        return (q << 1) | bit.astype(i64), rem - jnp.where(bit, mb, 0)
+
+    q, rem = jax.lax.fori_loop(0, 55, step, (d0.astype(i64), rem))
+    s = jnp.where(q >= (i64(1) << 55), 3, 2)
+    mq, eq = _round_even(
+        q >> s, q & ((i64(1) << s) - 1), i64(1) << (s - 1), rem != 0,
+        ea - eb - 55 + s,
+    )
+    # P = mq · 1e9 < 2^83 as A·2^32 + B, then rounded back to 53 bits.
+    a = (mq >> 32) * NANO
+    b = (mq & 0xFFFFFFFF) * NANO
+    s = jnp.where((a << 3) + (b >> 29) >= (i64(1) << _MANT), 30, 29)
+    mp, e = _round_even(
+        (a << (32 - s)) + (b >> s), b & ((i64(1) << s) - 1), i64(1) << (s - 1),
+        False, eq + s,
+    )
+    # floor(mp · 2^e), clipped at 2^62 (reached exactly when e >= 10).
+    whole = jnp.where(
+        e >= 0, mp << jnp.clip(e, 0, 9), mp >> jnp.clip(-e, 0, 63)
+    )
+    grant = jnp.where(e >= 10, i64(2**62), whole)
+    return jnp.where(delta > 0, grant, 0)
 
 # Packed-transfer layout of one take tick (engine._apply_takes ↔
 # engine._jit_take_packed): the host ships ONE int64[TAKE_PACK_ROWS, K]
@@ -114,9 +205,7 @@ def take_batch(
     interval = req.per_ns // safe_freq
     rate_zero = (req.freq == 0) | (req.per_ns == 0) | (interval == 0)
     safe_interval = jnp.where(interval == 0, 1, interval)
-    grant_tokens = delta.astype(jnp.float64) / safe_interval.astype(jnp.float64)
-    grant_f = jnp.where(rate_zero, 0.0, grant_tokens * float(NANO))
-    grant_nt = jnp.floor(jnp.clip(grant_f, 0.0, _GRANT_CLIP)).astype(i64)
+    grant_nt = jnp.where(rate_zero, 0, refill_grant_nt(delta, safe_interval))
     missing_nt = cap_now_nt - tokens_nt
     grant_nt = jnp.minimum(grant_nt, missing_nt)
 

@@ -45,21 +45,6 @@ from patrol_tpu.ops.take import TakeRequest, TakeResult, take_batch
 REPLICA_AXIS = "r"
 BUCKET_AXIS = "b"
 
-# jax.shard_map graduated from jax.experimental in newer releases (which
-# also renamed check_rep → check_vma); the pinned toolchain (0.4.x) still
-# ships the experimental name and the old kwarg.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - exercised only on older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-
-_SM_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in _inspect.signature(_shard_map).parameters
-    else "check_rep"
-)
 
 
 def make_mesh(replicas: int = 1, devices=None) -> Mesh:
@@ -217,7 +202,7 @@ def cluster_step(
 
 def build_cluster_step(mesh: Mesh, node_slot: int):
     """jit(shard_map(cluster_step)) over the mesh, with donated state."""
-    fn = _shard_map(
+    fn = jax.shard_map(
         partial(
             cluster_step,
             node_slot=node_slot,
@@ -237,7 +222,7 @@ def build_cluster_step(mesh: Mesh, node_slot: int):
         # compile path rejects for s64 ("Supported lowering only of Sum
         # all reduce", BENCH r2). Replication is instead asserted by
         # tests/test_topology.py's cross-replica equality checks.
-        **{_SM_CHECK_KW: False},
+        check_vma=False,
     )
     return jax.jit(fn, donate_argnums=0)
 
@@ -303,13 +288,13 @@ def build_cluster_step_packed(mesh: Mesh, node_slot: int):
         )
         return state, out
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         step,
         mesh=mesh,
         in_specs=(STATE_SPEC, mat_spec, mat_spec),
         out_specs=(STATE_SPEC, mat_spec),
         # See build_cluster_step: converge() replicates by value.
-        **{_SM_CHECK_KW: False},
+        check_vma=False,
     )
     return jax.jit(fn, donate_argnums=0)
 

@@ -59,7 +59,7 @@ _DECLARED: Tuple[Knob, ...] = (
     Knob("PATROL_DEVICE_ANNOTATIONS", "0",
          "Emit jax named_scope annotations for profiler traces (1 = on)."),
     Knob("PATROL_MERGE_KERNEL", "scatter",
-         "Merge kernel select: scatter | auto | pallas (compile-probed)."),
+         "Merge kernel select: scatter | auto | pallas (compiled at engine start)."),
     Knob("PATROL_TICK_FOLD", "1",
          "Fold deltas before the merge tick (default: 0 on cpu, 1 on "
          "accelerators)."),

@@ -284,6 +284,10 @@ class CounterRegistry:
         "slo_breaches",
         "gc_sweeps",
         "gc_buckets_reclaimed",
+        # Engine ticks (or mesh sub-dispatches) whose device work raised:
+        # their deltas and takes are lost with the tick, so any nonzero
+        # count is a fault to read the log for.
+        "engine_tick_failures",
         "gc_pressure_shed",
         "directory_compactions",
         "state_bytes_in_use",

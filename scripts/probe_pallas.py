@@ -57,7 +57,8 @@ def time_fn(run, state, n_lo=2, n_hi=8, repeats=3):
 
 def main():
     rng = np.random.default_rng(11)
-    print(f"K={K} hot={HOT} pallas_native={pallas_merge.native_available()}")
+    refused = pallas_merge.compile_error(mk_state())
+    print(f"K={K} hot={HOT} pallas_refused={refused}")
     for label, rows_np in (
         ("zipf-hot", rng.integers(0, HOT, K).astype(np.int64)),
         ("uniform", rng.integers(0, B, K).astype(np.int64)),
@@ -94,7 +95,7 @@ def main():
             f"{K / per / 1e6:8.2f} M-deltas/s (blocks {touched})"
         )
 
-        if pallas_merge.native_available():
+        if refused is None:
             # pallas path: host prep (sort+plan) is part of the cost in
             # production; measure device time with prep hoisted (prep is
             # ~1 ms numpy at K=65536, reported separately).

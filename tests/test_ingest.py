@@ -152,8 +152,6 @@ class TestDecodeFoldDifferential:
 
     @pytest.mark.parametrize("impl", ["xla", "pallas"])
     def test_corpus_bit_exact(self, impl):
-        if impl == "pallas" and not ingest_ops.available():
-            pytest.skip("pallas unavailable")
         raw = hostile_corpus()
         planes, lengths = planes_of(raw)
         P = len(raw)
@@ -189,8 +187,6 @@ class TestDecodeFoldDifferential:
         assert np.array_equal(np.asarray(state2.elapsed), ref_el)
 
     def test_pallas_and_xla_agree_on_every_output(self):
-        if not ingest_ops.available():
-            pytest.skip("pallas unavailable")
         raw = hostile_corpus(seed=99, n=24)
         planes, lengths = planes_of(raw)
         P = len(raw)
@@ -479,9 +475,11 @@ class TestRxRing:
             ]
             reps[0].delta.offer(states)
             reps[0].delta.flush()
+            # rx_deltas counts an interval once the engine has taken it
+            # in; a bound row alone may not hold its lanes yet.
             deadline = time_mod.time() + 10
             while time_mod.time() < deadline:
-                if engines[1].directory.lookup("rb49") is not None:
+                if reps[1].delta.rx_deltas >= len(states):
                     break
                 time_mod.sleep(0.02)
             assert engines[1].flush(timeout=30)

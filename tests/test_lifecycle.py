@@ -226,6 +226,42 @@ class TestTombstoneConservation:
             eng.stop()
 
 
+class TestRemoteLanesKept:
+    """A partial replicated join can read full: a peer's lane with a
+    positive balance lands before the lanes that spend it. GC must not
+    reclaim such a row, or the acknowledged remote lane is lost and the
+    later spend reads against a smaller join."""
+
+    @staticmethod
+    def _lane(eng, name, slot, added, taken):
+        eng.ingest_delta(
+            wire.from_nanotokens(
+                name, 10 * NANO, added, taken, origin_slot=slot,
+                cap_nt=10 * NANO, lane_added_nt=added, lane_taken_nt=taken,
+            ),
+            slot=slot,
+        )
+        eng.flush()
+
+    @pytest.mark.parametrize("hosted", [False, True])
+    def test_over_full_partial_join_is_kept(self, hosted):
+        eng, clock = mk_engine()
+        try:
+            own = 0
+            if hosted:
+                eng.take("a", RATE, 1)  # fresh bind -> host-resident
+                assert eng.hosted_buckets == 1
+                own = 1
+            self._lane(eng, "a", 1, 5 * NANO, 0)
+            assert eng.tokens("a") == 15 - own  # over capacity: reads full
+            clock.now += 10 * NANO
+            assert eng.gc_sweep(force=True) == 0
+            self._lane(eng, "a", 2, 0, 8 * NANO)
+            assert eng.tokens("a") == 7 - own  # the whole join
+        finally:
+            eng.stop()
+
+
 class TestMemoryBudget:
     def test_hard_watermark_sheds_new_names_only(self):
         eng, clock = mk_engine(max_buckets=4, window_ms=0)

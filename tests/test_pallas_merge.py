@@ -11,10 +11,6 @@ from patrol_tpu.models.limiter import LimiterConfig, init_state
 from patrol_tpu.ops import pallas_merge
 from patrol_tpu.ops.merge import MergeBatch, merge_batch
 
-pytestmark = pytest.mark.skipif(
-    not pallas_merge.available(), reason="pallas unavailable"
-)
-
 R = pallas_merge.ROWS_PER_BLOCK
 
 

@@ -305,10 +305,6 @@ class TestMutationGate:
 
 class TestPallasModel:
     def test_shipped_pallas_merge_is_silent(self):
-        from patrol_tpu.ops import pallas_merge
-
-        if not pallas_merge.available():
-            pytest.skip("pallas unavailable")
         assert prove.prove_root(ROOTS["merge_batch_pallas"]) == []
 
 
@@ -613,10 +609,6 @@ class TestRawIngestObligations:
         interpret path — the shape a future Mosaic lowering fills in)."""
         from patrol_tpu.ops import ingest as ingest_ops
 
-        if not ingest_ops.available():  # pragma: no cover
-            import pytest
-
-            pytest.skip("pallas unavailable")
         f = prove.prove_root(
             ROOTS["decode_fold_raw"],
             fn=lambda *a: ingest_ops.decode_fold_raw_pallas(*a, interpret=True),
@@ -750,6 +742,7 @@ class TestLifecycleObligations:
         rng = np.random.default_rng(12)
         B, N, K = 16, 3, 64
         pn = rng.integers(0, 4 * NANO, (B, N, 2)).astype(np.int64)
+        pn[B // 2 :, [0, 2]] = 0  # rows holding only the own lane (slot 1)
         el = rng.integers(0, 3 * NANO, B).astype(np.int64)
         rows = rng.integers(0, B, K).astype(np.int64)
         now = rng.integers(0, 8 * NANO, K).astype(np.int64)
@@ -768,10 +761,12 @@ class TestLifecycleObligations:
             ),
             node_slot=1,
         )
+        remote = (pn[rows][:, [0, 2]] != 0).any(axis=(1, 2))
         want = lc.host_lifecycle_full(
             pn[rows, :, 0].sum(axis=1), pn[rows, :, 1].sum(axis=1),
-            el[rows], cap, created, now, per,
+            el[rows], cap, created, now, per, remote,
         )
+        assert want.any() and remote.any()  # both verdicts exercised
         assert np.array_equal(np.asarray(view.full), want)
         assert np.array_equal(np.asarray(view.own_added_nt), pn[rows, 1, 0])
         assert np.array_equal(np.asarray(view.own_taken_nt), pn[rows, 1, 1])

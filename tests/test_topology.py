@@ -100,12 +100,12 @@ class TestTreeConverge:
             )
             return st.pn[None], st.elapsed[None]
 
-        fn = topo._shard_map(
+        fn = jax.shard_map(
             f,
             mesh=mesh,
             in_specs=(P(topo.REPLICA_AXIS), P(topo.REPLICA_AXIS)),
             out_specs=(P(topo.REPLICA_AXIS), P(topo.REPLICA_AXIS)),
-            **{topo._SM_CHECK_KW: False},
+            check_vma=False,
         )
         return jax.jit(fn)(jnp.asarray(pn_in), jnp.asarray(el_in))
 
