@@ -14,6 +14,7 @@ import contextlib
 import dataclasses
 import logging
 import signal
+import time
 from typing import List, Optional
 
 from patrol_tpu.models.limiter import LimiterConfig, SMALL
@@ -208,6 +209,11 @@ class Command:
                 "engine_demotions": engine.demotions,
                 "buckets": len(engine.directory),
                 "node_slot": slots.self_slot,
+                # CLOCK_MONOTONIC now: the clock of the flight recorder's
+                # ring, the C++ front's parse stamps and a capture's
+                # patrol.clock anchor; its difference over a window is
+                # the window's length on the node.
+                "mono_ns": time.monotonic_ns(),
                 # Bucket lifecycle (idle-bucket GC + memory budget):
                 # live gauges — reclaim/shed/compaction counts, bytes in
                 # use vs budget, tombstones, pressure level.

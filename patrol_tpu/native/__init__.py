@@ -122,7 +122,7 @@ NATIVE_EFFECTS: Dict[str, NativeEffect] = {
     # -- HTTP front (patrol_http.cpp) --
     "pt_http_start": _E(True, False, False, False),     # spawns epoll thread
     "pt_http_port": _E(False, False, False, False),
-    "pt_http_poll": _E(True, False, False, False),      # condvar wait
+    "pt_http_poll": _E(True, False, False, False),      # condvar wait; parse stamps out
     "pt_http_complete_takes": _E(False, False, False, False),
     "pt_http_complete_other": _E(False, False, False, False),
     "pt_http_stats": _E(False, False, False, False),
@@ -287,7 +287,8 @@ def load() -> Optional[ctypes.CDLL]:
         lib.pt_http_port.restype = ctypes.c_int
         lib.pt_http_poll.argtypes = [
             ctypes.c_int, ctypes.c_int,
-            _u64p, _i32p, _u8p, _i32p, _i64p, _i64p, _i64p, ctypes.c_int,
+            _u64p, _i32p, _u8p, _i32p, _i64p, _i64p, _i64p, _i64p,
+            ctypes.c_int,
             _u64p, _i32p, _u8p, _i32p, _u8p, ctypes.c_int,
             ctypes.POINTER(ctypes.c_int),
         ]

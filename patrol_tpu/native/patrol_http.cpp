@@ -359,6 +359,7 @@ int64_t realtime_ns() {
 struct TakeRec {
   uint64_t tag;
   int32_t stream;  // h2 stream id; 0 = HTTP/1.1
+  int64_t req_ns;  // parse stamp: steady_clock (CLOCK_MONOTONIC) ns
   int64_t freq, per_ns, count;
   uint8_t name[kNameMax];
   int name_len;
@@ -1103,6 +1104,9 @@ void serve_h2_request(Server* s, int slot, int32_t stream,
     TakeRec r{};
     r.tag = make_tag(slot, c.gen);
     r.stream = stream;
+    r.req_ns = std::chrono::duration_cast<std::chrono::nanoseconds>(
+                   t0.time_since_epoch())
+                   .count();
     r.freq = freq;
     r.per_ns = per_ns;
     r.count = count;
@@ -1374,6 +1378,9 @@ bool try_parse_one(Server* s, int slot) {
     }
     TakeRec r{};
     r.tag = make_tag(slot, c.gen);
+    r.req_ns = std::chrono::duration_cast<std::chrono::nanoseconds>(
+                   c.req_start.time_since_epoch())
+                   .count();
     r.freq = freq;
     r.per_ns = per_ns;
     r.count = count;
@@ -1658,10 +1665,13 @@ int pt_http_set_h2_backend(int h, uint16_t port) {
 // Drain parsed requests. Blocks up to timeout_ms when both queues are
 // empty (GIL released by ctypes). Fills up to cap_t takes and cap_o
 // others; *n_other receives the other-count; returns the take-count.
+// req_ns[i] is take i's parse stamp on steady_clock (CLOCK_MONOTONIC,
+// the clock of Python's time.perf_counter_ns).
 int pt_http_poll(int h, int timeout_ms,
                  uint64_t* tags, int32_t* streams, uint8_t* names,
                  int* name_lens,
-                 int64_t* freqs, int64_t* pers, int64_t* counts, int cap_t,
+                 int64_t* freqs, int64_t* pers, int64_t* counts,
+                 int64_t* req_ns, int cap_t,
                  uint64_t* otags, int32_t* ostreams, uint8_t* otargets,
                  int* otarget_lens,
                  uint8_t* omethods, int cap_o, int* n_other) {
@@ -1708,6 +1718,7 @@ int pt_http_poll(int h, int timeout_ms,
     freqs[nt] = r.freq;
     pers[nt] = r.per_ns;
     counts[nt] = r.count;
+    req_ns[nt] = r.req_ns;
     s->take_q.pop_front();
     nt++;
   }

@@ -253,20 +253,16 @@ class AntiEntropy:
                 job = self._jobs.popleft()
             try:
                 t0 = time.perf_counter_ns()
-                if job[0] == "trigger":
-                    self._job_trigger(job[1])
-                elif job[0] == "digest":
-                    self._job_digest(job[1], job[2])
-                elif job[0] == "fetch":
-                    self._job_fetch(job[1], job[2])
-                dur = time.perf_counter_ns() - t0
-                hist.AE_JOB.record(dur)
-                tr = trace_mod.TRACE
-                if tr.enabled:
-                    tr.record(
-                        trace_mod.EV_AE_PHASE, dur,
-                        trace_mod.AE_PHASES.get(job[0], 0),
-                    )
+                with trace_mod.TRACE.span(
+                    trace_mod.EV_AE_PHASE, trace_mod.AE_PHASES.get(job[0], 0)
+                ):
+                    if job[0] == "trigger":
+                        self._job_trigger(job[1])
+                    elif job[0] == "digest":
+                        self._job_digest(job[1], job[2])
+                    elif job[0] == "fetch":
+                        self._job_fetch(job[1], job[2])
+                hist.AE_JOB.record(time.perf_counter_ns() - t0)
             except Exception:  # pragma: no cover - worker must not die
                 log.exception("anti-entropy job failed")
 

@@ -359,7 +359,12 @@ class AuditPlane:
         """One audit tick: refresh lag/staleness gauges, absorb the local
         ledger, evaluate quiesced windows, and ship the digest + window
         frame to every peer. Returns datagrams sent."""
-        t0 = time.perf_counter_ns()
+        with trace_mod.TRACE.span(trace_mod.EV_AUDIT_TICK) as sp:
+            sent = self._flush()
+            sp.arg = sent
+        return sent
+
+    def _flush(self) -> int:
         self.flushes += 1
         self._sample_lag()
         sides_now = self._sides_now()
@@ -411,11 +416,6 @@ class AuditPlane:
         if sent:
             self.packets_tx += sent
             profiling.COUNTERS.inc("audit_packets_tx", sent)
-        tr = trace_mod.TRACE
-        if tr.enabled:
-            tr.record(
-                trace_mod.EV_AUDIT_TICK, time.perf_counter_ns() - t0, sent
-            )
         return sent
 
     # -- rx ------------------------------------------------------------------
@@ -471,16 +471,17 @@ class AuditPlane:
                 job = self._jobs.popleft()
             try:
                 if job[0] == "digest":
-                    self._compare(job[1])
+                    with trace_mod.TRACE.span(trace_mod.EV_AUDIT_COMPARE) as sp:
+                        sp.arg = self._compare(job[1])
             except Exception:  # pragma: no cover - worker must not die
                 if getattr(self.rep, "log", None):
                     self.rep.log.exception("audit digest compare failed")
 
-    def _compare(self, entries: List[Tuple[int, int]]) -> None:
+    def _compare(self, entries: List[Tuple[int, int]]) -> int:
         """READ-ONLY divergence compare: the sender's per-bucket digests
         vs our own state. Unknown bucket or digest mismatch ⇒ divergent.
-        Updates the gauge + age; never fetches, never pushes."""
-        t0 = time.perf_counter_ns()
+        Updates the gauge + age; never fetches, never pushes. → the
+        divergent bucket count."""
         eng = self._engine()
         own: Dict[int, int] = {}
         if eng is not None:
@@ -501,13 +502,7 @@ class AuditPlane:
             else:
                 self._divergence_since = None
         profiling.COUNTERS.inc("audit_divergence_checks")
-        tr = trace_mod.TRACE
-        if tr.enabled:
-            tr.record(
-                trace_mod.EV_AUDIT_COMPARE,
-                time.perf_counter_ns() - t0,
-                divergent,
-            )
+        return divergent
 
     # -- observability -------------------------------------------------------
 

@@ -336,38 +336,37 @@ class DeltaPlane:
     def flush(self) -> int:
         """One pacing tick: advertise to silent peers, retransmit expired
         intervals, pack + send the dirty set to every capable peer, drain
-        pending ack vectors. Returns data packets sent."""
-        t0 = time.perf_counter_ns()
+        pending ack vectors. Returns data packets sent. Its span's
+        duration counts into ``repl_send_ns``, sent or not."""
         sends: List[Tuple[bytes, Addr]] = []
         data_packets = 0
-        with self._mu:
-            self._tick += 1
-            tick = self._tick
-            dirty = self._dirty
-            self._dirty = {}
-            peers = list(self.rep.peers)
-            ae = getattr(self.rep, "antientropy", None)
-            for addr in peers:
-                st = self._peer(addr)
-                if not st.capable:
-                    if (
-                        self.tx_enabled
-                        and tick - st.last_advert_tick >= self.advert_ticks
-                    ):
-                        st.last_advert_tick = tick
-                        self.adverts_tx += 1
-                        sends.append((self._advert_bytes(ack=False), addr))
-                    continue
-                data_packets += self._flush_peer_locked(
-                    addr, st, dirty, tick, ae, sends
-                )
-        for data, addr in sends:
-            self.rep.unicast(data, addr)
-        tr = trace_mod.TRACE
-        if tr.enabled and sends:
-            tr.record(
-                trace_mod.EV_DELTA_PACK, time.perf_counter_ns() - t0, len(sends)
-            )
+        t0 = time.perf_counter_ns()
+        with trace_mod.TRACE.span(trace_mod.EV_DELTA_FLUSH) as sp:
+            with self._mu:
+                self._tick += 1
+                tick = self._tick
+                dirty = self._dirty
+                self._dirty = {}
+                peers = list(self.rep.peers)
+                ae = getattr(self.rep, "antientropy", None)
+                for addr in peers:
+                    st = self._peer(addr)
+                    if not st.capable:
+                        if (
+                            self.tx_enabled
+                            and tick - st.last_advert_tick >= self.advert_ticks
+                        ):
+                            st.last_advert_tick = tick
+                            self.adverts_tx += 1
+                            sends.append((self._advert_bytes(ack=False), addr))
+                        continue
+                    data_packets += self._flush_peer_locked(
+                        addr, st, dirty, tick, ae, sends
+                    )
+            for data, addr in sends:
+                self.rep.unicast(data, addr)
+            sp.arg = len(sends)
+        profiling.COUNTERS.inc("repl_send_ns", time.perf_counter_ns() - t0)
         return data_packets
 
     def _flush_peer_locked(

@@ -35,10 +35,15 @@ import numpy as np
 from patrol_tpu import native
 from patrol_tpu.ops.rate import Rate
 from patrol_tpu.utils import histogram as hist
+from patrol_tpu.utils import trace as trace_mod
 
 log = logging.getLogger("patrol.native-http")
 
 NAME_MAX = 256
+# The completer's bounded wait on one ticket: a tick stalled past it
+# snapshots the flight recorder (TakeTicket.wait's take-stall anomaly),
+# and the wait goes on.
+STALL_WAIT_S = 1.0
 
 
 class NativeHTTPFront:
@@ -79,6 +84,12 @@ class NativeHTTPFront:
         self._freqs = np.zeros(b, np.int64)
         self._pers = np.zeros(b, np.int64)
         self._counts = np.zeros(b, np.int64)
+        self._req_ns = np.zeros(b, np.int64)  # parse stamps (CLOCK_MONOTONIC)
+        self._batch = 0  # pump batch id, the seam's request id
+        # The seam's per-take histograms (bound once, so a test's front
+        # can be given its own): parse → poll, poll → ticket, complete
+        # → handed back.
+        self._seam = (hist.FRONT_QUEUE, hist.FRONT_SUBMIT, hist.FRONT_ANSWER)
         self._statuses = np.zeros(b, np.int32)
         self._remaining = np.zeros(b, np.int64)
         ob = 64
@@ -141,11 +152,13 @@ class NativeHTTPFront:
         # not wait out max(poll tick, 4x last drain cost) to leave the
         # slow path. Broadcast building keeps the cadence gate.
         events_seen = store.events if store is not None else 0
+        tr = trace_mod.TRACE
         while not self._stopped.is_set():
             nt = self.lib.pt_http_poll(
                 self.h, poll_ms,
                 self._tags, self._streams, self._names, self._name_lens,
-                self._freqs, self._pers, self._counts, self.batch,
+                self._freqs, self._pers, self._counts, self._req_ns,
+                self.batch,
                 self._otags, self._ostreams, self._otargets,
                 self._otarget_lens,
                 self._omethods, self._ob, ctypes.byref(n_other),
@@ -153,17 +166,20 @@ class NativeHTTPFront:
             if nt < 0:
                 return
             if nt > 0:
-                try:
-                    self._submit_takes(repo, nt)
-                except Exception:  # pragma: no cover - keep the front alive
-                    log.exception("take pump failed; answering 500")
-                    tags = self._tags[:nt].copy()
-                    streams = self._streams[:nt].copy()
-                    st = np.full(nt, 500, np.int32)
-                    rem = np.zeros(nt, np.int64)
-                    self.lib.pt_http_complete_takes(
-                        self.h, tags, streams, st, rem, nt
-                    )
+                t_poll = time.perf_counter_ns()
+                self._batch += 1
+                with tr.span(trace_mod.EV_HTTP_PUMP, nt, self._batch):
+                    try:
+                        self._submit_takes(repo, nt, t_poll, self._batch)
+                    except Exception:  # pragma: no cover - keep the front alive
+                        log.exception("take pump failed; answering 500")
+                        tags = self._tags[:nt].copy()
+                        streams = self._streams[:nt].copy()
+                        st = np.full(nt, 500, np.int32)
+                        rem = np.zeros(nt, np.int64)
+                        self.lib.pt_http_complete_takes(
+                            self.h, tags, streams, st, rem, nt
+                        )
             for j in range(n_other.value):
                 self._dispatch_other(j)
             if self._engine is not None:
@@ -194,9 +210,10 @@ class NativeHTTPFront:
                         log.exception("native promotion drain failed")
         self._cq.put(None)  # unblock the completer at shutdown
 
-    def _submit_takes(self, repo, nt: int) -> None:
+    def _submit_takes(self, repo, nt: int, t_poll: int, batch: int) -> None:
         tags = self._tags[:nt].copy()
         streams = self._streams[:nt].copy()
+        req_ns = self._req_ns[:nt].copy()
         names = [
             bytes(self._names[i, : self._name_lens[i]]).decode(
                 "utf-8", "surrogateescape"
@@ -227,38 +244,57 @@ class NativeHTTPFront:
                 return
             ksel = np.array(keep, np.intp)
             tags, streams, counts = tags[ksel], streams[ksel], counts[ksel]
+            req_ns = req_ns[ksel]
             names = [names[i] for i in keep]
             rates = [rates[i] for i in keep]
-        res = repo.submit_takes_batch(names, rates, counts)
+        res = repo.submit_takes_batch(names, rates, counts, batch=batch)
         if res is None:  # pool spent with everything pinned: rare overload
             raise RuntimeError("bucket pool spent; takes dropped")
         self._cq.put(
-            (tags, streams, [t for t, _ in res], time.perf_counter_ns())
+            (tags, streams, [t for t, _ in res], req_ns, t_poll,
+             time.perf_counter_ns(), batch)
         )
 
     def _completer(self) -> None:
+        tr = trace_mod.TRACE
         while True:
             group = self._cq.get()
             if group is None:
                 return
-            tags, streams, tickets, t_sub = group
+            tags, streams, tickets, req_ns, t_poll, t_sub, batch = group
             nt = len(tickets)
-            statuses = np.empty(nt, np.int32)
-            remaining = np.empty(nt, np.int64)
             # Tickets submitted together complete in the same engine
             # tick(s); ordered waits cost one tick total, not one each.
-            for i, t in enumerate(tickets):
-                t.wait()
-                statuses[i] = 200 if t.ok else 429
-                remaining[i] = t.remaining
+            for t in tickets:
+                while not t.wait(STALL_WAIT_S):
+                    pass
             # patrol-scope: the front's engine-wait latency (submit to
             # batch completion), one observation per pump batch — the
             # Python-side complement of the C++ server's own ring
             # (http_latency_* in stats()).
             hist.FRONT_WAIT.record(time.perf_counter_ns() - t_sub)
-            self.lib.pt_http_complete_takes(
-                self.h, tags, streams, statuses, remaining, nt
-            )
+            with tr.span(trace_mod.EV_HTTP_ANSWER, nt, batch):
+                statuses = np.fromiter(
+                    (200 if t.ok else 429 for t in tickets), np.int32, nt
+                )
+                remaining = np.fromiter(
+                    (t.remaining for t in tickets), np.int64, nt
+                )
+                self.lib.pt_http_complete_takes(
+                    self.h, tags, streams, statuses, remaining, nt
+                )
+                t_handed = time.perf_counter_ns()
+            self._record_seam(req_ns, t_poll, tickets, t_handed)
+
+    def _record_seam(self, req_ns, t_poll: int, tickets, t_handed: int) -> None:
+        """One pump batch's takes, each tiled parse → handed back across
+        the seam: queue (parse → poll returned), submit (poll returned →
+        ticket created), the engine's own take_service_ns (created →
+        complete), answer (complete → answers handed to the front)."""
+        queue, submit, answer = self._seam
+        queue.record_many([t_poll - r for r in req_ns.tolist()])
+        submit.record_many([t.t0_ns - t_poll for t in tickets])
+        answer.record_many([t_handed - t.done_ns for t in tickets])
 
     def _dispatch_other(self, j: int) -> None:
         tag = int(self._otags[j])

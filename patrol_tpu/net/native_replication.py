@@ -736,28 +736,28 @@ class NativeReplicator:
         addrs: Optional[List[Tuple[str, int]]],
     ) -> None:
         """Encode + sendmmsg ``states`` to ``addrs`` (None = every live
-        peer)."""
-        pkts, sizes = self._encode_states(states)
-        if addrs is None:
-            ips, ports = self._live_peers()
-        else:
-            if self.drop_addr is not None:
-                addrs = [a for a in addrs if not self.drop_addr(a)]
-            ips = np.array([_ip_to_u32(h) for h, _ in addrs], np.uint32)
-            ports = np.array([p for _, p in addrs], np.uint16)
-        if len(ips):
-            sent = self.sock.send_fanout(pkts, sizes, ips, ports)
-            self.tx_packets += sent
-            self.tx_bytes += int(np.maximum(sizes, 0).sum()) * len(ips)
-            profiling.COUNTERS.inc("replication_tx_packets", sent)
-            profiling.COUNTERS.inc(
-                "replication_tx_bytes", int(np.maximum(sizes, 0).sum()) * len(ips)
-            )
-            tr = trace_mod.TRACE
-            if tr.enabled:
-                tr.record(
-                    trace_mod.EV_BROADCAST_TX, 0, len(sizes) * len(ips)
+        peer): one ``broadcast.tx`` span, its duration counted into
+        ``repl_send_ns``."""
+        t0 = time.perf_counter_ns()
+        with trace_mod.TRACE.span(trace_mod.EV_BROADCAST_TX) as sp:
+            pkts, sizes = self._encode_states(states)
+            if addrs is None:
+                ips, ports = self._live_peers()
+            else:
+                if self.drop_addr is not None:
+                    addrs = [a for a in addrs if not self.drop_addr(a)]
+                ips = np.array([_ip_to_u32(h) for h, _ in addrs], np.uint32)
+                ports = np.array([p for _, p in addrs], np.uint16)
+            if len(ips):
+                sent = self.sock.send_fanout(pkts, sizes, ips, ports)
+                self.tx_packets += sent
+                self.tx_bytes += int(np.maximum(sizes, 0).sum()) * len(ips)
+                profiling.COUNTERS.inc("replication_tx_packets", sent)
+                profiling.COUNTERS.inc(
+                    "replication_tx_bytes", int(np.maximum(sizes, 0).sum()) * len(ips)
                 )
+                sp.arg = len(sizes) * len(ips)
+        profiling.COUNTERS.inc("repl_send_ns", time.perf_counter_ns() - t0)
 
     def _retry_oversize(self, states, pkts, sizes):
         """Re-encode trailer-oversized states (size −1) without the

@@ -1019,21 +1019,22 @@ class Replicator(asyncio.DatagramProtocol):
 
     def _broadcast_now(self, payloads: List[bytes], addrs: Optional[List[Addr]] = None) -> None:
         targets = self.peers if addrs is None else addrs
-        for data in payloads:
-            for peer in targets:
-                self._send(data, peer)
-        if payloads and targets:
-            profiling.COUNTERS.inc(
-                "replication_tx_packets", len(payloads) * len(targets)
-            )
-            profiling.COUNTERS.inc(
-                "replication_tx_bytes", sum(map(len, payloads)) * len(targets)
-            )
-        tr = trace_mod.TRACE
-        if tr.enabled and payloads and targets:
-            tr.record(
-                trace_mod.EV_BROADCAST_TX, 0, len(payloads) * len(targets)
-            )
+        if not (payloads and targets):
+            return
+        t0 = time.perf_counter_ns()
+        with trace_mod.TRACE.span(
+            trace_mod.EV_BROADCAST_TX, len(payloads) * len(targets)
+        ):
+            for data in payloads:
+                for peer in targets:
+                    self._send(data, peer)
+        profiling.COUNTERS.inc("repl_send_ns", time.perf_counter_ns() - t0)
+        profiling.COUNTERS.inc(
+            "replication_tx_packets", len(payloads) * len(targets)
+        )
+        profiling.COUNTERS.inc(
+            "replication_tx_bytes", sum(map(len, payloads)) * len(targets)
+        )
 
     def _payload_bytes(self, st: wire.WireState) -> bytes:
         """Mode-gated encode: ``compat`` rewrites a dual-payload state to

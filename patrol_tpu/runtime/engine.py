@@ -27,7 +27,6 @@ to preserve the unique-rows kernel invariant (sequential semantics).
 
 from __future__ import annotations
 
-import contextlib
 import itertools
 import logging
 import os
@@ -142,18 +141,22 @@ DISPATCH_AHEAD = max(2, int(os.environ.get("PATROL_DISPATCH_AHEAD", 8)))
 # observation rides the completer thread, which blocks on device results
 # anyway); opt out for overhead experiments.
 DEVICE_TIMING = os.environ.get("PATROL_DEVICE_TIMING", "1") != "0"
-# Optional jax.profiler dispatch annotations: names the engine's kernel
-# dispatches inside an XPlane capture (/debug/jax/trace) so the r06
-# device trace attributes time to commit/take/fold kernels directly.
-DEVICE_ANNOTATIONS = os.environ.get("PATROL_DEVICE_ANNOTATIONS", "0") != "0"
+# Kernel dispatch spans: a flight-recorder event per dispatch, and the
+# ``patrol.<kernel>`` annotation while an XPlane capture runs.
+_KERNEL_EVENTS = {
+    k: trace_mod.event_type(k)
+    for k in (
+        "take_packed", "merge_packed", "merge_folded", "merge_pallas",
+        "merge_scalar", "commit_blocks", "delta_fold", "decode_fold_raw",
+        "mesh_step",
+    )
+}
+profiling.count_jax_compiles()
 
 
-def _annotate(kernel: str):
-    """Context for one dispatch: a jax.profiler TraceAnnotation when
-    enabled, else a free nullcontext (no per-dispatch cost)."""
-    if DEVICE_ANNOTATIONS:
-        return jax.profiler.TraceAnnotation(f"patrol.{kernel}")
-    return contextlib.nullcontext()
+def _dispatch(kernel: str):
+    """The span around one kernel dispatch."""
+    return trace_mod.TRACE.span(_KERNEL_EVENTS[kernel])
 
 
 BroadcastFn = Callable[[List[wire.WireState]], None]
@@ -193,11 +196,7 @@ class StagingPool:
         else:
             profiling.COUNTERS.inc("staging_leases_fresh")
             buf = np.empty(key, dtype=np.int64)
-        dur = time.perf_counter_ns() - t0
-        hist.STAGE_STAGING_WAIT.record(dur)
-        tr = trace_mod.TRACE
-        if tr.enabled:
-            tr.record(trace_mod.EV_STAGING_LEASE, dur, buf.size)
+        hist.STAGE_STAGING_WAIT.record(time.perf_counter_ns() - t0)
         return buf
 
     def release(self, buf: np.ndarray) -> None:
@@ -205,9 +204,6 @@ class StagingPool:
             stack = self._free.setdefault(buf.shape, [])
             if len(stack) < self._max_per_shape:
                 stack.append(buf)
-        tr = trace_mod.TRACE
-        if tr.enabled:
-            tr.record(trace_mod.EV_STAGING_RECYCLE, 0, buf.size)
 
 # Host fast path (SURVEY §7 hard-part #1; VERDICT r3 item 1): serve
 # cold/low-QPS buckets from an in-process scalar-lane model — µs-class, no
@@ -492,10 +488,15 @@ class TakeTicket:
         "deferred",
         "shed",
         "t0_ns",
+        "done_ns",
+        "batch",
         "trace_id",
     )
 
-    def __init__(self, name: str, row: int, rate: Rate, count: int, now_ns: int):
+    def __init__(
+        self, name: str, row: int, rate: Rate, count: int, now_ns: int,
+        batch: int = 0,
+    ):
         self.name = name
         self.row = row
         self.rate = rate
@@ -515,20 +516,27 @@ class TakeTicket:
         # answer 429 "overloaded" for exactly the shed entries of a batch
         # while live names in the same request keep their real outcomes.
         self.shed = False
-        # patrol-scope: service-latency stamp (take_service_ns histogram)
-        # and the sampled cross-node trace id (None when unsampled).
+        # patrol-scope: service-latency stamps (take_service_ns is
+        # done_ns - t0_ns), the native front's pump batch id (0 for other
+        # callers) and the sampled cross-node trace id (None when
+        # unsampled).
         self.t0_ns = time.perf_counter_ns()
+        self.done_ns = 0
+        self.batch = batch
         self.trace_id = trace_mod.sample_take()
 
     def complete(self, remaining: int, ok: bool) -> bool:
         """Returns True on the first completion (False if already done) —
         the engine unpins the ticket's directory row exactly on that
-        transition."""
+        transition. ``done_ns`` keeps a stamp its caller set first (the
+        one its take_service_ns sample uses), else reads now."""
         with self._mu:
             if self._event.is_set():
                 return False
             self.remaining = remaining
             self.ok = ok
+            if not self.done_ns:
+                self.done_ns = time.perf_counter_ns()
             self._event.set()
             callbacks, self._callbacks = self._callbacks, []
         for cb in callbacks:
@@ -828,18 +836,18 @@ def fold_hybrid(deltas: DeltaArrays, nodes: int, row_dense_min: int):
 # the kernel's own 38µs + one transfer each way).
 @lru_cache(maxsize=8)
 def _jit_take_packed(node_slot: int):
-    def step(state, packed):
+    def take_packed(state, packed):
         # The packed↔result layout lives with the kernel now
         # (ops/take.py take_n_batch — its own certified prove root);
         # this factory only binds the static node slot and donation.
         return take_n_batch(state, packed, node_slot)
 
-    return jax.jit(step, donate_argnums=0)
+    return jax.jit(take_packed, donate_argnums=0)
 
 
 @lru_cache(maxsize=8)
 def _jit_merge_packed():
-    def step(state, packed):
+    def merge_packed(state, packed):
         batch = MergeBatch(
             rows=packed[0].astype(jnp.int32),
             slots=packed[1].astype(jnp.int32),
@@ -849,7 +857,7 @@ def _jit_merge_packed():
         )
         return merge_batch(state, batch)
 
-    return jax.jit(step, donate_argnums=0)
+    return jax.jit(merge_packed, donate_argnums=0)
 
 
 @lru_cache(maxsize=8)
@@ -857,7 +865,7 @@ def _jit_merge_packed_folded():
     """Scatter-max with unique/sorted flags asserted — only valid for
     batches prepared by :meth:`DeviceEngine._fold_lane_merges`."""
 
-    def step(state, packed):
+    def merge_folded(state, packed):
         batch = merge_mod.FoldedMergeBatch(
             rows=packed[0].astype(jnp.int32),
             slots=packed[1].astype(jnp.int32),
@@ -868,7 +876,7 @@ def _jit_merge_packed_folded():
         )
         return merge_mod.merge_batch_folded(state, batch)
 
-    return jax.jit(step, donate_argnums=0)
+    return jax.jit(merge_folded, donate_argnums=0)
 
 
 @lru_cache(maxsize=8)
@@ -878,7 +886,7 @@ def _jit_commit_packed():
     for matrices prepared by :func:`patrol_tpu.ops.commit.pack_commit_blocks`
     (flattened-sorted unique keys, sentinel padding)."""
 
-    def step(state, packed):
+    def commit_blocks(state, packed):
         blocks = commit_mod.CommitBlocks(
             rows=packed[0].astype(jnp.int32),
             slots=packed[1].astype(jnp.int32),
@@ -889,7 +897,7 @@ def _jit_commit_packed():
         )
         return commit_mod.commit_blocks(state, blocks)
 
-    return jax.jit(step, donate_argnums=0)
+    return jax.jit(commit_blocks, donate_argnums=0)
 
 
 @lru_cache(maxsize=8)
@@ -897,7 +905,7 @@ def _jit_merge_rows_dense():
     """Row-window scatter-max — the dense half of the fold-to-dense
     hybrid (one update per row, full lane plane per window)."""
 
-    def step(state, rows, updates, elapsed):
+    def merge_rows_dense(state, rows, updates, elapsed):
         batch = merge_mod.RowDenseBatch(
             rows=rows.astype(jnp.int32),
             updates=updates,
@@ -905,7 +913,7 @@ def _jit_merge_rows_dense():
         )
         return merge_mod.merge_rows_dense(state, batch)
 
-    return jax.jit(step, donate_argnums=0)
+    return jax.jit(merge_rows_dense, donate_argnums=0)
 
 
 @lru_cache(maxsize=8)
@@ -913,7 +921,7 @@ def _jit_merge_scalar_packed():
     """Deficit-attribution merge for scalar-semantics (reference-peer)
     deltas — interop path, typically a small batch."""
 
-    def step(state, packed):
+    def merge_scalar(state, packed):
         batch = MergeBatch(
             rows=packed[0].astype(jnp.int32),
             slots=packed[1].astype(jnp.int32),
@@ -923,7 +931,7 @@ def _jit_merge_scalar_packed():
         )
         return merge_scalar_batch(state, batch)
 
-    return jax.jit(step, donate_argnums=0)
+    return jax.jit(merge_scalar, donate_argnums=0)
 
 
 class DeviceEngine:
@@ -1119,6 +1127,9 @@ class DeviceEngine:
         # state lock so transfer overlaps the previous tick's compute),
         # and the dispatch-ahead bound on in-flight device ticks.
         self._staging = StagingPool()
+        # The take_service_ns histogram this engine's completions record
+        # into (bound once, so a test's engine can be given its own).
+        self._take_service = hist.TAKE_SERVICE
         self._dispatch_ahead = DISPATCH_AHEAD
         # Adaptive commit-block sizing (PATROL_COMMIT_BLOCKS=auto):
         # measured per-row device-commit cost (completer-written racy
@@ -1376,12 +1387,14 @@ class DeviceEngine:
         pressure = self._budget_pressure()
         idle_ns = 0 if (force or pressure) else self._gc_idle_ns
         t0 = time.perf_counter_ns()
-        cands, stamps = self.directory.gc_candidates(
-            now, idle_ns, self._gc_sweep_max
-        )
-        reclaimed = 0
-        if cands.size:
-            reclaimed = self._gc_reclaim(cands, stamps, now)
+        with trace_mod.TRACE.span(trace_mod.EV_GC_SWEEP) as sp:
+            cands, stamps = self.directory.gc_candidates(
+                now, idle_ns, self._gc_sweep_max
+            )
+            reclaimed = 0
+            if cands.size:
+                reclaimed = self._gc_reclaim(cands, stamps, now)
+            sp.arg = reclaimed
         with self._evict_mu:
             self._gc_sweeps += 1
             self._gc_win_start = now
@@ -1734,10 +1747,10 @@ class DeviceEngine:
             elapsed = lanes.elapsed_ns
             if lanes.win_takes > HOST_PROMOTE_TAKES:
                 self._promote_locked(row)
+        done_ns = ticket.done_ns = time.perf_counter_ns()
         if ticket.complete(remaining, ok):
             self.directory.unpin_rows([row])
-        done_ns = time.perf_counter_ns()
-        hist.TAKE_SERVICE.record(done_ns - ticket.t0_ns)
+        self._take_service.record(done_ns - ticket.t0_ns)
         if ok:
             # patrol-audit: book the admitted tokens into the open audit
             # window (the AP-overshoot auditor's own lane). Leaf lock,
@@ -2379,6 +2392,7 @@ class DeviceEngine:
         rates: Sequence[Rate],
         counts: Sequence[int],
         now_ns: Optional[int] = None,
+        batch: int = 0,
     ) -> Optional[List[Tuple[TakeTicket, bool]]]:
         """Batched :meth:`submit_take` for the native HTTP pump: ONE
         directory pass (assign_many), one capacity init, one queue
@@ -2409,6 +2423,7 @@ class DeviceEngine:
                         [rates[i] for i in keep],
                         [counts[i] for i in keep],
                         now,
+                        batch,
                     )
                     if sub is None:
                         return None
@@ -2416,7 +2431,7 @@ class DeviceEngine:
                         out[i] = r
                 return out
         return self._submit_takes_batch_inner(
-            list(names), list(rates), list(counts), now
+            list(names), list(rates), list(counts), now, batch
         )
 
     def _submit_takes_batch_inner(
@@ -2425,6 +2440,7 @@ class DeviceEngine:
         rates: Sequence[Rate],
         counts: Sequence[int],
         now: int,
+        batch: int = 0,
     ) -> Optional[List[Tuple[TakeTicket, bool]]]:
         res = self._assign_many_pinned(list(names), now, with_fresh=True)
         if res is None:
@@ -2487,7 +2503,9 @@ class DeviceEngine:
             self._emit_broadcasts(bc)
         tickets = [
             host_served.get(i)
-            or TakeTicket(names[i], int(rows[i]), rates[i], int(counts[i]), now)
+            or TakeTicket(
+                names[i], int(rows[i]), rates[i], int(counts[i]), now, batch
+            )
             for i in range(len(names))
         ]
         queued = [t for i, t in enumerate(tickets) if i not in host_served]
@@ -2796,7 +2814,7 @@ class DeviceEngine:
                 elapsed_ns=jnp.asarray(elapsed_p),
             )
             t0 = time.perf_counter_ns()
-            with self._state_mu, _annotate("delta_fold"):
+            with self._state_mu, _dispatch("delta_fold"):
                 self.state = delta_ops.delta_fold_jit(self.state, batch)
             self._observe_device_commit("delta_fold", t0, n)
             self._ticks += 1
@@ -2929,7 +2947,7 @@ class DeviceEngine:
             planes_dev = jax.device_put(np.ascontiguousarray(planes))
             _obs_stage(hist.STAGE_H2D, t0, trace_mod.EV_H2D_PUT, int(pi.size))
             t0 = time.perf_counter_ns()
-            with self._state_mu, _annotate("decode_fold_raw"):
+            with self._state_mu, _dispatch("decode_fold_raw"):
                 (
                     self.state, _ok_d, _entry_ok, hosted_mask,
                     d_slot, _d_cap, d_added, d_taken, d_elapsed,
@@ -3749,11 +3767,11 @@ class DeviceEngine:
                 self._pcond.notify_all()  # wake a back-pressured feeder
             try:
                 t0 = time.perf_counter_ns()
-                thunk()
-                _obs_stage(
-                    hist.STAGE_COMPLETION, t0, trace_mod.EV_COMMIT_COMPLETE,
-                    len(tickets),
-                )
+                with trace_mod.TRACE.span(
+                    trace_mod.EV_COMMIT_COMPLETE, len(tickets)
+                ):
+                    thunk()
+                hist.STAGE_COMPLETION.record(time.perf_counter_ns() - t0)
             except Exception:  # pragma: no cover - completer must not die
                 log.exception("tick completion failed")
                 try:
@@ -3883,6 +3901,9 @@ class DeviceEngine:
                     self._cond.wait()
                 if self._stopped and not (self._takes or self._deltas):
                     return
+                # The tick's span: drain through dispatch (its cause is
+                # the oldest pump batch it drained).
+                tick = trace_mod.TRACE.span(trace_mod.EV_TICK).begin()
                 self._gc_due = False  # this tick runs _maybe_gc below
                 # Drain up to _commit_blocks blocks per tick: everything
                 # past one block's budget coalesces into a single commit
@@ -3896,13 +3917,26 @@ class DeviceEngine:
                     MAX_MERGE_ROWS * self._commit_blocks
                 )
                 tickets = self._drain_takes(MAX_TAKE_ROWS)
+                t_drain = time.perf_counter_ns()
                 # Clear the re-queue marker at drain time, not in
                 # _group_tickets: if the tick dies before grouping runs, a
                 # stale True from a prior tick would make _fail_tickets skip
                 # the ticket and hang its caller while leaking the row pin.
+                # A ticket drained for the first time closes its queue
+                # wait (take_queue_ns).
+                queued_t0 = []
+                oldest = 0
                 for t in tickets:
-                    t.deferred = False
+                    if t.deferred:
+                        t.deferred = False
+                    else:
+                        queued_t0.append(t.t0_ns)
+                    if t.batch and (not oldest or t.batch < oldest):
+                        oldest = t.batch
                 self._busy = True
+            tick.cause = oldest
+            if queued_t0:
+                hist.TAKE_QUEUE.record_many([t_drain - t0 for t0 in queued_t0])
             # Idle demotion: count device-path takes on promoted rows and,
             # at window rollover, move quiet promoted rows back to host
             # residency BEFORE the re-route — so the very take that ends an
@@ -3948,13 +3982,9 @@ class DeviceEngine:
                 if deltas is not None or tickets:
                     self._apply(deltas, tickets)
                     tick_dur = time.perf_counter_ns() - t_tick0
-                    tr = trace_mod.TRACE
-                    if tr.enabled:
-                        tr.record(
-                            trace_mod.EV_TICK, tick_dur,
-                            (len(deltas) if deltas is not None else 0)
-                            + len(tickets),
-                        )
+                    tick.arg = (
+                        len(deltas) if deltas is not None else 0
+                    ) + len(tickets)
                     for tid, tname in self._tick_traced:
                         # Remote deltas merged this tick: their merge
                         # spans close here, joined by the propagated id.
@@ -3975,6 +4005,7 @@ class DeviceEngine:
                     self.directory.unpin_rows(deltas.rows)
                 with self._cond:
                     self._busy = False
+                tick.end()
 
     @staticmethod
     def _drain(q: deque, limit: int) -> list:
@@ -4138,7 +4169,7 @@ class DeviceEngine:
         unpin: List[int] = []
         done_ns = time.perf_counter_ns()
         now_clock = self.clock()
-        take_hist = hist.TAKE_SERVICE
+        take_hist = self._take_service
         for i, key in enumerate(keys):
             ts = groups[key]
             c_nt = ts[0].count * NANO
@@ -4153,6 +4184,8 @@ class DeviceEngine:
             for t, (remaining, ok) in zip(ts, outcomes):
                 if ok:
                     admitted_nt += c_nt
+                if not t.done_ns:
+                    t.done_ns = done_ns
                 if t.complete(remaining, ok):
                     unpin.append(t.row)
                     take_hist.record(done_ns - t.t0_ns)
@@ -4269,7 +4302,7 @@ class DeviceEngine:
             )
             if use_pallas:
                 t0 = time.perf_counter_ns()
-                with self._state_mu, _annotate("merge_pallas"):
+                with self._state_mu, _dispatch("merge_pallas"):
                     self.state = pallas_merge.merge_batch_pallas(
                         self.state,
                         deltas.rows,
@@ -4313,7 +4346,7 @@ class DeviceEngine:
             )
             _obs_stage(hist.STAGE_H2D, t0, trace_mod.EV_H2D_PUT, len(deltas))
             t0 = time.perf_counter_ns()
-            with self._state_mu, _annotate("merge_folded"):
+            with self._state_mu, _dispatch("merge_folded"):
                 if dense_dev is not None:
                     self.state = _jit_merge_rows_dense()(
                         self.state, *dense_dev
@@ -4341,7 +4374,7 @@ class DeviceEngine:
         packed_dev = jax.device_put(packed)  # staged ahead of the lock
         _obs_stage(hist.STAGE_H2D, t0, trace_mod.EV_H2D_PUT, n)
         t0 = time.perf_counter_ns()
-        with self._state_mu, _annotate("merge_packed"):
+        with self._state_mu, _dispatch("merge_packed"):
             self.state = _jit_merge_packed()(self.state, packed_dev)
         _obs_stage(hist.STAGE_DISPATCH, t0, trace_mod.EV_COMMIT_DISPATCH, n)
         self._observe_device_commit("merge_packed", t0, n)
@@ -4372,7 +4405,7 @@ class DeviceEngine:
             packed_dev = jax.device_put(packed)
             _obs_stage(hist.STAGE_H2D, t0, trace_mod.EV_H2D_PUT, len(ur))
             t0 = time.perf_counter_ns()
-            with self._state_mu, _annotate("merge_folded"):
+            with self._state_mu, _dispatch("merge_folded"):
                 self.state = _jit_merge_packed_folded()(
                     self.state, packed_dev
                 )
@@ -4391,7 +4424,7 @@ class DeviceEngine:
             dev = jax.device_put(buf)
             _obs_stage(hist.STAGE_H2D, t0, trace_mod.EV_H2D_PUT, len(ur))
             t0 = time.perf_counter_ns()
-            with self._state_mu, _annotate("commit_blocks"):
+            with self._state_mu, _dispatch("commit_blocks"):
                 self.state = _jit_commit_packed()(self.state, dev)
             _obs_stage(
                 hist.STAGE_DISPATCH, t0, trace_mod.EV_COMMIT_DISPATCH,
@@ -4570,7 +4603,7 @@ class DeviceEngine:
             packed[2, :n] = chunk.added_nt
             packed[3, :n] = chunk.taken_nt
             packed[4, :n] = chunk.elapsed_ns
-            with self._state_mu, _annotate("merge_scalar"):
+            with self._state_mu, _dispatch("merge_scalar"):
                 self.state = _jit_merge_scalar_packed()(
                     self.state, jnp.asarray(packed)
                 )
@@ -4580,9 +4613,10 @@ class DeviceEngine:
     @staticmethod
     def _note_take_coalesce(keys, groups) -> None:
         """Hot-key coalescing receipt for one tick's take pack (shared
-        with the mesh fused path): rows dispatched as take-n (nreq > 1),
-        with the flight-recorder arg carrying how many tickets rode
-        beyond one-per-row."""
+        with the mesh fused path): rows dispatched, rows dispatched as
+        take-n (nreq > 1), with the flight-recorder arg carrying how many
+        tickets rode beyond one-per-row."""
+        profiling.COUNTERS.inc("take_rows_dispatched", len(keys))
         multi = sum(1 for key in keys if len(groups[key]) > 1)
         if multi:
             profiling.COUNTERS.inc("take_rows_coalesced", multi)
@@ -4618,7 +4652,7 @@ class DeviceEngine:
         packed_dev = jax.device_put(packed)  # staged ahead of the lock
         _obs_stage(hist.STAGE_H2D, t0, trace_mod.EV_H2D_PUT, len(keys))
         t0 = time.perf_counter_ns()
-        with self._state_mu, _annotate("take_packed"):
+        with self._state_mu, _dispatch("take_packed"):
             self.state, out = _jit_take_packed(self.node_slot)(
                 self.state, packed_dev
             )

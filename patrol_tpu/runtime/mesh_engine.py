@@ -60,7 +60,7 @@ from patrol_tpu.runtime.engine import (
     DeltaArrays,
     DeviceEngine,
     TakeTicket,
-    _annotate,
+    _dispatch,
     _jit_merge_packed,
     _jit_merge_scalar_packed,
     _obs_stage,
@@ -515,7 +515,7 @@ class MeshEngine(DeviceEngine):
             len(takes_d) + (len(deltas_d[0]) if deltas_d else 0),
         )
         t0 = time.perf_counter_ns()
-        with self._state_mu, _annotate("mesh_step"):
+        with self._state_mu, _dispatch("mesh_step"):
             self.state, out = self._step(self.state, take_dev, merge_dev)
         _obs_stage(
             hist.STAGE_DISPATCH, t0, trace_mod.EV_COMMIT_DISPATCH,

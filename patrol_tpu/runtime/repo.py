@@ -57,11 +57,12 @@ class TPURepo:
             self._maybe_incast(name)
         return ticket
 
-    def submit_takes_batch(self, names, rates, counts):
+    def submit_takes_batch(self, names, rates, counts, batch: int = 0):
         """Batched :meth:`submit_take` (native HTTP pump): one engine
         directory pass, then the per-created incast solicitations.
-        → [(ticket, created), ...] or None on a fully-pinned pool."""
-        res = self.engine.submit_takes_batch(names, rates, counts)
+        ``batch`` is the pump batch id the tickets carry. → [(ticket,
+        created), ...] or None on a fully-pinned pool."""
+        res = self.engine.submit_takes_batch(names, rates, counts, batch=batch)
         if res is None:
             return None
         for (ticket, created), name in zip(res, names):

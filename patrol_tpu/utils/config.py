@@ -56,8 +56,6 @@ _DECLARED: Tuple[Knob, ...] = (
          "Max in-flight device dispatches before the engine awaits."),
     Knob("PATROL_DEVICE_TIMING", "1",
          "Record per-kernel device timings into patrol-scope (0 = off)."),
-    Knob("PATROL_DEVICE_ANNOTATIONS", "0",
-         "Emit jax named_scope annotations for profiler traces (1 = on)."),
     Knob("PATROL_MERGE_KERNEL", "scatter",
          "Merge kernel select: scatter | auto | pallas (compiled at engine start)."),
     Knob("PATROL_TICK_FOLD", "1",

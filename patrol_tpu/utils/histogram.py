@@ -75,6 +75,24 @@ class LatticeHistogram:
             self._counts[self.node_slot][b] += 1
             self._sums[self.node_slot] += v
 
+    def record_many(self, values: Iterable[int]) -> None:
+        """:meth:`record` each of ``values`` under one lock acquisition: a
+        pump batch's per-take stages cost one call. Plain Python on
+        purpose: numpy's ``bincount`` drops the interpreter lock even for
+        a few elements, and on a busy node taking it back can cost the
+        serving thread a whole switch interval (5 ms) per call."""
+        with self._mu:
+            lane = self._counts[self.node_slot]
+            total = 0
+            for v in values:
+                v = int(v)
+                if v < 0:
+                    v = 0
+                b = v.bit_length()
+                lane[b if b < NBUCKETS else NBUCKETS - 1] += 1
+                total += v
+            self._sums[self.node_slot] += total
+
     # -- lattice -------------------------------------------------------------
 
     def _grow(self, nodes: int) -> None:
@@ -228,6 +246,15 @@ TAKE_SERVICE = HISTOGRAMS.get("take_service_ns")
 RX_APPLY = HISTOGRAMS.get("replication_rx_apply_ns")
 AE_JOB = HISTOGRAMS.get("ae_job_ns")
 FRONT_WAIT = HISTOGRAMS.get("http_front_wait_ns")
+# A native-front take's latency tiled across the seam, one sample per
+# take: parse → poll returned (queue), poll returned → ticket created
+# (submit), take_service_ns (ticket created → complete), complete →
+# answers handed back to the C++ front (answer). take_queue_ns is the
+# part of take_service_ns before the feeder drained the ticket.
+FRONT_QUEUE = HISTOGRAMS.get("http_front_queue_ns")
+FRONT_SUBMIT = HISTOGRAMS.get("http_front_submit_ns")
+FRONT_ANSWER = HISTOGRAMS.get("http_front_answer_ns")
+TAKE_QUEUE = HISTOGRAMS.get("take_queue_ns")
 # Device-side stage histograms (patrol-fleet, ROADMAP item 1's r06
 # capture): dispatch→ready wall time of the engine's commit and take
 # kernels, measured on the completion pipeline (block_until_ready /

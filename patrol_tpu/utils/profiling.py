@@ -338,6 +338,17 @@ class CounterRegistry:
         "take_rows_coalesced",
         "take_tickets_folded",
         "take_partial_grants",
+        # Packed take rows dispatched to the take kernel, summed over
+        # ticks: tickets over rows is the whole take fold (open-entry
+        # folds plus same-key merges at packing).
+        "take_rows_dispatched",
+        # Busy ns of the replication send path: every delta-plane flush
+        # (net/delta.py) and state fan-out (broadcast.tx), summed.
+        "repl_send_ns",
+        # XLA backend compiles (and their ns) seen by the jax.monitoring
+        # listener (count_jax_compiles): 0 in a warm serving window.
+        "jit_compiles",
+        "jit_compile_ns",
     )
 
     def __init__(self):
@@ -369,6 +380,30 @@ class CounterRegistry:
 
 
 COUNTERS = CounterRegistry()
+
+# jax.monitoring's duration event around every XLA backend compile
+# (a persistent-cache hit included, an in-memory jit cache hit not).
+JAX_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_listener_mu = threading.Lock()
+_compile_listener_on = False
+
+
+def count_jax_compiles() -> None:
+    """Count JAX's backend compiles into ``jit_compiles`` and
+    ``jit_compile_ns`` (idempotent; the engine calls it at import)."""
+    global _compile_listener_on
+    with _compile_listener_mu:
+        if _compile_listener_on:
+            return
+        import jax.monitoring
+
+        def on_duration(event: str, duration_s: float, **_kw) -> None:
+            if event == JAX_COMPILE_EVENT:
+                COUNTERS.inc("jit_compiles")
+                COUNTERS.inc("jit_compile_ns", int(duration_s * 1e9))
+
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        _compile_listener_on = True
 
 
 class ProfiledLock:
@@ -491,20 +526,42 @@ class ProfilerBusyError(RuntimeError):
 _jax_trace_mu = threading.Lock()
 
 
+def capture_options():
+    """Profiler options for a capture of the serving node: no Python
+    tracer (it hooks every Python call on every thread and slows the node
+    it measures many times over), the host tracer at the level that keeps
+    the ``TraceMe`` annotations and the runtime's enqueue events."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    return opts
+
+
 def jax_trace(duration_s: float = 2.0, out_dir: Optional[str] = None) -> str:
     """Capture a JAX profiler trace (XPlane; viewable in perfetto /
-    tensorboard). Returns the dump directory. Raises
-    :class:`ProfilerBusyError` when a capture is already in flight."""
+    tensorboard). Returns the dump directory. While it runs the flight
+    recorder's spans also land in the capture (``patrol.<event>``), and
+    a ``patrol.clock`` annotation at its start and stop anchors the
+    ring's clock to its timeline. Raises :class:`ProfilerBusyError` when
+    a capture is already in flight."""
     if not _jax_trace_mu.acquire(blocking=False):
         raise ProfilerBusyError("a jax trace capture is already running")
     try:
         import jax
 
+        from patrol_tpu.utils import trace
+
         out = out_dir or tempfile.mkdtemp(prefix="patrol-jax-trace-")
-        jax.profiler.start_trace(out)
+        jax.profiler.start_trace(out, profiler_options=capture_options())
         try:
+            trace.clock_anchor()
+            trace.TRACE.capturing = True
             time.sleep(min(duration_s, 30.0))
         finally:
+            trace.TRACE.capturing = False
+            trace.clock_anchor()
             jax.profiler.stop_trace()
         return out
     finally:

@@ -42,7 +42,8 @@ int pt_http_start(const char* ip, uint16_t port);
 int pt_http_port(int h);
 int pt_http_poll(int h, int timeout_ms, uint64_t* tags, int32_t* streams,
                  uint8_t* names, int* name_lens, int64_t* freqs,
-                 int64_t* pers, int64_t* counts, int cap_t, uint64_t* otags,
+                 int64_t* pers, int64_t* counts, int64_t* req_ns, int cap_t,
+                 uint64_t* otags,
                  int32_t* ostreams, uint8_t* otargets, int* otarget_lens,
                  uint8_t* omethods, int cap_o, int* n_other);
 int pt_http_complete_takes(int h, const uint64_t* tags,
@@ -322,12 +323,14 @@ int main() {
     std::vector<uint8_t> names((size_t)CT * kPacket),
         otargets((size_t)CO * kPathMax), omethods((size_t)CO * 8);
     std::vector<int> nlens(CT), otlens(CO), statuses(CT);
-    std::vector<int64_t> freqs(CT), pers(CT), counts(CT), remaining(CT);
+    std::vector<int64_t> freqs(CT), pers(CT), counts(CT), req_ns(CT),
+        remaining(CT);
     while (!stop.load()) {
       int n_other = 0;
       int nt = pt_http_poll(hs, 10, tags.data(), streams.data(),
                             names.data(), nlens.data(), freqs.data(),
-                            pers.data(), counts.data(), CT, otags.data(),
+                            pers.data(), counts.data(), req_ns.data(), CT,
+                            otags.data(),
                             ostreams.data(), otargets.data(), otlens.data(),
                             omethods.data(), CO, &n_other);
       if (nt < 0) return;

@@ -401,6 +401,28 @@ class TestEffectsTable:
         assert NATIVE_EFFECTS["pt_http_poll"].blocks
         assert not NATIVE_EFFECTS["pt_hls_events"].takes_host_mu
 
+    @pytest.mark.parametrize(
+        "symbol", ["pt_http_poll", "pt_http_complete_takes", "pt_http_stats"]
+    )
+    def test_http_front_argtypes_match_the_c_definition(self, symbol):
+        """The ctypes registration passes exactly the C definition's
+        parameters (pt_http_poll's req_ns parse-stamp array included): a
+        missing pointer would shift every later argument."""
+        import re
+
+        lib = native.load()
+        if lib is None:
+            pytest.skip("native toolchain unavailable")
+        src = os.path.join(
+            os.path.dirname(native.__file__), "patrol_http.cpp"
+        )
+        with open(src) as f:
+            text = f.read()
+        m = re.search(rf"\nint {symbol}\(([^)]*)\)\s*{{", text)
+        assert m, symbol
+        params = [p for p in m.group(1).split(",") if p.strip()]
+        assert len(getattr(lib, symbol).argtypes) == len(params)
+
 
 # --- suppression + drivers ---------------------------------------------------
 

@@ -324,3 +324,179 @@ class TestConnectionHandling:
         # Status split: every /take answer here is a 200 or a 429.
         assert int(out[3]) + int(out[4]) == int(out[0])
         assert int(out[3]) > 0  # 1000/s bucket admits plenty in 500 ms
+
+
+def _take_burst(port: int, names, rate: str = "1000:1s"):
+    """Pipeline one take per name on one connection; → statuses."""
+    import http.client
+
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=10)
+    try:
+        out = []
+        for name in names:
+            conn.request("POST", f"/take/{name}?rate={rate}")
+            resp = conn.getresponse()
+            resp.read()
+            out.append(resp.status)
+        return out
+    finally:
+        conn.close()
+
+
+SEAM = (
+    "http_front_queue_ns",
+    "http_front_submit_ns",
+    "take_service_ns",
+    "http_front_answer_ns",
+)
+
+
+@pytest.fixture
+def seam_front(monkeypatch):
+    """A front of its own whose seam histograms are fresh: no other
+    front's or engine's takes land in them."""
+    from patrol_tpu.net.native_http import NativeHTTPFront
+    from patrol_tpu.runtime import engine as engine_mod
+    from patrol_tpu.utils import histogram as hist
+
+    # Every take rides the device queue (and so a feeder tick).
+    monkeypatch.setattr(engine_mod, "HOST_FASTPATH", False)
+    hists = {}
+    for attr, name in zip(
+        ("FRONT_QUEUE", "FRONT_SUBMIT", "TAKE_SERVICE", "FRONT_ANSWER"), SEAM
+    ):
+        hists[name] = hist.LatticeHistogram(name)
+        monkeypatch.setattr(hist, attr, hists[name])
+    engine = DeviceEngine(LimiterConfig(buckets=64, nodes=4), node_slot=0)
+    f = NativeHTTPFront(API(TPURepo(engine), stats=lambda: {}), "127.0.0.1", 0)
+    monkeypatch.undo()  # fronts and engines made later record globally again
+    monkeypatch.setattr(engine_mod, "HOST_FASTPATH", False)
+    yield f, hists
+    f.close()
+    engine.stop()
+
+
+class TestSeamTiling:
+    """A take's latency tiled across the HTTP-to-engine seam: parse →
+    poll returned → ticket created → complete → handed back."""
+
+    def test_stages_count_every_take_and_sum_to_parse_to_handed(
+        self, seam_front, monkeypatch
+    ):
+        import time
+
+        from patrol_tpu.net import native_http
+        from patrol_tpu.utils import trace as trace_mod
+
+        front, hists = seam_front
+        seen = []
+        record = native_http.NativeHTTPFront._record_seam
+
+        def spy(self, req_ns, t_poll, tickets, t_handed):
+            if self is front:
+                seen.append((
+                    np.array(req_ns), t_poll,
+                    [(t.t0_ns, t.done_ns, t.batch) for t in tickets], t_handed,
+                ))
+            record(self, req_ns, t_poll, tickets, t_handed)
+
+        monkeypatch.setattr(native_http.NativeHTTPFront, "_record_seam", spy)
+        t_send = time.perf_counter_ns()
+        names = [f"seam{i}" for i in range(24)]
+        statuses = _take_burst(front.port, names)
+        assert all(s in (200, 429) for s in statuses)
+        deadline = time.monotonic() + 5
+        while sum(len(b[2]) for b in seen) < len(names):
+            assert time.monotonic() < deadline, "batches never recorded"
+            time.sleep(0.01)
+        for name in SEAM:
+            assert hists[name].count == len(names), name
+        # Per take, the four stages are ordered stamps: parse ≤ poll ≤
+        # created ≤ complete ≤ handed back, so they tile exactly.
+        want = 0
+        service = 0
+        for req_ns, t_poll, tix, t_handed in seen:
+            for r, (t0, done, _) in zip(req_ns.tolist(), tix):
+                assert t_send <= r <= t_poll <= t0 <= done <= t_handed
+                want += t_handed - r
+                service += done - t0
+        assert sum(hists[k].total for k in SEAM) == want
+        assert hists["take_service_ns"].total == service
+        # One request id across the seam: the answer span carries the
+        # batch id its tickets carry, and a feeder tick names the oldest
+        # batch it drained as its cause.
+        batches = {b for _, _, tix, _ in seen for _, _, b in tix}
+        assert 0 not in batches
+        ring = trace_mod.TRACE.dump()
+        assert batches <= {e["id"] for e in ring if e["type"] == "http.answer"}
+        assert batches <= {e["id"] for e in ring if e["type"] == "http.pump"}
+        assert {e["cause"] for e in ring if e["type"] == "engine.tick"} & batches
+
+    def test_parse_stamp_shares_the_perf_counter_clock(self, seam_front, monkeypatch):
+        """The C++ parse stamp (steady_clock) is perf_counter_ns's clock:
+        it lies between the client's send and the pump's poll return."""
+        import time
+
+        from patrol_tpu.net import native_http
+
+        front, _ = seam_front
+        polled = []
+        record = native_http.NativeHTTPFront._record_seam
+
+        def spy(self, req_ns, t_poll, tickets, t_handed):
+            if self is front:
+                polled.append((int(req_ns.min()), int(req_ns.max()), t_poll))
+            record(self, req_ns, t_poll, tickets, t_handed)
+
+        monkeypatch.setattr(native_http.NativeHTTPFront, "_record_seam", spy)
+        t_send = time.perf_counter_ns()
+        _take_burst(front.port, ["clock-a", "clock-b"])
+        deadline = time.monotonic() + 5
+        while not polled:
+            assert time.monotonic() < deadline
+            time.sleep(0.01)
+        for lo, hi, t_poll in polled:
+            assert t_send <= lo <= hi <= t_poll <= time.perf_counter_ns()
+
+
+class TestCompleterStall:
+    def test_stalled_tick_snapshots_take_stall_and_still_answers(
+        self, front, monkeypatch
+    ):
+        """The completer waits a bounded second at a time: a feeder held
+        past it fires the take-stall anomaly snapshot on the served path,
+        and the take is answered once the feeder resumes."""
+        import threading
+        import time
+
+        from patrol_tpu.runtime import engine as engine_mod
+        from patrol_tpu.utils import trace as trace_mod
+
+        monkeypatch.setattr(engine_mod, "HOST_FASTPATH", False)
+        tr = trace_mod.TRACE
+        with tr._snap_mu:
+            tr._last_anomaly.pop("take-stall", None)
+        t_pause = time.perf_counter_ns()
+        eng = front.api.repo.engine
+        with eng._cond:
+            eng._tick_paused = True
+        out = []
+        client = threading.Thread(
+            target=lambda: out.extend(_take_burst(front.port, ["stalled"]))
+        )
+        client.start()
+        try:
+            deadline = time.monotonic() + 5
+            while not any(
+                s["reason"] == "take-stall" and s["at_ns"] >= t_pause
+                for s in tr.snapshots()
+            ):
+                assert time.monotonic() < deadline, "no take-stall snapshot"
+                time.sleep(0.05)
+            assert not out  # still waiting on the paused feeder
+        finally:
+            with eng._cond:
+                eng._tick_paused = False
+                eng._cond.notify_all()
+            client.join(10)
+        assert out == [200]

@@ -507,3 +507,230 @@ class TestTraceTrailerWire:
         assert dbuf.slots[0] == 2
         assert dbuf.caps[0] == 3 * 10**9
         assert dbuf.lane_a[0] == 7 and dbuf.lane_t[0] == 3
+
+
+class TestSpans:
+    def test_span_records_start_duration_id_and_cause(self, recorder):
+        t_before = time.perf_counter_ns()
+        with recorder.span(trace_mod.EV_HTTP_PUMP, 3, id=7, cause=5) as sp:
+            time.sleep(0.001)
+            sp.arg = 4
+        tick = recorder.span(trace_mod.EV_TICK).begin()
+        tick.cause = 7
+        tick.end()
+        pump, ev = recorder.dump()
+        assert (pump["type"], pump["arg"], pump["id"], pump["cause"]) == (
+            "http.pump", 4, 7, 5,
+        )
+        assert t_before <= pump["t_ns"] and pump["dur_ns"] >= 1_000_000
+        assert ev["type"] == "engine.tick" and ev["cause"] == 7
+        assert ev["t_ns"] >= pump["t_ns"] + pump["dur_ns"]
+        doc = json.loads(recorder.chrome_trace())
+        assert doc["traceEvents"][0]["args"]["id"] == 7
+
+    def test_disabled_span_is_one_shared_noop(self, recorder):
+        recorder.enabled = False
+        a = recorder.span(trace_mod.EV_GC_SWEEP)
+        with a as sp:
+            sp.arg = 9
+        assert a is recorder.span(trace_mod.EV_TICK)
+        assert recorder.dump() == []
+
+    def test_event_types_register_once(self):
+        k = trace_mod.event_type("test.kernel")
+        assert trace_mod.event_type("test.kernel") == k
+        assert trace_mod.EVENT_NAMES[k] == "test.kernel"
+        assert trace_mod.event_type("engine.tick") == trace_mod.EV_TICK
+
+    def test_record_many_matches_record(self):
+        import numpy as np
+
+        vals = [0, 1, 2, 3, 7, 8, 1023, 1024, 10**6, 2**62, 2**63 - 1, -5]
+        one, many = LatticeHistogram("a"), LatticeHistogram("a")
+        for v in vals:
+            one.record(v)
+        many.record_many(np.array(vals, np.int64))
+        many.record_many(np.array([], np.int64))
+        assert one.to_lattice() == many.to_lattice()
+
+
+def _capture_with(tmp_path, work):
+    """Run ``work()`` inside a CPU ``jax_trace`` capture (from another
+    thread, once the capture runs); → the capture's .xplane.pb path."""
+    import threading
+
+    from perfbench import xplane
+    from patrol_tpu.utils import profiling
+
+    done = threading.Event()
+
+    def inside():
+        deadline = time.monotonic() + 10
+        while not trace_mod.TRACE.capturing:
+            assert time.monotonic() < deadline
+            time.sleep(0.001)
+        work()
+        done.set()
+
+    t = threading.Thread(target=inside)
+    t.start()
+    out = profiling.jax_trace(0.5, out_dir=str(tmp_path))
+    t.join(10)
+    assert done.is_set()
+    return xplane.find_xplane(out)
+
+
+class TestCapture:
+    """The flight recorder's spans on the device trace's clock."""
+
+    def test_span_lands_in_capture_only_while_it_runs(self, tmp_path):
+        from perfbench import xplane
+
+        tr = trace_mod.TRACE
+        before = trace_mod.event_type("test.before")
+        inside = trace_mod.event_type("test.inside")
+        after = trace_mod.event_type("test.after")
+        with tr.span(before):
+            pass
+
+        def work():
+            with tr.span(inside):
+                time.sleep(0.002)
+
+        path = _capture_with(tmp_path, work)
+        with tr.span(after):
+            pass
+        names = {e.name for e in xplane.load_events(path)}
+        assert "patrol.test.inside" in names
+        assert not names & {"patrol.test.before", "patrol.test.after"}
+        assert "patrol.clock" in names
+
+    def test_capture_holds_no_python_tracer_events(self, tmp_path):
+        import re
+
+        import jax
+        import numpy as np
+        from jax.profiler import ProfileData
+
+        f = jax.jit(lambda x: x + 1)
+
+        def work():
+            for _ in range(3):
+                sum(range(10_000))
+                f(np.arange(4)).block_until_ready()
+
+        path = _capture_with(tmp_path, work)
+        names = [
+            e.name
+            for plane in ProfileData.from_file(path).planes
+            for line in plane.lines
+            for e in line.events
+        ]
+        assert names
+        assert not [n for n in names if re.search(r"\.py:\d+", n)]
+
+    def test_clock_anchor_maps_ring_span_onto_capture(self, tmp_path):
+        from jax.profiler import ProfileData
+
+        tr = trace_mod.TRACE
+        ev = trace_mod.event_type("test.anchored")
+
+        def work():
+            with tr.span(ev, id=4242):
+                time.sleep(0.001)
+
+        path = _capture_with(tmp_path, work)
+        clocks, spans = [], []
+        for plane in ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("patrol.clock"):
+                        clocks.append((e.start_ns, dict(e.stats)["mono_ns"]))
+                    elif e.name == "patrol.test.anchored":
+                        spans.append(e.start_ns)
+        assert len(clocks) == 2 and len(spans) == 1
+        offset = clocks[0][0] - int(clocks[0][1])
+        # The stop anchor reads the same offset.
+        assert abs(clocks[1][0] - int(clocks[1][1]) - offset) < 100_000
+        ring = [e for e in tr.dump() if e["type"] == "test.anchored" and e["id"] == 4242]
+        assert len(ring) == 1
+        assert abs(ring[0]["t_ns"] + offset - spans[0]) < 100_000
+
+
+class TestCounters:
+    def test_jit_compiles_counts_a_fresh_compile_not_a_cache_hit(self):
+        import jax
+        import numpy as np
+
+        from patrol_tpu.runtime import engine  # noqa: F401 - installs the listener
+        from patrol_tpu.utils import profiling
+
+        c = profiling.COUNTERS
+        f = jax.jit(lambda x: x * 3 + 1)
+        x = np.arange(5)
+        n0, ns0 = c.get("jit_compiles"), c.get("jit_compile_ns")
+        f(x).block_until_ready()
+        n1, ns1 = c.get("jit_compiles"), c.get("jit_compile_ns")
+        f(x).block_until_ready()
+        assert n1 - n0 == 1 and ns1 > ns0
+        assert c.get("jit_compiles") == n1
+
+    def test_take_rows_dispatched_against_tickets(self, monkeypatch):
+        from patrol_tpu.models.limiter import LimiterConfig
+        from patrol_tpu.ops.rate import Rate
+        from patrol_tpu.runtime import engine as engine_mod
+        from patrol_tpu.utils import profiling
+
+        monkeypatch.setattr(engine_mod, "HOST_FASTPATH", False)
+        c = profiling.COUNTERS
+        eng = engine_mod.DeviceEngine(LimiterConfig(buckets=64, nodes=4), node_slot=0)
+        rate = Rate(freq=100, per_ns=10**9)
+        try:
+            for names in (
+                [f"distinct{i}" for i in range(16)],  # one row per ticket
+                ["hot"] * 16,  # one key: folded onto few rows
+            ):
+                rows0 = c.get("take_rows_dispatched")
+                with eng._cond:
+                    eng._tick_paused = True
+                res = eng.submit_takes_batch(names, [rate] * len(names), [1] * len(names))
+                with eng._cond:
+                    eng._tick_paused = False
+                    eng._cond.notify_all()
+                for t, _ in res:
+                    assert t.wait(10)
+                rows = c.get("take_rows_dispatched") - rows0
+                if len(set(names)) == len(names):
+                    assert rows == len(names)
+                else:
+                    assert 1 <= rows < len(names)
+        finally:
+            eng.stop()
+
+    def test_completion_stamp_rides_the_two_argument_complete(self, monkeypatch):
+        """The engine completes tickets through ``complete(remaining, ok)``
+        (a wrapper of that form, as the benchmark's fault nodes plant,
+        still serves), and the ticket's ``done_ns`` is the stamp its
+        take_service_ns sample used."""
+        from patrol_tpu.models.limiter import LimiterConfig
+        from patrol_tpu.ops.rate import Rate
+        from patrol_tpu.runtime import engine as engine_mod
+        from patrol_tpu.utils import histogram as hist
+
+        orig = engine_mod.TakeTicket.complete
+        monkeypatch.setattr(
+            engine_mod.TakeTicket, "complete",
+            lambda self, remaining, ok: orig(self, remaining, ok),
+        )
+        for fastpath in (True, False):
+            monkeypatch.setattr(engine_mod, "HOST_FASTPATH", fastpath)
+            service = LatticeHistogram("take_service_ns")
+            monkeypatch.setattr(hist, "TAKE_SERVICE", service)
+            eng = engine_mod.DeviceEngine(LimiterConfig(buckets=16, nodes=2), node_slot=0)
+            try:
+                ticket, _ = eng.submit_take(f"stamp{fastpath}", Rate(freq=5, per_ns=10**9), 1)
+                assert ticket.wait(10) and ticket.ok
+                assert service.count == 1
+                assert service.total == ticket.done_ns - ticket.t0_ns
+            finally:
+                eng.stop()
