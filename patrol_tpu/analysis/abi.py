@@ -25,7 +25,10 @@ production uses):
    fold's output through the *registered jax kernel roots*
    (``ops/obligations.py::PROVE_ROOTS``) and comparing against the raw
    batch through ``merge_batch``: the two paths into device state must
-   be indistinguishable.
+   be indistinguishable. ``pt_raw_walk`` (the rx thread's dv2 structure
+   walk) is held to the numpy ``ops.ingest.host_walk`` the same way, and
+   to its binding: argument types as the C definition declares them, the
+   interpreter lock released, every output written within its size.
 
 2. **Merge laws on the native side** (PTA002 commutativity / batch-order
    freedom, PTA003 idempotence under duplication + monotonicity) — the
@@ -1542,6 +1545,155 @@ def check_rxring_interleavings(ob: AbiObligation, lib=None) -> List[Finding]:
 
 
 # ===========================================================================
+# Pass 1c — PTA001 for pt_raw_walk, the rx thread's dv2 structure walk.
+
+# C parameter type → what its ctypes registration must declare: a numpy
+# pointer of that dtype, or the scalar ctypes type.
+_C_PARAM_TYPES = {
+    "uint8_t*": np.dtype(np.uint8),
+    "int32_t*": np.dtype(np.int32),
+    "int64_t*": np.dtype(np.int64),
+    "uint64_t*": np.dtype(np.uint64),
+    "int": ctypes.c_int,
+}
+_WALK_POISON = 0xA5  # every output byte before the call: unwritten shows
+
+
+def _c_param_types(symbol: str) -> List[str]:
+    """Parameter types of ``symbol``'s definition in patrol_host.cpp, with
+    ``const`` and the names dropped (``const uint8_t* planes`` → ``uint8_t*``)."""
+    with open(os.path.join(_REPO_ROOT, _HOST_CPP), encoding="utf-8") as fh:
+        m = re.search(rf"\nint {symbol}\(([^)]*)\)\s*{{", fh.read())
+    if m is None:
+        return []
+    out = []
+    for p in m.group(1).split(","):
+        p = p.replace("const ", "").strip()
+        out.append(p[: p.rindex("*") + 1] if "*" in p else p.split()[0])
+    return [t.replace(" ", "") for t in out]
+
+
+def _raw_walk_corpus() -> Tuple[np.ndarray, np.ndarray]:
+    """A plane batch for the walk: valid dv2 datagrams beside flipped,
+    truncated, over-long, bit-63 (checksum fixed up) and random rows, then
+    zero-length padding rows whose stale bytes hold whole datagrams."""
+    from patrol_tpu.ops import wire
+
+    rng = np.random.default_rng(20261016)
+    row = 1024
+    raw = []
+    for i in range(48):
+        ents = [
+            wire.DeltaEntry(
+                f"k{int(rng.integers(0, 99))}", int(rng.integers(0, 3)),
+                *(int(v) for v in rng.integers(0, 1 << 40, 4)),
+            )
+            for _ in range(int(rng.integers(0, 12)))
+        ]
+        acks = [int(a) for a in rng.integers(0, 1 << 32, int(rng.integers(0, 4)))]
+        b = bytearray(
+            wire.encode_delta_packet(
+                1, int(rng.integers(1, 1 << 32)), acks, ents, max_size=row
+            )[0]
+        )
+        kind = i % 6
+        if kind == 1:
+            b[int(rng.integers(0, len(b)))] ^= 0x41
+        elif kind == 2:
+            b = b[: int(rng.integers(1, len(b)))]
+        elif kind == 3:
+            b += bytes(rng.integers(0, 256, 3).astype(np.uint8))
+        elif kind == 4 and ents:
+            off = 32 + 8 + 4 * len(acks) + 2  # the first entry's name_len
+            b[off + 1 + b[off] + 2] |= 0x80  # its cap's top byte
+            b[-1] = sum(b[32:-1]) & 0xFF
+        elif kind == 5:
+            b = bytearray(rng.integers(0, 256, int(rng.integers(1, 200))).astype(np.uint8))
+        raw.append(bytes(b))
+    pad = 8
+    planes = np.full((len(raw) + pad, row), 0x5A, np.uint8)
+    lengths = np.zeros(len(raw) + pad, np.int64)
+    for i, b in enumerate(raw):
+        planes[i, : len(b)] = np.frombuffer(b, np.uint8)
+        lengths[i] = len(b)
+    planes[len(raw):] = planes[:pad]
+    return planes, lengths
+
+
+def check_raw_walk_conformance(ob: AbiObligation, lib) -> List[Finding]:
+    """PTA001 for pt_raw_walk: (1) the ctypes argument types match the C
+    definition one for one; (2) the binding releases the interpreter lock
+    (a CDLL function, not a PyDLL one: the walk exists so that the rx
+    thread takes the lock back once a batch); (3) over the corpus, every
+    output is written whole and nothing past its declared size, each
+    array filled beforehand with a poison byte and given a guard row; and
+    (4) every RawWalk field equals ops.ingest.host_walk's, element by
+    element (resolved at check time, so a perturbed reference is what
+    gets compared)."""
+    from patrol_tpu.ops import ingest
+
+    site = _cpp_site("pt_raw_walk")
+    findings: List[Finding] = []
+
+    def emit(msg: str) -> None:
+        findings.append(Finding("PTA001", *site, f"[{ob.name}] {msg}"))
+
+    fn = lib.pt_raw_walk
+    want_types = _c_param_types("pt_raw_walk")
+    got_types = list(fn.argtypes or ())
+    if not want_types or len(want_types) != len(got_types):
+        emit(
+            f"ctypes declares {len(got_types)} arguments, the C definition "
+            f"{len(want_types)}: every later argument would be misread"
+        )
+        return findings
+    for i, (c_type, declared) in enumerate(zip(want_types, got_types)):
+        want = _C_PARAM_TYPES.get(c_type)
+        got = getattr(declared, "_dtype_", declared)
+        if want is None or got != want:
+            emit(f"argument {i}: C {c_type}, ctypes declares {declared}")
+    if findings:
+        return findings
+    if fn._flags_ & ctypes._FUNCFLAG_PYTHONAPI:
+        emit(
+            "bound through PyDLL: the call holds the interpreter lock for "
+            "the whole walk, so every other thread waits on the rx thread"
+        )
+
+    planes, lengths = _raw_walk_corpus()
+    P, row = planes.shape
+    E = ingest.max_entries(row)
+    ref = ingest.host_walk(planes, lengths)
+    outs = {}
+    for field in ingest.RawWalk._fields:
+        r = getattr(ref, field)
+        dt = np.dtype(np.uint8 if r.dtype == bool else r.dtype)
+        shape = (P + 1,) + r.shape[1:]  # one guard row past the output
+        outs[field] = (
+            np.full(int(np.prod(shape)) * dt.itemsize, _WALK_POISON, np.uint8)
+            .view(dt)
+            .reshape(shape)
+        )
+    rc = fn(planes, lengths, P, row, E, *outs.values())
+    if rc != 0:
+        emit(f"refused a {P}x{row} batch (rc={rc})")
+        return findings
+    for field, arr in outs.items():
+        guard = arr[P:].view(np.uint8)
+        if (guard != _WALK_POISON).any():
+            emit(f"{field}: wrote past its {P} rows")
+        want = getattr(ref, field)
+        got = arr[:P].view(bool) if want.dtype == bool else arr[:P]
+        if not np.array_equal(got, want):
+            bad = np.argwhere(np.asarray(got != want))[:3].tolist()
+            emit(
+                f"{field} diverges from host_walk at {bad} (an element left "
+                "unwritten reads as the poison byte)"
+            )
+    return findings
+
+
+# ===========================================================================
 # Pass 4 — PTA005: effects-table completeness.
 
 _ARGTYPES_RE = re.compile(r"lib\.(pt_\w+)\.argtypes")
@@ -1597,6 +1749,7 @@ _CHECKS: Dict[str, Callable] = {
     "fold_conformance": check_fold_conformance,
     "rxring_interleavings": check_rxring_interleavings,
     "classify_conformance": check_classify_conformance,
+    "raw_walk_conformance": check_raw_walk_conformance,
     "hls_interleavings": check_hls_interleavings,
     "effects_table": check_effects_table,
 }

@@ -86,6 +86,7 @@ NATIVE_EFFECTS: Dict[str, NativeEffect] = {
     "pt_send_fanout": _E(True, False, False, False),  # POLLOUT stall wait
     "pt_decode_batch": _E(False, False, False, True),
     "pt_encode_batch": _E(False, False, False, True),
+    "pt_raw_walk": _E(False, False, False, True),
     # -- zero-copy rx ring (device-resident ingest) --
     # pt_rx_ring_create allocates C++-OWNED page-aligned planes that
     # Python views zero-copy via pt_rx_ring_plane until destroy: the
@@ -280,6 +281,12 @@ def load() -> Optional[ctypes.CDLL]:
             ctypes.c_int, _u8p, _i32p,
         ]
         lib.pt_encode_batch.restype = ctypes.c_int
+        lib.pt_raw_walk.argtypes = [
+            _u8p, _i64p, ctypes.c_int, ctypes.c_int, ctypes.c_int,
+            _u8p, _i32p, _i64p, _i32p, _i64p, _i32p, _i32p, _i32p, _u64p,
+            _i64p, _i64p, _i64p, _i64p, _i64p,
+        ]
+        lib.pt_raw_walk.restype = ctypes.c_int
         # -- HTTP front (patrol_http.cpp) --
         lib.pt_http_start.argtypes = [ctypes.c_char_p, ctypes.c_uint16]
         lib.pt_http_start.restype = ctypes.c_int

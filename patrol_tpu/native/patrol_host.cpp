@@ -32,6 +32,7 @@
 #include <unistd.h>
 
 #include <algorithm>
+#include <initializer_list>
 #include <memory>
 #include <mutex>
 #include <shared_mutex>
@@ -564,6 +565,144 @@ int pt_encode_batch(const double* added, const double* taken,
     ok++;
   }
   return ok;
+}
+
+// ---- pt_raw_walk: the dv2 structure walk of one rx plane batch -------------
+//
+// The C++ twin of ops/ingest.py::host_walk, bit-identical on every RawWalk
+// field (tests/test_ingest.py pins it over the hostile corpus). One call
+// walks the whole batch, so the rx thread releases the interpreter lock
+// once instead of at every numpy call of the per-ordinal walk. Framing
+// mirrors ops/wire.py's dv2 codec: envelope (24 zero bytes, reserved
+// name), version u8 | sender_slot u16 | seq u32 | n_acks u8, n_acks x u32,
+// count u16, count x (name_len u8, name, slot u16, cap/added/taken/elapsed
+// u64), checksum u8 = sum(data[32:len-1]).
+//
+// planes is n rows of `row` bytes, lengths the datagram size of each row.
+// Every output element is written (callers allocate uninitialised):
+// ok/sender_slot/seq/n_acks/count per row, acks at kDv2MaxAcks per row,
+// the entry fields at max_e per row. Verdicts are all-or-nothing per
+// packet. name_off/name_len are the framing PROPOSAL the device kernel
+// re-validates, so they are filled for every walkable packet, even one
+// whose checksum or envelope fails; value fields and name_hash are zero
+// for refused packets. Returns 0, or -EINVAL for a row too short to hold
+// the header (host_walk's reads would leave the row there).
+
+namespace {
+
+constexpr int kDv2Base = 32;  // 25-byte v1 header + 7-byte reserved name
+constexpr int kDv2Head = 8;
+constexpr int kDv2Ack = 4;
+constexpr int kDv2Count = 2;
+constexpr int kDv2EntryTail = 34;
+constexpr int kDv2MinLen = kDv2Base + kDv2Head + kDv2Count + 1;
+constexpr int kDv2MaxAcks = 32;
+constexpr int kDv2Version = 2;
+constexpr uint8_t kDv2Name[7] = {0, 'p', 't', '!', 'd', 'v', '2'};
+
+inline uint32_t load_be32(const uint8_t* p) {
+  return (uint32_t(p[0]) << 24) | (uint32_t(p[1]) << 16) |
+         (uint32_t(p[2]) << 8) | uint32_t(p[3]);
+}
+
+}  // namespace
+
+int pt_raw_walk(const uint8_t* planes, const int64_t* lengths, int n,
+                int row, int max_e, uint8_t* ok, int32_t* sender_slot,
+                int64_t* seq, int32_t* n_acks, int64_t* acks,
+                int32_t* count, int32_t* name_off, int32_t* name_len,
+                uint64_t* name_hash, int64_t* slot, int64_t* cap,
+                int64_t* added, int64_t* taken, int64_t* elapsed) {
+  if (row < kDv2Base + kDv2Head || max_e < 1 || n < 0) return -EINVAL;
+  const size_t E = static_cast<size_t>(max_e);
+  for (int p = 0; p < n; p++) {
+    const uint8_t* b = planes + static_cast<size_t>(p) * row;
+    const size_t e0 = static_cast<size_t>(p) * E;
+    std::memset(acks + static_cast<size_t>(p) * kDv2MaxAcks, 0,
+                kDv2MaxAcks * sizeof(int64_t));
+    std::memset(name_off + e0, 0, E * sizeof(int32_t));
+    std::memset(name_len + e0, 0, E * sizeof(int32_t));
+    std::memset(name_hash + e0, 0, E * sizeof(uint64_t));
+    for (int64_t* f : {slot, cap, added, taken, elapsed}) {
+      std::memset(f + e0, 0, E * sizeof(int64_t));
+    }
+    // Header fields are read whatever the verdict (host_walk does too).
+    sender_slot[p] = (int32_t(b[kDv2Base + 1]) << 8) | b[kDv2Base + 2];
+    seq[p] = load_be32(b + kDv2Base + 3);
+    const int na = b[kDv2Base + 7];
+    const int64_t len = lengths[p];
+    const int64_t end = len - 1;  // checksum byte offset
+    const int64_t off0 = kDv2Base + kDv2Head + int64_t(kDv2Ack) * na;
+    const bool walkable = len >= kDv2MinLen && len <= row &&
+                          na <= kDv2MaxAcks && off0 + kDv2Count <= end;
+    bool good = walkable;
+    if (good) {
+      for (int k = 0; k < 24; k++) good &= b[k] == 0;
+      good &= b[24] == sizeof(kDv2Name);
+      good &= std::memcmp(b + 25, kDv2Name, sizeof(kDv2Name)) == 0;
+      // Bytes past the datagram are stale ring contents: never summed.
+      uint8_t sum = 0;
+      for (int64_t c = kDv2Base; c < end; c++) sum += b[c];
+      good &= sum == b[end];
+      good &= b[kDv2Base] == kDv2Version;
+    }
+    if (good) {
+      for (int k = 0; k < na; k++) {
+        acks[static_cast<size_t>(p) * kDv2MaxAcks + k] =
+            load_be32(b + kDv2Base + kDv2Head + kDv2Ack * k);
+      }
+    }
+    int64_t cnt = 0;
+    int64_t walked = 0;
+    int64_t off = 0;
+    if (walkable) {
+      cnt = (int64_t(b[off0]) << 8) | b[off0 + 1];
+      off = off0 + kDv2Count;
+      const int64_t lim = std::min<int64_t>(cnt, max_e);
+      for (; walked < lim; walked++) {
+        if (off >= end) break;
+        const int64_t nl = b[off];
+        if (off + 1 + nl + kDv2EntryTail > end) break;
+        name_off[e0 + walked] = static_cast<int32_t>(off + 1);
+        name_len[e0 + walked] = static_cast<int32_t>(nl);
+        off += 1 + nl + kDv2EntryTail;
+      }
+      // A framing bound hit mid-walk, a count no row can carry, and a
+      // payload that does not end exactly at the checksum all refuse.
+      good &= walked == lim && cnt <= max_e && off == end;
+    }
+    if (good) {
+      uint64_t hi = 0;
+      for (int64_t i = 0; i < walked; i++) {
+        const uint8_t* t = b + name_off[e0 + i] + name_len[e0 + i];
+        const uint64_t c = load_be64(t + 2), a = load_be64(t + 10),
+                       tk = load_be64(t + 18), el = load_be64(t + 26);
+        hi |= c | a | tk | el;
+        slot[e0 + i] = (int64_t(t[0]) << 8) | t[1];
+        cap[e0 + i] = static_cast<int64_t>(c);
+        added[e0 + i] = static_cast<int64_t>(a);
+        taken[e0 + i] = static_cast<int64_t>(tk);
+        elapsed[e0 + i] = static_cast<int64_t>(el);
+      }
+      // A value with bit 63 set refuses the whole packet.
+      if (hi >> 63) {
+        good = false;
+        for (int64_t* f : {slot, cap, added, taken, elapsed}) {
+          std::memset(f + e0, 0, E * sizeof(int64_t));
+        }
+      }
+    }
+    if (good) {
+      for (int64_t i = 0; i < walked; i++) {
+        name_hash[e0 + i] =
+            fnv1a64(b + name_off[e0 + i], name_len[e0 + i]);
+      }
+    }
+    ok[p] = good;
+    n_acks[p] = good ? na : 0;
+    count[p] = good ? static_cast<int32_t>(cnt) : 0;
+  }
+  return 0;
 }
 
 // ---- pt_dir: native bucket-name resolve table ------------------------------

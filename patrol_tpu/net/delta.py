@@ -500,7 +500,7 @@ class DeltaPlane:
         n = len(data)
         plane[0, :n] = np.frombuffer(data, np.uint8)
         lengths = np.array([n], np.int32)
-        walk = ingest_ops.host_walk(plane, lengths)
+        walk = ingest_ops.walk(plane, lengths)
         self._ingest_walk(
             eng, plane, lengths, walk, [addr],
             lambda: self._release_raw_plane(plane), t0,
@@ -527,7 +527,7 @@ class DeltaPlane:
         from patrol_tpu.ops import ingest as ingest_ops
 
         t0 = time.perf_counter_ns()
-        walk = ingest_ops.host_walk(planes, lengths)
+        walk = ingest_ops.walk(planes, lengths)
         self._ingest_walk(eng, planes, lengths, walk, addrs, release, t0)
         return True
 

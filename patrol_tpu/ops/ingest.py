@@ -15,9 +15,9 @@ state (:func:`decode_fold_raw`).
 Division of labor with the host (the part a device kernel cannot do):
 
 * **row resolution** — bucket names live in the host directory's hash
-  table, so the host runs a *vectorized structure walk*
-  (:func:`host_walk`, numpy: one python-level iteration per entry
-  ordinal, vectorized across all packets) that extracts per-entry name
+  table, so the host runs a *structure walk* (:func:`walk`: one C++ call
+  per batch, or :func:`host_walk`, the numpy reference, where the native
+  library is missing) that extracts per-entry name
   offsets/hashes and the header/ack fields, resolves rows through the
   existing directory pass, and hands the kernel a ``rows[P, E]`` plan
   (``FOLD_PAD_ROW`` marks entries the fold must skip: directory-miss
@@ -60,6 +60,7 @@ rejected (tests/test_prove.py).
 
 from __future__ import annotations
 
+import time
 from functools import partial
 from typing import NamedTuple
 
@@ -67,9 +68,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from patrol_tpu import native
 from patrol_tpu.models.limiter import LimiterState
 from patrol_tpu.ops import wire
 from patrol_tpu.ops.merge import FOLD_PAD_ROW
+from patrol_tpu.utils import profiling
 
 # Framing constants, mirrored from ops/wire.py (the codec is the spec;
 # these are the offsets its struct layout implies).
@@ -152,7 +155,8 @@ def host_walk(planes: np.ndarray, lengths: np.ndarray) -> "RawWalk":
     FNV hash) the directory pass needs and the numeric fields the
     host-lane absorb and cap-adoption tails use. One python-level loop
     iteration per entry ORDINAL (≤ :data:`MAX_RAW_ENTRIES`), each
-    vectorized across every packet still walking — not per entry."""
+    vectorized across every packet still walking — not per entry. The
+    reference the C++ walk (:func:`walk`) is held to, and its fallback."""
     planes = np.asarray(planes)
     P, row = planes.shape
     E = max_entries(row)
@@ -213,26 +217,13 @@ def host_walk(planes: np.ndarray, lengths: np.ndarray) -> "RawWalk":
     entry_seen = np.zeros((P, E), bool)
 
     # Structure walk: ONLY the cursor advance and framing bounds run
-    # per-ordinal; field extraction happens once, flat, below (34 gathers
-    # total instead of 34 per ordinal — the walk is the host hot path).
+    # per-ordinal; field extraction happens once, flat, below.
     off = np.where(walkable, off0 + _COUNT, 0).astype(np.int64)
     walking = walkable.copy()
     for i in range(E):
         active = walking & (count > i)
         if not active.any():
             break
-        if active.all():
-            # Flood fast path (every packet still walking — the common
-            # recvmmsg-sweep shape): full-array ops, no index sets.
-            in_bounds = off < end
-            nl = planes[pidx, np.minimum(off, row - 1)].astype(np.int64)
-            fits = in_bounds & (off + 1 + nl + _ENTRY_TAIL <= end)
-            if fits.all():
-                name_off[:, i] = off + 1
-                name_len[:, i] = nl
-                entry_seen[:, i] = True
-                off = off + 1 + nl + _ENTRY_TAIL
-                continue
         ai = np.flatnonzero(active)
         o = off[ai]
         # Python: ``if off >= end: return None`` then name_len = data[off];
@@ -341,6 +332,58 @@ def host_walk(planes: np.ndarray, lengths: np.ndarray) -> "RawWalk":
         taken=taken,
         elapsed=elapsed,
     )
+
+
+def _native_walk(lib, planes: np.ndarray, lengths: np.ndarray):
+    """:func:`host_walk` in one C++ call (``pt_raw_walk``): the same
+    :class:`RawWalk`, bit for bit, with the interpreter lock released once
+    for the whole batch instead of at every numpy call of the per-ordinal
+    walk. Outputs are fresh arrays the call fills whole, since the engine
+    keeps the walk's arrays past this batch. None when the call refuses
+    the geometry (a row too short for the header)."""
+    planes = np.ascontiguousarray(planes, np.uint8)
+    P, row = planes.shape
+    E = max_entries(row)
+    lengths = np.ascontiguousarray(lengths, np.int64)
+    if lengths.shape != (P,):
+        raise ValueError(f"{lengths.shape} lengths for {P} plane rows")
+    w = RawWalk(
+        ok=np.empty(P, bool),
+        sender_slot=np.empty(P, np.int32),
+        seq=np.empty(P, np.int64),
+        n_acks=np.empty(P, np.int32),
+        acks=np.empty((P, wire.DELTA_MAX_ACKS), np.int64),
+        count=np.empty(P, np.int32),
+        name_off=np.empty((P, E), np.int32),
+        name_len=np.empty((P, E), np.int32),
+        name_hash=np.empty((P, E), np.uint64),
+        slot=np.empty((P, E), np.int64),
+        cap=np.empty((P, E), np.int64),
+        added=np.empty((P, E), np.int64),
+        taken=np.empty((P, E), np.int64),
+        elapsed=np.empty((P, E), np.int64),
+    )
+    rc = lib.pt_raw_walk(
+        planes, lengths, P, row, E, w.ok.view(np.uint8), *w[1:]
+    )
+    return w if rc == 0 else None
+
+
+def walk(planes: np.ndarray, lengths: np.ndarray) -> RawWalk:
+    """The rx paths' structure walk: the native walk when the library
+    loads, :func:`host_walk` (the numpy reference) otherwise. Counts the
+    path taken (``rx_walk_native_batches`` / ``rx_walk_numpy_batches``)
+    and the walk's wall time (``rx_walk_ns``)."""
+    t0 = time.perf_counter_ns()
+    lib = native.load()
+    out = None if lib is None else _native_walk(lib, planes, lengths)
+    if out is None:
+        out = host_walk(planes, lengths)
+        profiling.COUNTERS.inc("rx_walk_numpy_batches")
+    else:
+        profiling.COUNTERS.inc("rx_walk_native_batches")
+    profiling.COUNTERS.inc("rx_walk_ns", time.perf_counter_ns() - t0)
+    return out
 
 
 def gather_name_rows(

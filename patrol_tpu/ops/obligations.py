@@ -978,6 +978,15 @@ KERNEL_FAMILIES: Tuple[KernelFamily, ...] = (
                 "native.rx_ring_schedules", "pt_rx_ring_lease", ("PTA004",),
                 "rxring_interleavings",
             ),
+            AbiObligation(
+                # The rx thread's dv2 structure walk: the ctypes argument
+                # types against the C definition, a binding that releases
+                # the interpreter lock, every output written within its
+                # declared size, and every RawWalk field bit-exact against
+                # the numpy reference ops.ingest.host_walk.
+                "native.pt_raw_walk", "pt_raw_walk", ("PTA001",),
+                "raw_walk_conformance",
+            ),
         ),
         wire_codec="ops.wire.delta_codec",
         bench_fields=("ingest_raw_smoke_deltas",),

@@ -2843,7 +2843,7 @@ class DeviceEngine:
 
         ``planes`` is uint8[P, ROW] (rows straight out of the rx ring —
         non-dv2 rows simply fail the in-kernel verdict via a zeroed
-        length); ``walk`` is the caller's :func:`ops.ingest.host_walk`
+        length); ``walk`` is the caller's :func:`ops.ingest.walk`
         result when it already ran one (the delta plane's ack
         bookkeeping shares it); ``release`` is invoked on the completion
         pipeline once the shipped planes operand is READY (the
@@ -2864,7 +2864,7 @@ class DeviceEngine:
             planes = np.asarray(planes)
             lengths = np.ascontiguousarray(lengths, np.int32)
             if walk is None:
-                walk = ingest_ops.host_walk(planes, lengths)
+                walk = ingest_ops.walk(planes, lengths)
             if not walk.ok.any():
                 # Nothing dispatch-worthy: every row failed the framing
                 # walk, so the kernel would sentinel-pad the whole batch

@@ -345,6 +345,12 @@ class CounterRegistry:
         # Busy ns of the replication send path: every delta-plane flush
         # (net/delta.py) and state fan-out (broadcast.tx), summed.
         "repl_send_ns",
+        # The rx paths' dv2 structure walk (ops/ingest.walk): batches the
+        # C++ walk took, batches the numpy reference took, and the walk's
+        # summed wall time on either path.
+        "rx_walk_native_batches",
+        "rx_walk_numpy_batches",
+        "rx_walk_ns",
         # XLA backend compiles (and their ns) seen by the jax.monitoring
         # listener (count_jax_compiles): 0 in a warm serving window.
         "jit_compiles",

@@ -148,6 +148,73 @@ class TestClassifyConformance:
             assert (out_a[0], out_t[0], out_e[0]) == (5 * NANO, 9 * NANO, 3)
 
 
+# --- PTA001: the rx structure walk -----------------------------------------
+
+
+class TestRawWalkConformance:
+    class _Shim:
+        """Delegating facade over the real lib for seeded binding faults."""
+
+        def __init__(self, lib, fn):
+            self._lib = lib
+            self.pt_raw_walk = fn
+
+        def __getattr__(self, name):
+            return getattr(self._lib, name)
+
+    def test_shipped_walk_is_silent(self, lib):
+        assert (
+            abi.check_raw_walk_conformance(OBS["raw_walk_conformance"], lib)
+            == []
+        )
+
+    def test_reference_mutation_is_rejected(self, lib, monkeypatch):
+        """A reference walk that skips the checksum verdict disagrees with
+        the native walk on the flipped rows: PTA001."""
+        from patrol_tpu.ops import ingest
+
+        orig = ingest.host_walk
+
+        def lax(planes, lengths):
+            w = orig(planes, lengths)
+            return w._replace(ok=w.ok | (np.asarray(lengths) > 0))
+
+        monkeypatch.setattr(ingest, "host_walk", lax)
+        f = abi.check_raw_walk_conformance(OBS["raw_walk_conformance"], lib)
+        assert codes(f) == ["PTA001"]
+        assert any("ok diverges" in x.message for x in f)
+
+    def test_lock_holding_binding_is_rejected(self, lib):
+        import ctypes
+
+        held = ctypes.PyDLL(lib._name).pt_raw_walk
+        held.argtypes = lib.pt_raw_walk.argtypes
+        held.restype = ctypes.c_int
+        f = abi.check_raw_walk_conformance(
+            OBS["raw_walk_conformance"], self._Shim(lib, held)
+        )
+        assert codes(f) == ["PTA001"]
+        assert any("interpreter lock" in x.message for x in f)
+
+    def test_argtype_drift_is_rejected(self, lib):
+        import ctypes
+
+        drifted = ctypes.CDLL(lib._name).pt_raw_walk
+        types = list(lib.pt_raw_walk.argtypes)
+        types[1] = types[6]  # lengths declared int32
+        drifted.argtypes = types
+        f = abi.check_raw_walk_conformance(
+            OBS["raw_walk_conformance"], self._Shim(lib, drifted)
+        )
+        assert codes(f) == ["PTA001"]
+        assert any("argument 1" in x.message for x in f)
+        drifted.argtypes = types[:-1]
+        f = abi.check_raw_walk_conformance(
+            OBS["raw_walk_conformance"], self._Shim(lib, drifted)
+        )
+        assert any("C definition 19" in x.message for x in f)
+
+
 # --- PTA002/PTA003: merge laws on the native side ---------------------------
 
 
@@ -476,6 +543,7 @@ class TestRepoAbiClean:
         for required in (
             "native.pt_fold_hybrid",
             "native.pt_rx_classify",
+            "native.pt_raw_walk",
             "native.hls_schedules",
             "native.effects_table",
         ):

@@ -14,6 +14,7 @@ import pytest
 
 import jax.numpy as jnp
 
+from patrol_tpu import native
 from patrol_tpu.models.limiter import NANO, LimiterConfig, init_state
 from patrol_tpu.ops import ingest as ingest_ops
 from patrol_tpu.ops import wire
@@ -95,11 +96,62 @@ def planes_of(raw, stale=0xAB):
     return planes, lengths
 
 
+def _native_lib():
+    lib = native.load()
+    if lib is None:
+        pytest.skip("native toolchain unavailable: the C++ walk is not tested")
+    return lib
+
+
+@pytest.fixture(params=["numpy", "native"])
+def walk_fn(request):
+    """The two structure walks: the numpy reference and the C++ twin."""
+    if request.param == "numpy":
+        return ingest_ops.host_walk
+    lib = _native_lib()
+    return lambda planes, lengths: ingest_ops._native_walk(lib, planes, lengths)
+
+
+def _padded(raw, pad):
+    """A recv batch padded as the rx loop pads it: ``pad`` rows of zero
+    length whose stale bytes still hold whole datagrams."""
+    planes, lengths = planes_of(raw)
+    return (
+        np.concatenate([planes, planes[:pad]]),
+        np.concatenate([lengths, np.zeros(pad, np.int32)]),
+    )
+
+
+def _full_row():
+    """One 8-KiB datagram carrying the most entries a row can (232, with
+    empty names), beside the same bytes with a length one entry short and
+    a length past the row."""
+    row = wire.DELTA_PACKET_SIZE
+    ents = [wire.DeltaEntry("", i % 3, i, 2 * i, 3 * i, 4 * i) for i in range(232)]
+    data, n = wire.encode_delta_packet(2, 77, [5, 6], ents, max_size=row)
+    assert n == ingest_ops.max_entries(row) == 232
+    planes = np.zeros((3, row), np.uint8)
+    planes[:] = np.frombuffer(data.ljust(row, b"\xee"), np.uint8)
+    lengths = np.array([len(data), len(data) - 35, row + 1], np.int64)
+    return planes, lengths
+
+
+WALK_CASES = {
+    "corpus-20260805": lambda: planes_of(hostile_corpus()),
+    "corpus-7": lambda: planes_of(hostile_corpus(seed=7, n=64)),
+    "corpus-99": lambda: planes_of(hostile_corpus(seed=99, n=64)),
+    "padded": lambda: _padded(hostile_corpus(seed=3, n=40), 24),
+    "P1": lambda: planes_of([mk_packet(1, 30, acks=(4, 5))]),
+    "P512": lambda: planes_of(hostile_corpus(seed=512, n=512)),
+    "232-entries": _full_row,
+}
+
+
 class TestHostWalkParity:
-    def test_verdicts_and_fields_match_python_decoder(self):
+    def test_verdicts_and_fields_match_python_decoder(self, walk_fn):
         raw = hostile_corpus()
         planes, lengths = planes_of(raw)
-        walk = ingest_ops.host_walk(planes, lengths)
+        walk = walk_fn(planes, lengths)
         for i, b in enumerate(raw):
             pk = wire.decode_delta_packet(b[:ROW] if len(b) > ROW else b)
             assert walk.ok[i] == (pk is not None), i
@@ -120,6 +172,52 @@ class TestHostWalkParity:
                     i, walk.name_off[i, j] : walk.name_off[i, j] + walk.name_len[i, j]
                 ].tobytes()
                 assert nb.decode("utf-8", "surrogateescape") == e.name
+
+    @pytest.mark.parametrize("case", sorted(WALK_CASES))
+    def test_native_walk_equals_host_walk(self, case):
+        """Every RawWalk field of the C++ walk, element by element, dtype
+        and shape, against the numpy reference — refused packets'
+        framing proposals and zeroed values included."""
+        lib = _native_lib()
+        planes, lengths = WALK_CASES[case]()
+        want = ingest_ops.host_walk(planes, lengths)
+        got = ingest_ops._native_walk(lib, planes, lengths)
+        assert got is not None
+        for field in ingest_ops.RawWalk._fields:
+            a, b = getattr(want, field), getattr(got, field)
+            assert (a.dtype, a.shape) == (b.dtype, b.shape), field
+            assert np.array_equal(a, b), (case, field, np.argwhere(a != b)[:4])
+        if case == "232-entries":
+            assert got.ok.tolist() == [True, False, False]
+            assert int(got.count[0]) == 232
+
+    def test_native_walk_refuses_mismatched_lengths(self):
+        lib = _native_lib()
+        planes, lengths = planes_of(hostile_corpus(seed=2, n=4))
+        with pytest.raises(ValueError):
+            ingest_ops._native_walk(lib, planes, lengths[:3])
+
+    def test_walk_counts_the_path_it_took(self, monkeypatch):
+        from patrol_tpu import native as native_mod
+
+        _native_lib()
+        planes, lengths = planes_of(hostile_corpus(seed=5, n=16))
+        keys = ("rx_walk_native_batches", "rx_walk_numpy_batches", "rx_walk_ns")
+        before = {k: profiling.COUNTERS.get(k) for k in keys}
+        native_walk = ingest_ops.walk(planes, lengths)
+        mid = {k: profiling.COUNTERS.get(k) for k in keys}
+        monkeypatch.setattr(native_mod, "load", lambda: None)
+        numpy_walk = ingest_ops.walk(planes, lengths)
+        after = {k: profiling.COUNTERS.get(k) for k in keys}
+        assert mid["rx_walk_native_batches"] == before["rx_walk_native_batches"] + 1
+        assert mid["rx_walk_numpy_batches"] == before["rx_walk_numpy_batches"]
+        assert after["rx_walk_numpy_batches"] == mid["rx_walk_numpy_batches"] + 1
+        assert after["rx_walk_native_batches"] == mid["rx_walk_native_batches"]
+        assert before["rx_walk_ns"] < mid["rx_walk_ns"] < after["rx_walk_ns"]
+        for field in ingest_ops.RawWalk._fields:
+            assert np.array_equal(
+                getattr(native_walk, field), getattr(numpy_walk, field)
+            ), field
 
     def test_dv2_mask_matches_is_delta_packet(self):
         raw = hostile_corpus(seed=7, n=40) + [b"", b"\x00" * 31, b"\x00" * 40]
@@ -399,6 +497,69 @@ class TestDeltaPlaneRawPath:
                 eng.stop()
         assert stats[True] == stats[False]
         assert stats[(True, "acked")] == stats[(False, "acked")]
+
+    def test_native_and_numpy_walks_ingest_alike(self, monkeypatch):
+        """on_raw_planes with the C++ walk and with the library missing
+        (the numpy fallback): the same acks, ``rx_deltas``, rx errors and
+        folded state, and the counters name the path each batch took."""
+        from patrol_tpu import native as native_mod
+
+        _native_lib()
+        peer = ("127.0.0.1", 1234)
+        raw = [
+            mk_packet(40 + i, 12, name_pool=16, seq=100 + i, acks=(i + 1,))
+            for i in range(6)
+        ]
+        raw += hostile_corpus(seed=11, n=10)
+        planes, lengths = _padded(raw, 16)  # 32 rows, as the rx loop pads
+        addrs = [peer] * len(raw) + [None] * 16
+        names = {
+            e.name
+            for b in raw
+            if (pk := wire.decode_delta_packet(b)) is not None
+            for e in pk.entries
+        }
+        keys = ("rx_walk_native_batches", "rx_walk_numpy_batches", "rx_walk_ns")
+        seen = {}
+        for path in ("native", "numpy"):
+            eng, rep, plane = self._plane_with_engine()
+            try:
+                before = {k: profiling.COUNTERS.get(k) for k in keys}
+                released = []
+                with monkeypatch.context() as m:
+                    if path == "numpy":
+                        m.setattr(native_mod, "load", lambda: None)
+                    assert plane.on_raw_planes(
+                        planes.copy(), lengths.copy(), addrs,
+                        release=lambda: released.append(1),
+                    )
+                assert eng.flush(timeout=30)
+                assert released == [1]
+                moved = {k: profiling.COUNTERS.get(k) - before[k] for k in keys}
+                assert moved[f"rx_walk_{path}_batches"] == 1
+                other = "numpy" if path == "native" else "native"
+                assert moved[f"rx_walk_{other}_batches"] == 0
+                assert moved["rx_walk_ns"] > 0
+                state = {}
+                for nm in names:
+                    pn, el = eng.row_view(eng.directory.lookup(nm))
+                    state[nm] = (pn.tolist(), int(el))
+                seen[path] = (
+                    list(plane._peers[peer].pending_acks),
+                    plane.rx_deltas,
+                    plane.rx_errors,
+                    state,
+                )
+            finally:
+                eng.stop()
+        assert seen["native"] == seen["numpy"]
+        acks, rx_deltas, rx_errors, _ = seen["native"]
+        assert acks == [
+            pk.seq
+            for b in raw
+            if (pk := wire.decode_delta_packet(b)) is not None and pk.seq
+        ]
+        assert rx_deltas >= 6 * 12 and rx_errors > 0
 
 
 @pytest.mark.skipif(
