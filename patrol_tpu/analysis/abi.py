@@ -28,7 +28,10 @@ production uses):
    be indistinguishable. ``pt_raw_walk`` (the rx thread's dv2 structure
    walk) is held to the numpy ``ops.ingest.host_walk`` the same way, and
    to its binding: argument types as the C definition declares them, the
-   interpreter lock released, every output written within its size.
+   interpreter lock released, every output written within its size. The
+   one binding that keeps the interpreter lock (``pt_http_answer_takes``,
+   the HTTP front's answer hand-back) is held to its declaration: argument
+   types, leaf mutexes only, no native call into Python, a live answer.
 
 2. **Merge laws on the native side** (PTA002 commutativity / batch-order
    freedom, PTA003 idempotence under duplication + monotonicity) — the
@@ -1552,6 +1555,7 @@ def check_rxring_interleavings(ob: AbiObligation, lib=None) -> List[Finding]:
 _C_PARAM_TYPES = {
     "uint8_t*": np.dtype(np.uint8),
     "int32_t*": np.dtype(np.int32),
+    "int*": np.dtype(np.int32),
     "int64_t*": np.dtype(np.int64),
     "uint64_t*": np.dtype(np.uint64),
     "int": ctypes.c_int,
@@ -1559,18 +1563,48 @@ _C_PARAM_TYPES = {
 _WALK_POISON = 0xA5  # every output byte before the call: unwritten shows
 
 
+def _native_sources() -> Dict[str, str]:
+    """The native library's C++ sources, by repo-relative path."""
+    out = {}
+    for rel in (_HOST_CPP, _HTTP_CPP):
+        with open(os.path.join(_REPO_ROOT, rel), encoding="utf-8") as fh:
+            out[rel] = fh.read()
+    return out
+
+
 def _c_param_types(symbol: str) -> List[str]:
-    """Parameter types of ``symbol``'s definition in patrol_host.cpp, with
-    ``const`` and the names dropped (``const uint8_t* planes`` → ``uint8_t*``)."""
-    with open(os.path.join(_REPO_ROOT, _HOST_CPP), encoding="utf-8") as fh:
-        m = re.search(rf"\nint {symbol}\(([^)]*)\)\s*{{", fh.read())
-    if m is None:
+    """Parameter types of ``symbol``'s definition in the native sources,
+    with ``const`` and the names dropped (``const uint8_t* planes`` →
+    ``uint8_t*``)."""
+    for text in _native_sources().values():
+        m = re.search(rf"\nint {symbol}\(([^)]*)\)\s*{{", text)
+        if m is not None:
+            break
+    else:
         return []
     out = []
     for p in m.group(1).split(","):
         p = p.replace("const ", "").strip()
         out.append(p[: p.rindex("*") + 1] if "*" in p else p.split()[0])
     return [t.replace(" ", "") for t in out]
+
+
+def _argtype_findings(fn, symbol: str, emit: Callable[[str], None]) -> None:
+    """Emit each way ``fn``'s ctypes argument types differ from
+    ``symbol``'s C definition."""
+    want_types = _c_param_types(symbol)
+    got_types = list(fn.argtypes or ())
+    if not want_types or len(want_types) != len(got_types):
+        emit(
+            f"ctypes declares {len(got_types)} arguments, the C definition "
+            f"{len(want_types)}: every later argument would be misread"
+        )
+        return
+    for i, (c_type, declared) in enumerate(zip(want_types, got_types)):
+        want = _C_PARAM_TYPES.get(c_type)
+        got = getattr(declared, "_dtype_", declared)
+        if want is None or got != want:
+            emit(f"argument {i}: C {c_type}, ctypes declares {declared}")
 
 
 def _raw_walk_corpus() -> Tuple[np.ndarray, np.ndarray]:
@@ -1639,19 +1673,7 @@ def check_raw_walk_conformance(ob: AbiObligation, lib) -> List[Finding]:
         findings.append(Finding("PTA001", *site, f"[{ob.name}] {msg}"))
 
     fn = lib.pt_raw_walk
-    want_types = _c_param_types("pt_raw_walk")
-    got_types = list(fn.argtypes or ())
-    if not want_types or len(want_types) != len(got_types):
-        emit(
-            f"ctypes declares {len(got_types)} arguments, the C definition "
-            f"{len(want_types)}: every later argument would be misread"
-        )
-        return findings
-    for i, (c_type, declared) in enumerate(zip(want_types, got_types)):
-        want = _C_PARAM_TYPES.get(c_type)
-        got = getattr(declared, "_dtype_", declared)
-        if want is None or got != want:
-            emit(f"argument {i}: C {c_type}, ctypes declares {declared}")
+    _argtype_findings(fn, "pt_raw_walk", emit)
     if findings:
         return findings
     if fn._flags_ & ctypes._FUNCFLAG_PYTHONAPI:
@@ -1690,6 +1712,199 @@ def check_raw_walk_conformance(ob: AbiObligation, lib) -> List[Finding]:
                 f"{field} diverges from host_walk at {bad} (an element left "
                 "unwritten reads as the poison byte)"
             )
+    return findings
+
+
+# ===========================================================================
+# Pass 1d — PTA001 for the bindings that keep the interpreter lock.
+
+# A call into Python from C++: the header, or any Py* API function.
+_PY_CALL_RE = re.compile(r"#\s*include\s*[<\"]Python\.h|\bPy[A-Z_]\w*\s*\(")
+# A scoped mutex acquisition; group 1 is the mutex expression.
+_LOCK_RE = re.compile(r"(?:lock_guard|unique_lock|scoped_lock)<[^>]*>\s*\w+\(([^)]*)\)")
+
+
+def _block_rest(text: str, start: int) -> str:
+    """``text`` from ``start`` to the brace that closes the block
+    enclosing it."""
+    depth = 0
+    for i in range(start, len(text)):
+        if text[i] == "{":
+            depth += 1
+        elif text[i] == "}":
+            depth -= 1
+            if depth < 0:
+                return text[start:i]
+    return text[start:]
+
+
+def _mutex_name(expr: str) -> str:
+    """The mutex a lock expression names (``s->answer_mu`` and
+    ``s->answer_mu, std::adopt_lock`` → ``answer_mu``)."""
+    return re.split(r"->|\.", expr.split(",")[0].strip())[-1]
+
+
+def _non_leaf_holds(sources: Dict[str, str], mutex: str) -> List[str]:
+    """Each place that holds ``mutex`` while it takes another lock or
+    waits, as ``path:line``: a leaf mutex has none."""
+    out = []
+    for rel, text in sorted(sources.items()):
+        for m in _LOCK_RE.finditer(text):
+            if _mutex_name(m.group(1)) != mutex:
+                continue
+            rest = _block_rest(text, m.end())
+            if _LOCK_RE.search(rest) or ".wait" in rest or ".lock()" in rest:
+                out.append(f"{rel}:{text[: m.start()].count(chr(10)) + 1}")
+    return out
+_HELD_TAKE = b"POST /take/abi-held?rate=5:1s HTTP/1.1\r\nHost: x\r\n\r\n"
+
+
+def _held_answer(lib, fn) -> Optional[str]:
+    """Answer one parsed take through ``fn`` on a server of its own; a
+    fault as text, or None when the client reads 429 with balance 7 and
+    the stopped handle reads -EBADF."""
+    import socket
+
+    from patrol_tpu import native
+
+    h = lib.pt_http_start(b"127.0.0.1", 0)
+    if h < 0:
+        return f"pt_http_start failed (rc={h})"
+    stopped = False
+    try:
+        with socket.create_connection(
+            ("127.0.0.1", lib.pt_http_port(h)), timeout=5
+        ) as c:
+            c.sendall(_HELD_TAKE)
+            tags = np.zeros(1, np.uint64)
+            streams = np.zeros(1, np.int32)
+            req_ns = np.zeros(1, np.int64)
+            n_other = ctypes.c_int(0)
+            nt = 0
+            for _ in range(50):
+                nt = lib.pt_http_poll(
+                    h, 100, tags, streams, np.zeros(256, np.uint8),
+                    np.zeros(1, np.int32), np.zeros(1, np.int64),
+                    np.zeros(1, np.int64), np.zeros(1, np.int64), req_ns, 1,
+                    np.zeros(1, np.uint64), np.zeros(1, np.int32),
+                    np.zeros(native.PATH_MAX, np.uint8),
+                    np.zeros(1, np.int32), np.zeros(8, np.uint8), 1,
+                    ctypes.byref(n_other),
+                )
+                if nt:
+                    break
+            if nt != 1:
+                return f"the take never reached the poll (rc={nt})"
+            rc = fn(
+                h, tags, streams, np.array([429], np.int32),
+                np.array([7], np.int64), 1,
+            )
+            if rc != 0:
+                return f"refused a live answer (rc={rc})"
+            data = b""
+            while not (b"\r\n\r\n" in data and data.endswith(b"7")):
+                chunk = c.recv(4096)
+                if not chunk:
+                    break
+                data += chunk
+            if not (data.startswith(b"HTTP/1.1 429 ") and data.endswith(b"\r\n7")):
+                return f"the client read {data[:80]!r}, not 429 with balance 7"
+        lib.pt_http_stop(h)
+        stopped = True
+        rc = fn(h, tags, streams, np.array([200], np.int32),
+                np.array([1], np.int64), 1)
+        if rc != -errno.EBADF:
+            return f"a stopped handle returned {rc}, not -EBADF"
+        return None
+    except OSError as exc:
+        return f"the client failed: {exc}"
+    finally:
+        if not stopped:
+            lib.pt_http_stop(h)
+
+
+def check_held_binding_conformance(ob: AbiObligation, lib) -> List[Finding]:
+    """PTA001 for the ``keeps_gil`` column of NATIVE_EFFECTS: (1) every
+    registered binding keeps the interpreter lock exactly where the table
+    declares it (a PyDLL function there, a CDLL one elsewhere); (2) a
+    symbol that keeps it neither blocks nor takes the host-lane mutex;
+    (3) its ctypes argument types match the C definition one for one;
+    (4) its body takes only leaf mutexes: no hold of one anywhere in the
+    sources takes another lock or waits. A caller keeping the interpreter
+    lock may not wait on a mutex whose holder waits on another mutex: the
+    HTTP server's ``mu`` is held by the epoll thread while it waits on the
+    host-lane store mutex, which Python threads hold while they wait on
+    the interpreter lock. (5) No native source calls into Python and no
+    registered argument is a ctypes callback; and (6) live, on a server
+    of its own: ``ob.symbol`` answers a parsed take as its client reads
+    it, and refuses a stopped handle with -EBADF."""
+    from patrol_tpu.native import NATIVE_EFFECTS
+
+    site = _cpp_site(ob.symbol)
+    findings: List[Finding] = []
+
+    def emit(msg: str) -> None:
+        findings.append(Finding("PTA001", *site, f"[{ob.name}] {msg}"))
+
+    held = []
+    for sym, eff in sorted(NATIVE_EFFECTS.items()):
+        fn = getattr(lib, sym, None)
+        if fn is None:
+            continue  # PTA005's finding
+        keeps = bool(fn._flags_ & ctypes._FUNCFLAG_PYTHONAPI)
+        if keeps != eff.keeps_gil:
+            emit(
+                f"{sym} is bound through {'PyDLL' if keeps else 'CDLL'}, the "
+                f"table declares keeps_gil={eff.keeps_gil}: "
+                + (
+                    "the call holds the interpreter lock for its whole run"
+                    if keeps
+                    else "the caller drops the interpreter lock and queues "
+                    "to take it back"
+                )
+            )
+        if eff.keeps_gil:
+            held.append(sym)
+            if eff.blocks or eff.takes_host_mu:
+                emit(
+                    f"{sym} keeps the interpreter lock yet is declared to "
+                    "block or take the host-lane mutex: every other thread "
+                    "would wait on it"
+                )
+            _argtype_findings(fn, sym, emit)
+        for i, a in enumerate(fn.argtypes or ()):
+            if isinstance(a, type) and issubclass(a, ctypes._CFuncPtr):
+                emit(f"{sym} argument {i} is a callback into Python")
+    sources = _native_sources()
+    for sym in held:
+        for text in sources.values():
+            m = re.search(rf"\nint {sym}\([^)]*\)\s*{{", text)
+            if m is None:
+                continue
+            for lk in _LOCK_RE.finditer(_block_rest(text, m.end())):
+                mutex = _mutex_name(lk.group(1))
+                for at in _non_leaf_holds(sources, mutex):
+                    emit(
+                        f"{sym} takes {mutex}, which {at} holds while it "
+                        "takes another lock or waits: a thread there can "
+                        "wait, through that lock, on the interpreter lock "
+                        "this caller keeps"
+                    )
+    for rel, text in sorted(sources.items()):
+        m = _PY_CALL_RE.search(text)
+        if m is not None:
+            line = text[: m.start()].count("\n") + 1
+            emit(
+                f"{rel}:{line} calls into Python: a thread holding a native "
+                "mutex could wait there on the interpreter lock that a "
+                "keeps_gil caller holds while it waits for that mutex"
+            )
+    if ob.symbol not in held:
+        emit(f"{ob.symbol} is not declared keeps_gil")
+    elif not findings:
+        fault = _held_answer(lib, getattr(lib, ob.symbol))
+        if fault is not None:
+            emit(fault)
     return findings
 
 
@@ -1750,6 +1965,7 @@ _CHECKS: Dict[str, Callable] = {
     "rxring_interleavings": check_rxring_interleavings,
     "classify_conformance": check_classify_conformance,
     "raw_walk_conformance": check_raw_walk_conformance,
+    "held_binding_conformance": check_held_binding_conformance,
     "hls_interleavings": check_hls_interleavings,
     "effects_table": check_effects_table,
 }

@@ -19,14 +19,16 @@ analogue for patrol's own seams. Two halves:
 **Dynamic — the epoll-seam schedule explorer (PTR001, PTR002).** A
 step-for-step Python model of the ``patrol_http.cpp`` front protocol —
 ``Server.mu``/``cv``, the parsed-take ring (``take_q``), (slot, gen)
-completion tags, ``pt_http_poll`` park/drain, ``pt_http_complete_takes``
-fan-in — explored over EVERY interleaving of three concurrent actors
-(the epoll thread running arrival/close scripts, the Python pump's poll
-loop, and a modeled completer), bounded and exhaustive with state
-memoization. Steps that the real code runs under ``Server::mu`` are
-atomic in the model (lock-based reduction: two critical sections on the
-same mutex cannot interleave); seeded mutations split exactly the
-accesses the real bug class would leave unprotected:
+completion tags, ``pt_http_poll`` park/drain, and the take answers'
+queue (``pt_http_answer_takes`` appends under the leaf ``answer_mu`` and
+kicks the eventfd; the epoll thread reads the eventfd, then drains the
+queue under ``Server::mu``) — explored over EVERY interleaving of three
+concurrent actors (the epoll thread running arrival/close scripts and
+answer drains, the Python pump's poll loop, and a modeled completer),
+bounded and exhaustive with state memoization. Steps that the real code
+runs under one mutex are atomic in the model (lock-based reduction: two
+critical sections on the same mutex cannot interleave); seeded mutations
+split exactly the accesses the real bug class would leave unprotected:
 
 * ``completion-before-park`` — the pump checks the ring *before*
   becoming a waiter (predicate evaluated outside the mutex, then an
@@ -38,10 +40,15 @@ accesses the real bug class would leave unprotected:
   slot without bumping ``gen``; a completion for the dead request then
   matches the NEW connection occupying the slot and answers a request
   it never made (PTR002: completion-ring token conservation).
-* ``ack-without-holding-mutex`` — the completion path reads conn
-  liveness and appends the response as two unlocked steps; a concurrent
-  close (or close+reuse) between them writes into a dead or recycled
-  connection (PTR002).
+* ``ack-without-holding-mutex`` — the completer answers on the
+  connection itself instead of queueing, reading conn liveness and
+  appending the response as two unlocked steps; a concurrent close (or
+  close+reuse) between them writes into a dead or recycled connection
+  (PTR002).
+* ``drain-before-eventfd-read`` — the epoll thread swaps the answer
+  queue first and reads (resets) the eventfd after; a completer's append
+  and kick between the two is consumed with its answers left in the
+  queue, and no kick is coming for them (PTR001: stalled answers).
 
 The model checks, at every step and at every quiescent terminal state:
 no request is polled or completed twice (ring token conservation), every
@@ -143,14 +150,20 @@ class SeamSemantics:
     * ``slot_fence`` — ``"gen"``: reopening a recycled conn slot bumps
       the generation so stale completion tags miss; ``"reuse"``: the
       slot is reused verbatim.
-    * ``complete_lock`` — ``"mutex"``: liveness check + response append
-      are one critical section (``pt_http_complete_takes`` under
-      ``s->mu``); ``"none"``: two unlocked steps.
+    * ``complete_path`` — ``"queue"``: the completer appends its batch
+      to the answer queue (``answer_mu``), then kicks the eventfd; the
+      epoll thread drains the queue, each liveness check + response
+      append one critical section under ``s->mu``; ``"direct"``: the
+      completer answers on the connection itself in two unlocked steps.
+    * ``drain_order`` — ``"read-then-swap"``: the epoll thread reads the
+      eventfd, then swaps the queue out (``serve_loop`` →
+      ``drain_answers``); ``"swap-then-read"``: the other way round.
     """
 
     park_check: str = "locked"  # "locked" | "unlocked"
     slot_fence: str = "gen"  # "gen" | "reuse"
-    complete_lock: str = "mutex"  # "mutex" | "none"
+    complete_path: str = "queue"  # "queue" | "direct"
+    drain_order: str = "read-then-swap"  # | "swap-then-read"
 
 
 SEAM_CLEAN = SeamSemantics()
@@ -162,7 +175,10 @@ SEAM_MUTATIONS: Dict[str, Tuple[SeamSemantics, str]] = {
         SeamSemantics(slot_fence="reuse"), "PTR002",
     ),
     "ack-without-holding-mutex": (
-        SeamSemantics(complete_lock="none"), "PTR002",
+        SeamSemantics(complete_path="direct"), "PTR002",
+    ),
+    "drain-before-eventfd-read": (
+        SeamSemantics(drain_order="swap-then-read"), "PTR001",
     ),
 }
 
@@ -182,12 +198,18 @@ class SeamScenario:
 
 
 # Model state is one flat hashable tuple (for DFS memoization):
-#   (ei, pump_pc, take_q, handoff, comp_pc, conn_slots, incarnations)
+#   (ei, pump_pc, take_q, handoff, comp_pc, answers, kicked, drain_pc,
+#    conn_slots, incarnations)
 # pump_pc: "idle" | "parked" | ("checked", empty: bool)
 # take_q:  ((req, slot, gen), ...)
 # handoff: (batch, ...) each batch ((req, slot, gen), ...)
-# comp_pc: None | ("mid", (req, slot, gen), rest_of_batch)  — the
-#          unlocked completer's snapshot-taken-but-not-yet-appended item
+# comp_pc: None | "kick" (queued, eventfd not yet written) |
+#          ("mid", (req, slot, gen), rest_of_batch, gen) — the direct
+#          completer's snapshot-taken-but-not-yet-appended item
+# answers: ((req, slot, gen), ...) handed over, not yet drained
+# kicked:  the eventfd is readable
+# drain_pc: None | "first" — the epoll thread is between the two steps
+#          of a drain (eventfd read and queue swap, in drain_order)
 # conn_slots: ((conn_name, slot) ...) for OPEN conns
 # incarnations: per slot, a tuple of (gen, alive, expected, responses)
 #          — the FULL history; the last entry is the current occupant.
@@ -231,7 +253,7 @@ class _SeamState:
 
     __slots__ = (
         "ei", "pump_pc", "take_q", "handoff", "comp_pc",
-        "conn_slots", "slots",
+        "answers", "kicked", "drain_pc", "conn_slots", "slots",
     )
 
     def __init__(self, scenario: SeamScenario):
@@ -240,6 +262,9 @@ class _SeamState:
         self.take_q: List[tuple] = []
         self.handoff: List[tuple] = []
         self.comp_pc = None
+        self.answers: List[tuple] = []
+        self.kicked = False
+        self.drain_pc: Optional[str] = None
         self.conn_slots: Dict[str, int] = {
             c: i for i, c in enumerate(scenario.conns)
         }
@@ -256,6 +281,9 @@ class _SeamState:
             tuple(self.take_q),
             tuple(self.handoff),
             self.comp_pc,
+            tuple(self.answers),
+            self.kicked,
+            self.drain_pc,
             tuple(sorted(self.conn_slots.items())),
             tuple(
                 tuple(
@@ -276,6 +304,9 @@ class _SeamState:
         other.take_q = list(self.take_q)
         other.handoff = [tuple(b) for b in self.handoff]
         other.comp_pc = self.comp_pc
+        other.answers = list(self.answers)
+        other.kicked = self.kicked
+        other.drain_pc = self.drain_pc
         other.conn_slots = dict(self.conn_slots)
         other.slots = [
             [
@@ -347,7 +378,7 @@ def _seam_drain(st: _SeamState, cap: int) -> None:
 def _seam_complete_one(st: _SeamState, item: tuple, checked_gen: int) -> None:
     """Append the response for one completion tag whose liveness check
     already passed (atomically in the clean model; against a possibly
-    stale snapshot under the ``complete_lock="none"`` mutation)."""
+    stale snapshot under the ``complete_path="direct"`` mutation)."""
     req, slot, _gen = item
     inc = st.slots[slot][-1]
     if not inc["alive"]:
@@ -365,6 +396,30 @@ def _seam_complete_one(st: _SeamState, item: tuple, checked_gen: int) -> None:
             f"checked gen {checked_gen}, wrote into gen {inc['gen']} "
             f"(slot {slot})",
         )
+
+
+def _seam_answer_drain(st: _SeamState) -> None:
+    """The epoll thread's queue swap: every handed-over answer is queued
+    on its connection under ``Server::mu`` (one critical section per
+    answer, on the thread that also closes and reopens connections), a
+    stale (slot, gen) tag missing."""
+    answers, st.answers = st.answers, []
+    for item in answers:
+        _req, slot, gen = item
+        inc = st.slots[slot][-1]
+        if inc["alive"] and inc["gen"] == gen:
+            _seam_complete_one(st, item, inc["gen"])
+
+
+def _seam_drain_step(st: _SeamState, sem: SeamSemantics) -> None:
+    """One of the epoll thread's two drain steps (eventfd read, queue
+    swap), in ``sem.drain_order``."""
+    first = st.drain_pc is None
+    st.drain_pc = "first" if first else None
+    if first == (sem.drain_order == "read-then-swap"):
+        st.kicked = False  # read(event_fd): the counter resets
+    else:
+        _seam_answer_drain(st)
 
 
 def _seam_check_conservation(st: _SeamState, terminal: bool) -> None:
@@ -405,6 +460,13 @@ def _seam_check_conservation(st: _SeamState, terminal: bool) -> None:
             "PTR001",
             "stalled completion: polled requests were never completed "
             "although the completer had no more steps",
+        )
+    if st.answers:
+        raise _SeamViolation(
+            "PTR001",
+            f"stalled answers: {len(st.answers)} answer(s) sit in the "
+            "answer queue and no eventfd kick is left to drain them (the "
+            "kick was consumed before the queue was swapped)",
         )
     for slot, incs in enumerate(st.slots):
         inc = incs[-1]
@@ -447,8 +509,10 @@ def explore_seam(
 
     def moves(st: _SeamState, polls_left: int) -> List[tuple]:
         out: List[tuple] = []
-        if st.ei < len(scenario.script):
+        if st.ei < len(scenario.script) and st.drain_pc is None:
             out.append(("epoll",))
+        if st.kicked or st.drain_pc is not None:
+            out.append(("drain",))
         if st.pump_pc == "idle" and polls_left > 0:
             out.append(("pump",))
         elif isinstance(st.pump_pc, tuple):  # mid unlocked check
@@ -461,6 +525,9 @@ def explore_seam(
         """Apply one move in place; returns the new polls_left."""
         if mv[0] == "epoll":
             _seam_apply_epoll(st, scenario.script[st.ei], sem)
+            return polls_left
+        if mv[0] == "drain":
+            _seam_drain_step(st, sem)
             return polls_left
         if mv[0] == "pump":
             if sem.park_check == "locked":
@@ -483,13 +550,13 @@ def explore_seam(
                 return polls_left - 1
             return polls_left
         # completer
-        if sem.complete_lock == "mutex":
-            batch = st.handoff.pop(0)
-            for item in batch:
-                req, slot, gen = item
-                inc = st.slots[slot][-1]
-                if inc["alive"] and inc["gen"] == gen:
-                    _seam_complete_one(st, item, inc["gen"])
+        if sem.complete_path == "queue":
+            if st.comp_pc == "kick":
+                st.comp_pc = None
+                st.kicked = True  # write(event_fd), after the append
+            else:
+                st.answers.extend(st.handoff.pop(0))  # under answer_mu
+                st.comp_pc = "kick"
             return polls_left
         # unlocked: per-item snapshot step, then append step
         if st.comp_pc is None:

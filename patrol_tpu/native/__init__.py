@@ -62,6 +62,13 @@ class NativeEffect(NamedTuple):
       reading freed storage: use-after-recycle). The static ownership
       pass checks both directions against its declared retained-buffer
       registry, PTA005-style.
+    * ``keeps_gil`` — bound through ``ctypes.PyDLL``: the caller keeps
+      the interpreter lock across the call instead of dropping it and
+      queueing to take it back. Legal only for a short call that never
+      blocks and takes nothing but leaf mutexes (held by no one while
+      they wait on anything), so no thread that holds what the call needs
+      can be waiting, directly or through another mutex, for the
+      interpreter lock (PTA001, ``held_binding_conformance``).
     """
 
     blocks: bool
@@ -70,6 +77,7 @@ class NativeEffect(NamedTuple):
     callback_safe: bool
     owns_buffers: bool = False
     borrows_until: str = "call"
+    keeps_gil: bool = False
 
 
 _E = NativeEffect
@@ -124,7 +132,9 @@ NATIVE_EFFECTS: Dict[str, NativeEffect] = {
     "pt_http_start": _E(True, False, False, False),     # spawns epoll thread
     "pt_http_port": _E(False, False, False, False),
     "pt_http_poll": _E(True, False, False, False),      # condvar wait; parse stamps out
-    "pt_http_complete_takes": _E(False, False, False, False),
+    # Every take answer, kept the interpreter lock through: an append
+    # under the server's leaf answer_mu.
+    "pt_http_answer_takes": _E(False, False, False, False, keeps_gil=True),
     "pt_http_complete_other": _E(False, False, False, False),
     "pt_http_stats": _E(False, False, False, False),
     "pt_http_set_h2_backend": _E(False, False, False, False),
@@ -300,10 +310,14 @@ def load() -> Optional[ctypes.CDLL]:
             ctypes.POINTER(ctypes.c_int),
         ]
         lib.pt_http_poll.restype = ctypes.c_int
-        lib.pt_http_complete_takes.argtypes = [
+        # Bound through PyDLL (keeps_gil): the thread that completed a
+        # batch of takes hands their answers over without dropping the
+        # interpreter lock, which it could wait milliseconds to take back.
+        lib.pt_http_answer_takes = ctypes.PyDLL(_LIB).pt_http_answer_takes
+        lib.pt_http_answer_takes.argtypes = [
             ctypes.c_int, _u64p, _i32p, _i32p, _i64p, ctypes.c_int,
         ]
-        lib.pt_http_complete_takes.restype = ctypes.c_int
+        lib.pt_http_answer_takes.restype = ctypes.c_int
         lib.pt_http_complete_other.argtypes = [
             ctypes.c_int, ctypes.c_uint64, ctypes.c_int32, ctypes.c_int,
             ctypes.c_char_p, ctypes.c_char_p, ctypes.c_int,

@@ -365,6 +365,16 @@ struct TakeRec {
   int name_len;
 };
 
+// One take answer handed over by pt_http_answer_takes, waiting for the
+// epoll thread to queue it on its connection.
+struct AnswerRec {
+  uint64_t tag;
+  int32_t stream;  // h2 stream id; 0 = HTTP/1.1
+  int32_t status;
+  int64_t remaining;
+  int64_t handed_ns;  // steady_clock ns when pt_http_answer_takes took it
+};
+
 struct OtherRec {
   uint64_t tag;
   int32_t stream;  // h2 stream id; 0 = HTTP/1.1
@@ -551,7 +561,22 @@ struct Server {
   uint64_t hls_takes = 0;        // served in-front (this server)
   std::deque<TakeRec> take_q;
   std::deque<OtherRec> other_q;
-  // Completions flow: pump → (mu) wbuf append → eventfd kick.
+  // Take answers flow: pt_http_answer_takes → (answer_mu) append →
+  // eventfd kick → epoll thread: (mu) drain_answers → wbuf append.
+  // answer_mu is a LEAF lock (held only to append or swap the vector,
+  // never while waiting on anything), so a caller that keeps the Python
+  // interpreter lock never waits on mu, which the epoll thread holds
+  // while it waits on the host-lane store mutex, which Python threads
+  // hold while they wait on that lock.
+  std::mutex answer_mu;
+  std::vector<AnswerRec> answers;
+  // pt_http_answer_takes calls that found answer_mu held (the epoll
+  // thread's swap): each waited, keeping the interpreter lock.
+  std::atomic<uint64_t> answer_lock_waits{0};
+  // Answers the epoll thread queued on their connections (mu), and the
+  // sum of their handed → queued times.
+  uint64_t answers_drained = 0;
+  uint64_t answer_drain_ns = 0;
 
   // stats
   uint64_t accepted = 0, requests = 0, dropped = 0;
@@ -1439,6 +1464,46 @@ void flush_writes(Server* s, int slot) {
   if (s->conns[slot].fd >= 0) epoll_mod(s, slot);
 }
 
+// steady_clock (CLOCK_MONOTONIC) now, in ns: the clock of the parse stamps.
+int64_t steady_ns() {
+  return std::chrono::duration_cast<std::chrono::nanoseconds>(
+             std::chrono::steady_clock::now().time_since_epoch())
+      .count();
+}
+
+// Queue one take's answer on its connection (mu held): status 200/429 and
+// the remaining-tokens body; streams > 0 answers on that h2 stream.
+void answer_take(Server* s, uint64_t tag, int32_t stream, int status,
+                 int64_t remaining) {
+  int slot = (int)(tag >> 32);
+  uint32_t gen = (uint32_t)tag;
+  if (slot >= (int)s->conns.size()) return;
+  Conn& c = s->conns[slot];
+  if (c.fd < 0 || c.gen != gen) return;  // conn died mid-flight
+  char body[24];
+  int bl = snprintf(body, sizeof(body), "%lld", (long long)remaining);
+  if (stream > 0 && c.h2 != nullptr)
+    queue_h2_response(s, &c, stream, status, "text/plain", body, bl);
+  else
+    queue_response(s, &c, status, "text/plain", body, bl);
+}
+
+// Queue every answer pt_http_answer_takes handed over (mu held; the
+// answer_mu hold is the swap alone). Counts each answer's handed →
+// queued time, the epoll thread's share of the seam.
+void drain_answers(Server* s) {
+  std::vector<AnswerRec> q;
+  {
+    std::lock_guard<std::mutex> ak(s->answer_mu);
+    q.swap(s->answers);
+  }
+  for (const AnswerRec& a : q) {
+    answer_take(s, a.tag, a.stream, a.status, a.remaining);
+    s->answer_drain_ns += (uint64_t)(steady_ns() - a.handed_ns);
+  }
+  s->answers_drained += q.size();
+}
+
 void serve_loop(Server* s) {
   epoll_event evs[256];
   while (s->running.load(std::memory_order_relaxed)) {
@@ -1478,6 +1543,7 @@ void serve_loop(Server* s) {
         uint64_t v;
         ssize_t rd = read(s->event_fd, &v, 8);
         (void)rd;
+        drain_answers(s);
         // Flush every conn with pending writes.
         for (int slot = 0; slot < (int)s->conns.size(); slot++) {
           if (s->conns[slot].fd >= 0 &&
@@ -1738,30 +1804,29 @@ int pt_http_poll(int h, int timeout_ms,
   return nt;
 }
 
-// Complete a batch of takes: status 200/429 + remaining-tokens body.
-// streams[i] > 0 answers on that h2 stream; 0 = HTTP/1.1.
-int pt_http_complete_takes(int h, const uint64_t* tags,
-                           const int32_t* streams, const int* statuses,
-                           const int64_t* remaining, int n) {
-  std::lock_guard<std::mutex> reg(g_reg_mu);
+// Answer a batch of takes: status (200/429, or the pump's 400/500) and
+// the remaining-tokens body; streams[i] > 0 answers on that h2 stream,
+// 0 = HTTP/1.1. The answers go to the epoll thread, which queues them on
+// their connections (drain_answers). Bound through PyDLL: the caller
+// keeps the interpreter lock, so this takes nothing but the leaf
+// answer_mu (see Server) and never the registry lock; the front never
+// calls it concurrently with pt_http_stop (it joins its pump and detaches
+// its answer sink first, as for pt_http_poll).
+int pt_http_answer_takes(int h, const uint64_t* tags, const int32_t* streams,
+                         const int* statuses, const int64_t* remaining,
+                         int n) {
   Server* s = g_servers[h];
   if (!s) return -EBADF;
+  int64_t now = steady_ns();
+  if (!s->answer_mu.try_lock()) {
+    s->answer_lock_waits.fetch_add(1, std::memory_order_relaxed);
+    s->answer_mu.lock();
+  }
   {
-    std::lock_guard<std::mutex> lk(s->mu);
-    for (int i = 0; i < n; i++) {
-      int slot = (int)(tags[i] >> 32);
-      uint32_t gen = (uint32_t)tags[i];
-      if (slot >= (int)s->conns.size()) continue;
-      Conn& c = s->conns[slot];
-      if (c.fd < 0 || c.gen != gen) continue;  // conn died mid-flight
-      char body[24];
-      int bl = snprintf(body, sizeof(body), "%lld", (long long)remaining[i]);
-      if (streams[i] > 0 && c.h2 != nullptr)
-        queue_h2_response(s, &c, streams[i], statuses[i], "text/plain",
-                          body, bl);
-      else
-        queue_response(s, &c, statuses[i], "text/plain", body, bl);
-    }
+    std::lock_guard<std::mutex> ak(s->answer_mu, std::adopt_lock);
+    for (int i = 0; i < n; i++)
+      s->answers.push_back(
+          {tags[i], streams[i], statuses[i], remaining[i], now});
   }
   uint64_t one = 1;
   ssize_t wr = write(s->event_fd, &one, 8);
@@ -1797,30 +1862,35 @@ int pt_http_complete_other(int h, uint64_t tag, int32_t stream, int status,
   return 0;
 }
 
-// out8 = {accepted, requests, active_conns, dropped, lat_p50_ns,
-// lat_p99_ns, lat_max_ns, lat_samples} — latency is server-side
-// (request parsed → response queued) over a 4096-sample ring.
-int pt_http_stats(int h, uint64_t* out8) {
+// out11 = {accepted, requests, active_conns, dropped, lat_p50_ns,
+// lat_p99_ns, lat_max_ns, lat_samples, answer_lock_waits,
+// answers_drained, answer_drain_ns} — latency is server-side (request
+// parsed → response queued) over a 4096-sample ring; the last three
+// are cumulative (see Server).
+int pt_http_stats(int h, uint64_t* out11) {
   std::lock_guard<std::mutex> reg(g_reg_mu);
   Server* s = g_servers[h];
   if (!s) return -EBADF;
   std::lock_guard<std::mutex> lk(s->mu);
-  out8[0] = s->accepted;
-  out8[1] = s->requests;
-  out8[2] = 0;
+  out11[0] = s->accepted;
+  out11[1] = s->requests;
+  out11[2] = 0;
   for (const auto& c : s->conns)
-    if (c.fd >= 0) out8[2]++;
-  out8[3] = s->dropped;
+    if (c.fd >= 0) out11[2]++;
+  out11[3] = s->dropped;
   uint64_t n = s->lat_count < Server::kLatRing ? s->lat_count : Server::kLatRing;
-  out8[4] = out8[5] = out8[6] = 0;
-  out8[7] = n;
+  out11[4] = out11[5] = out11[6] = 0;
+  out11[7] = n;
   if (n > 0) {
     std::vector<uint64_t> lat(s->lat_ns, s->lat_ns + n);
     std::sort(lat.begin(), lat.end());
-    out8[4] = lat[n / 2];
-    out8[5] = lat[(size_t)(n * 0.99) < n ? (size_t)(n * 0.99) : n - 1];
-    out8[6] = lat[n - 1];
+    out11[4] = lat[n / 2];
+    out11[5] = lat[(size_t)(n * 0.99) < n ? (size_t)(n * 0.99) : n - 1];
+    out11[6] = lat[n - 1];
   }
+  out11[8] = s->answer_lock_waits.load(std::memory_order_relaxed);
+  out11[9] = s->answers_drained;
+  out11[10] = s->answer_drain_ns;
   return 0;
 }
 

@@ -8,8 +8,10 @@ epoll, HTTP parse, percent-decoding, Go-semantics rate parsing, response
 formatting — into C++, and crosses into Python in BATCHES:
 
 * the pump thread drains up to ``batch`` parsed /take records in ONE
-  ctypes call, submits them as engine tickets (they coalesce into the
-  same device tick), waits, and completes them in ONE call back;
+  ctypes call and submits them as engine tickets (they coalesce into the
+  same device tick), each addressed to the front's :class:`AnswerSink`;
+  whichever thread completes a batch of tickets hands their answers back
+  in ONE call (``pt_http_answer_takes``, keeping the interpreter lock);
 * non-/take routes (debug, metrics — rare) are dispatched to the existing
   :class:`patrol_tpu.net.api.API` handlers on a private asyncio loop, so
   both fronts share one routing/semantics implementation.
@@ -25,6 +27,7 @@ connections splice byte-for-byte to the loopback python h2 server
 from __future__ import annotations
 
 import asyncio
+import collections
 import ctypes
 import logging
 import threading
@@ -35,15 +38,153 @@ import numpy as np
 from patrol_tpu import native
 from patrol_tpu.ops.rate import Rate
 from patrol_tpu.utils import histogram as hist
+from patrol_tpu.utils import profiling
 from patrol_tpu.utils import trace as trace_mod
 
 log = logging.getLogger("patrol.native-http")
 
 NAME_MAX = 256
-# The completer's bounded wait on one ticket: a tick stalled past it
-# snapshots the flight recorder (TakeTicket.wait's take-stall anomaly),
-# and the wait goes on.
-STALL_WAIT_S = 1.0
+# A native take unanswered this long snapshots the flight recorder (the
+# pump's take-stall check); it is still answered when it completes.
+STALL_NS = 1_000_000_000
+
+
+class AnswerSink:
+    """The native front's take answers on their way back to the C++ front.
+
+    A ticket submitted by the pump carries this sink, its connection tag
+    and h2 stream. ``TakeTicket.complete`` queues it (:meth:`add`); the
+    code that completed a batch of tickets then calls :meth:`flush` once
+    (``engine.hand_back``), which hands every pending answer over in one
+    ``pt_http_answer_takes`` call on that thread. The binding keeps the
+    interpreter lock (``NATIVE_EFFECTS`` ``keeps_gil``), so no answer
+    waits for a thread to take the lock back; the call appends under a
+    leaf mutex and the epoll thread queues the responses. The pump also
+    flushes on every poll, a backstop that should find nothing.
+
+    Each pump batch is open from before its submit until its last answer
+    is handed back: answers of a batch still being submitted wait in the
+    queue (so a batch that fails mid-submit is answered 500, once), and
+    the batch's last answer closes its ``http_front_wait_ns`` sample."""
+
+    def __init__(self, lib, h: int, cap: int):
+        self._lib = lib
+        self._h = h  # -1 once detached: answers are dropped, not sent
+        self._mu = threading.Lock()
+        self._pending: collections.deque = collections.deque()
+        self.add = self._pending.append  # TakeTicket.complete's hook
+        # pump batch id -> [answers left, poll stamp, submit stamp (0
+        # while the submit runs)], oldest first
+        self._open: dict = {}
+        self._answer = hist.FRONT_ANSWER  # complete → handed back
+        self._alloc(cap)
+
+    def _alloc(self, cap: int) -> None:
+        self._tags = np.zeros(cap, np.uint64)
+        self._streams = np.zeros(cap, np.int32)
+        self._statuses = np.zeros(cap, np.int32)
+        self._remaining = np.zeros(cap, np.int64)
+
+    def open(self, batch: int, n: int, t_poll: int) -> None:
+        with self._mu:
+            self._open[batch] = [n, t_poll, 0]
+
+    def submitted(self, batch: int, t_sub: int) -> None:
+        with self._mu:
+            self._open[batch][2] = t_sub
+
+    def drop(self, batch: int) -> None:
+        """A batch whose submit failed: its answers are never sent."""
+        with self._mu:
+            self._open.pop(batch, None)
+
+    def oldest(self):
+        """(batch id, poll stamp) of the oldest batch with an answer
+        outstanding, or None."""
+        with self._mu:
+            for batch, rec in self._open.items():
+                return batch, rec[1]
+        return None
+
+    def detach(self) -> None:
+        """The front is closing: nothing is sent from here on."""
+        with self._mu:
+            self._h = -1
+            self._open.clear()
+            self._pending.clear()
+
+    def flush(self, direct: bool = True) -> None:
+        """Hand every pending answer back in one native call. ``direct``:
+        called by the code that completed them (``http_answers_direct``),
+        else by the pump's backstop (``http_answers_backstop``). A call
+        that fails is logged, and its answers wait in the queue for the
+        next flush."""
+        pending = self._pending
+        if not pending:
+            return
+        with self._mu:
+            if self._h < 0:
+                pending.clear()
+                return
+            opened = self._open
+            live = []
+            held = []
+            for _ in range(len(pending)):
+                t = pending.popleft()
+                rec = opened.get(t.batch)
+                if rec is None:
+                    continue  # its batch failed: answered 500 already
+                (live if rec[2] else held).append(t)
+            pending.extend(held)
+            n = len(live)
+            if not n:
+                return
+            if n > len(self._tags):
+                self._alloc(2 * n)
+            tags, streams = self._tags, self._streams
+            statuses, remaining = self._statuses, self._remaining
+            oldest = live[0].batch
+            for i, t in enumerate(live):
+                tags[i] = t.tag
+                streams[i] = t.stream
+                statuses[i] = 200 if t.ok else 429
+                remaining[i] = t.remaining
+                if t.batch < oldest:
+                    oldest = t.batch
+            try:
+                with trace_mod.TRACE.span(trace_mod.EV_HTTP_ANSWER, n, oldest):
+                    self._lib.pt_http_answer_takes(
+                        self._h, tags, streams, statuses, remaining, n
+                    )
+                    t_handed = time.perf_counter_ns()
+            except Exception:
+                # Never into the completing code (the engine's threads):
+                # the answers go back to the queue for the pump's flush.
+                log.exception("take answer hand-back failed; the pump retries")
+                pending.extendleft(reversed(live))
+                return
+            waits = []
+            for t in live:
+                rec = opened[t.batch]
+                rec[0] -= 1
+                if not rec[0]:
+                    # The batch's last answer: submit → handed back.
+                    waits.append(t_handed - rec[2])
+                    del opened[t.batch]
+        if direct:
+            profiling.COUNTERS.inc("http_answers_direct", n)
+        else:
+            profiling.COUNTERS.inc("http_answers_backstop", n)
+        self._record_answers(live, t_handed)
+        for w in waits:
+            # patrol-scope: the front's engine-wait latency, one
+            # observation per pump batch — the Python-side complement of
+            # the C++ server's own ring (http_latency_* in stats()).
+            hist.FRONT_WAIT.record(w)
+
+    def _record_answers(self, tickets, t_handed: int) -> None:
+        """Each take's last seam stage: complete → handed back."""
+        self._answer.record_many([t_handed - t.done_ns for t in tickets])
 
 
 class NativeHTTPFront:
@@ -87,11 +228,10 @@ class NativeHTTPFront:
         self._req_ns = np.zeros(b, np.int64)  # parse stamps (CLOCK_MONOTONIC)
         self._batch = 0  # pump batch id, the seam's request id
         # The seam's per-take histograms (bound once, so a test's front
-        # can be given its own): parse → poll, poll → ticket, complete
-        # → handed back.
-        self._seam = (hist.FRONT_QUEUE, hist.FRONT_SUBMIT, hist.FRONT_ANSWER)
-        self._statuses = np.zeros(b, np.int32)
-        self._remaining = np.zeros(b, np.int64)
+        # can be given its own): parse → poll, poll → ticket; the sink
+        # binds complete → handed back.
+        self._seam = (hist.FRONT_QUEUE, hist.FRONT_SUBMIT)
+        self._sink = AnswerSink(lib, self.h, b)
         ob = 64
         self._otags = np.zeros(ob, np.uint64)
         self._ostreams = np.zeros(ob, np.int32)
@@ -108,17 +248,9 @@ class NativeHTTPFront:
             target=self._run_loop, name="patrol-http-debug", daemon=True
         )
         self._loop_thread.start()
-        # Pipelined pump: the poll/submit thread hands (tags, tickets)
-        # groups to the completer, so batch N+1 is being drained and
-        # submitted WHILE batch N's device tick runs — without this the
-        # front runs lock-step at ~2 ticks of latency per request.
-        import queue as _queue
-
-        self._cq: "_queue.Queue" = _queue.Queue(maxsize=64)
-        self._completer_thread = threading.Thread(
-            target=self._completer, name="patrol-http-complete", daemon=True
-        )
-        self._completer_thread.start()
+        # The pump never waits on a tick: batch N+1 is drained and
+        # submitted WHILE batch N's device tick runs, and the engine's
+        # completer hands batch N's answers back.
         self._pump_thread = threading.Thread(
             target=self._pump, name="patrol-http-pump", daemon=True
         )
@@ -153,6 +285,8 @@ class NativeHTTPFront:
         # slow path. Broadcast building keeps the cadence gate.
         events_seen = store.events if store is not None else 0
         tr = trace_mod.TRACE
+        sink = self._sink
+        stalled = 0  # the batch the last take-stall snapshot was for
         while not self._stopped.is_set():
             nt = self.lib.pt_http_poll(
                 self.h, poll_ms,
@@ -169,17 +303,19 @@ class NativeHTTPFront:
                 t_poll = time.perf_counter_ns()
                 self._batch += 1
                 with tr.span(trace_mod.EV_HTTP_PUMP, nt, self._batch):
-                    try:
-                        self._submit_takes(repo, nt, t_poll, self._batch)
-                    except Exception:  # pragma: no cover - keep the front alive
-                        log.exception("take pump failed; answering 500")
-                        tags = self._tags[:nt].copy()
-                        streams = self._streams[:nt].copy()
-                        st = np.full(nt, 500, np.int32)
-                        rem = np.zeros(nt, np.int64)
-                        self.lib.pt_http_complete_takes(
-                            self.h, tags, streams, st, rem, nt
-                        )
+                    self._submit_takes(repo, nt, t_poll, self._batch)
+            sink.flush(direct=False)
+            oldest = sink.oldest()
+            if (
+                oldest is not None
+                and oldest[0] != stalled
+                and time.perf_counter_ns() - oldest[1] > STALL_NS
+            ):
+                # A caller-visible take stall: freeze the flight recorder
+                # so the timeline that led here is inspectable (once per
+                # stalled batch; damped inside anomaly() besides).
+                stalled = oldest[0]
+                trace_mod.anomaly("take-stall")
             for j in range(n_other.value):
                 self._dispatch_other(j)
             if self._engine is not None:
@@ -208,7 +344,6 @@ class NativeHTTPFront:
                         self._engine.drain_native_promotions()
                     except Exception:  # pragma: no cover
                         log.exception("native promotion drain failed")
-        self._cq.put(None)  # unblock the completer at shutdown
 
     def _submit_takes(self, repo, nt: int, t_poll: int, batch: int) -> None:
         tags = self._tags[:nt].copy()
@@ -234,7 +369,7 @@ class NativeHTTPFront:
             # in-front C++ path only ever serves rows this pump created,
             # so rejecting creation closes the namespace on this front).
             sel = np.array(reserved, np.intp)
-            self.lib.pt_http_complete_takes(
+            self.lib.pt_http_answer_takes(
                 self.h, tags[sel], streams[sel],
                 np.full(len(sel), 400, np.int32),
                 np.zeros(len(sel), np.int64), len(sel),
@@ -247,54 +382,39 @@ class NativeHTTPFront:
             req_ns = req_ns[ksel]
             names = [names[i] for i in keep]
             rates = [rates[i] for i in keep]
-        res = repo.submit_takes_batch(names, rates, counts, batch=batch)
-        if res is None:  # pool spent with everything pinned: rare overload
-            raise RuntimeError("bucket pool spent; takes dropped")
-        self._cq.put(
-            (tags, streams, [t for t, _ in res], req_ns, t_poll,
-             time.perf_counter_ns(), batch)
-        )
+        sink = self._sink
+        n = len(names)
+        sink.open(batch, n, t_poll)
+        try:
+            res = repo.submit_takes_batch(
+                names, rates, counts, batch=batch,
+                answer=(sink, tags, streams),
+            )
+            if res is None:  # pool spent with everything pinned: rare overload
+                raise RuntimeError("bucket pool spent; takes dropped")
+        except Exception:  # pragma: no cover - keep the front alive
+            log.exception("take pump failed; answering 500")
+            sink.drop(batch)
+            self.lib.pt_http_answer_takes(
+                self.h, tags, streams, np.full(n, 500, np.int32),
+                np.zeros(n, np.int64), n,
+            )
+            return
+        sink.submitted(batch, time.perf_counter_ns())
+        # The answers this thread completed inside the submit (host-served
+        # and shed takes), and any the engine completed meanwhile.
+        sink.flush()
+        self._record_intake(req_ns, t_poll, [t for t, _ in res])
 
-    def _completer(self) -> None:
-        tr = trace_mod.TRACE
-        while True:
-            group = self._cq.get()
-            if group is None:
-                return
-            tags, streams, tickets, req_ns, t_poll, t_sub, batch = group
-            nt = len(tickets)
-            # Tickets submitted together complete in the same engine
-            # tick(s); ordered waits cost one tick total, not one each.
-            for t in tickets:
-                while not t.wait(STALL_WAIT_S):
-                    pass
-            # patrol-scope: the front's engine-wait latency (submit to
-            # batch completion), one observation per pump batch — the
-            # Python-side complement of the C++ server's own ring
-            # (http_latency_* in stats()).
-            hist.FRONT_WAIT.record(time.perf_counter_ns() - t_sub)
-            with tr.span(trace_mod.EV_HTTP_ANSWER, nt, batch):
-                statuses = np.fromiter(
-                    (200 if t.ok else 429 for t in tickets), np.int32, nt
-                )
-                remaining = np.fromiter(
-                    (t.remaining for t in tickets), np.int64, nt
-                )
-                self.lib.pt_http_complete_takes(
-                    self.h, tags, streams, statuses, remaining, nt
-                )
-                t_handed = time.perf_counter_ns()
-            self._record_seam(req_ns, t_poll, tickets, t_handed)
-
-    def _record_seam(self, req_ns, t_poll: int, tickets, t_handed: int) -> None:
+    def _record_intake(self, req_ns, t_poll: int, tickets) -> None:
         """One pump batch's takes, each tiled parse → handed back across
-        the seam: queue (parse → poll returned), submit (poll returned →
-        ticket created), the engine's own take_service_ns (created →
-        complete), answer (complete → answers handed to the front)."""
-        queue, submit, answer = self._seam
+        the seam: queue (parse → poll returned) and submit (poll returned
+        → ticket created) here, the engine's own take_service_ns (created
+        → complete), and the sink's answer stage (complete → handed
+        back)."""
+        queue, submit = self._seam
         queue.record_many([t_poll - r for r in req_ns.tolist()])
         submit.record_many([t.t0_ns - t_poll for t in tickets])
-        answer.record_many([t_handed - t.done_ns for t in tickets])
 
     def _dispatch_other(self, j: int) -> None:
         tag = int(self._otags[j])
@@ -333,7 +453,7 @@ class NativeHTTPFront:
         self.h2_backend_port = port
 
     def stats(self) -> dict:
-        out = np.zeros(8, np.uint64)
+        out = np.zeros(11, np.uint64)
         self.lib.pt_http_stats(self.h, out)
         return {
             "http_accepted": int(out[0]),
@@ -344,9 +464,18 @@ class NativeHTTPFront:
             "http_latency_p50_us": int(out[4]) // 1000,
             "http_latency_p99_us": int(out[5]) // 1000,
             "http_latency_max_us": int(out[6]) // 1000,
+            # Take answers handed over (pt_http_answer_takes): calls that
+            # waited for the answer queue's lock, answers the epoll thread
+            # queued on their connections, and their summed handed →
+            # queued time (cumulative).
+            "http_answer_lock_waits": int(out[8]),
+            "http_answers_drained": int(out[9]),
+            "http_answer_drain_ns": int(out[10]),
         }
 
     def close(self) -> None:
+        if self._stopped.is_set():
+            return  # closed already: the handle may name another server now
         # Detach the host store FIRST (under the server mutex): the engine
         # destroys the store after this front closes, and the epoll thread
         # must never touch freed blocks — even on the leaked-server path.
@@ -354,11 +483,13 @@ class NativeHTTPFront:
             self.lib.pt_http_attach_host(self.h, -1, -1)
         self._stopped.set()
         self._pump_thread.join(timeout=5)
-        self._completer_thread.join(timeout=5)
-        if self._pump_thread.is_alive() or self._completer_thread.is_alive():
-            # pt_http_poll/complete_takes deliberately skip the registry
-            # lock (they assume the pumps are joined first); destroying the
-            # Server under a live pump would be a use-after-free. Leak the
+        # No answer is handed back from here on: a ticket completed later
+        # is dropped from the sink, never sent to a stopping server.
+        self._sink.detach()
+        if self._pump_thread.is_alive():
+            # pt_http_poll deliberately skips the registry lock (it
+            # assumes the pump is joined first); destroying the Server
+            # under a live pump would be a use-after-free. Leak the
             # native server instead — the process is shutting down anyway.
             # The host store must leak WITH it: a wedged pump may be
             # mid-drain inside the store, and engine.stop would otherwise
@@ -366,7 +497,7 @@ class NativeHTTPFront:
             if self._engine is not None:
                 self._engine._leak_native_store = True
             log.error(
-                "http pump threads did not exit in 5s; leaking native server "
+                "http pump thread did not exit in 5s; leaking native server "
                 "handle %d to avoid a use-after-free", self.h,
             )
         else:

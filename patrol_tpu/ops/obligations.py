@@ -1229,6 +1229,14 @@ KERNEL_FAMILIES: Tuple[KernelFamily, ...] = (
 # (the effects-table sweep covers every exported native symbol).
 TOOLCHAIN_ABI: Tuple[AbiObligation, ...] = (
     AbiObligation(
+        # The HTTP front's answer hand-back keeps the interpreter lock
+        # (NATIVE_EFFECTS keeps_gil): every binding as the table declares
+        # it, the argument types against the C definition, leaf mutexes
+        # only, no native call into Python, and a live answer.
+        "native.pt_http_answer_takes", "pt_http_answer_takes",
+        ("PTA001",), "held_binding_conformance",
+    ),
+    AbiObligation(
         "native.effects_table", None, ("PTA005",), "effects_table",
     ),
 )

@@ -34,7 +34,7 @@ import threading
 import time
 from collections import deque
 from functools import lru_cache
-from typing import Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
+from typing import Callable, Dict, Iterable, List, NamedTuple, Optional, Sequence, Set, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -491,6 +491,9 @@ class TakeTicket:
         "done_ns",
         "batch",
         "trace_id",
+        "sink",
+        "tag",
+        "stream",
     )
 
     def __init__(
@@ -524,6 +527,13 @@ class TakeTicket:
         self.done_ns = 0
         self.batch = batch
         self.trace_id = trace_mod.sample_take()
+        # Where a native-front take's answer goes: the front's answer
+        # sink, the connection tag and the h2 stream (0 = HTTP/1.1). Set
+        # before the ticket can complete (submit_takes_batch's
+        # ``answer``); None for every other caller.
+        self.sink = None
+        self.tag = 0
+        self.stream = 0
 
     def complete(self, remaining: int, ok: bool) -> bool:
         """Returns True on the first completion (False if already done) —
@@ -539,6 +549,10 @@ class TakeTicket:
                 self.done_ns = time.perf_counter_ns()
             self._event.set()
             callbacks, self._callbacks = self._callbacks, []
+        if self.sink is not None:
+            # Queued for the front; the completing code hands the batch
+            # back in one native call (hand_back).
+            self.sink.add(self)
         for cb in callbacks:
             cb()
         return True
@@ -560,6 +574,29 @@ class TakeTicket:
             # inspectable after the fact (damped inside anomaly()).
             trace_mod.anomaly("take-stall")
         return ok
+
+
+def _addressed(ticket: TakeTicket, answer: Optional[tuple], i: int) -> TakeTicket:
+    """``ticket`` with request ``i``'s answer address from ``answer`` =
+    (sink, tags, streams), or unchanged without one."""
+    if answer is not None:
+        ticket.sink = answer[0]
+        ticket.tag = int(answer[1][i])
+        ticket.stream = int(answer[2][i])
+    return ticket
+
+
+def hand_back(tickets: Iterable[TakeTicket]) -> None:
+    """Hand the native front's answers among ``tickets``, completed just
+    before, back to their front: one flush (one native call) per sink, on
+    the thread that completed them. Never raises: the completing code
+    still has its tick to finish (pins, audit, broadcasts), and an answer
+    a flush could not send waits for the front's own flush."""
+    for sink in {t.sink for t in tickets if t.sink is not None}:
+        try:
+            sink.flush()
+        except Exception:
+            log.exception("take answer hand-back failed")
 
 
 class _TakeFold:
@@ -1632,14 +1669,14 @@ class DeviceEngine:
         created = fresh or int(self.directory.cap_base_nt[row]) == 0
         self.directory.init_cap_base(row, rate.freq * NANO)
         self.directory.note_rate(row, rate.per_ns)
-        if HOST_FASTPATH and (fresh or self._hosted_flag[row]):
-            ticket = self._try_host_take(
-                name, row, rate, count, now, fresh, seed=seed
-            )
-            if ticket is not None:
-                self._kick_gc_if_due(now)
-                return ticket, created
         ticket = TakeTicket(name, row, rate, count, now)
+        if (
+            HOST_FASTPATH
+            and (fresh or self._hosted_flag[row])
+            and self._host_serve_ticket(ticket, fresh, seed=seed)
+        ):
+            self._kick_gc_if_due(now)
+            return ticket, created
         with self._cond:
             if seed is not None:
                 # Tombstone re-seed rides the SAME tick's merge phase —
@@ -1651,25 +1688,6 @@ class DeviceEngine:
         return ticket, created
 
     # -- host fast path (cold/low-QPS buckets; VERDICT r3 item 1) -----------
-
-    def _try_host_take(
-        self,
-        name: str,
-        row: int,
-        rate: Rate,
-        count: int,
-        now: int,
-        fresh: bool,
-        out_broadcasts: Optional[List[wire.WireState]] = None,
-        seed: Optional[Tuple[int, int, int]] = None,
-    ) -> Optional[TakeTicket]:
-        """Serve one take from the host-resident lane model, in-process.
-        Returns the already-completed ticket, or None when the row is (or
-        just became) device-resident — the caller falls through to the
-        device queue."""
-        ticket = TakeTicket(name, row, rate, count, now)
-        served = self._host_serve_ticket(ticket, fresh, out_broadcasts, seed)
-        return ticket if served else None
 
     def _host_serve_ticket(
         self,
@@ -2393,15 +2411,22 @@ class DeviceEngine:
         counts: Sequence[int],
         now_ns: Optional[int] = None,
         batch: int = 0,
+        answer: Optional[tuple] = None,
     ) -> Optional[List[Tuple[TakeTicket, bool]]]:
         """Batched :meth:`submit_take` for the native HTTP pump: ONE
         directory pass (assign_many), one capacity init, one queue
         append + wake-up, instead of per-request lock/notify churn.
         Returns [(ticket, created), ...] in request order, or None when
         the pool is spent with every row pinned (the caller falls back or
-        fails the batch). Under the memory budget's hard watermark,
-        requests for NEW names come back as already-completed shed
-        tickets (ok=False) — per-request 429s, never a failed batch."""
+        fails the batch; no ticket was completed). Under the memory
+        budget's hard watermark, requests for NEW names come back as
+        already-completed shed tickets (ok=False) — per-request 429s,
+        never a failed batch. ``answer`` = (sink, tags, streams), one
+        tag and stream per request: each ticket carries its answer
+        address from creation, so whichever thread completes it hands
+        the answer back (:func:`hand_back`); the tickets this call
+        completes itself (host-served, shed) are the caller's to hand
+        back once it returns."""
         now = self.clock() if now_ns is None else now_ns
         if self._max_buckets or self._bytes_budget:
             unknown = [
@@ -2411,11 +2436,6 @@ class DeviceEngine:
             if unknown and self._shed_new_names(now, len(unknown)):
                 shed = set(unknown)
                 out: List = [None] * len(names)
-                for i in unknown:
-                    t = TakeTicket(names[i], 0, rates[i], int(counts[i]), now)
-                    t.shed = True  # overload shed, not a rate deny
-                    t.complete(0, False)  # never pinned, never queued
-                    out[i] = (t, False)
                 keep = [i for i in range(len(names)) if i not in shed]
                 if keep:
                     sub = self._submit_takes_batch_inner(
@@ -2424,14 +2444,27 @@ class DeviceEngine:
                         [counts[i] for i in keep],
                         now,
                         batch,
+                        answer and (
+                            answer[0],
+                            [answer[1][i] for i in keep],
+                            [answer[2][i] for i in keep],
+                        ),
                     )
                     if sub is None:
                         return None
                     for i, r in zip(keep, sub):
                         out[i] = r
+                for i in unknown:
+                    t = _addressed(
+                        TakeTicket(names[i], 0, rates[i], int(counts[i]), now, batch),
+                        answer, i,
+                    )
+                    t.shed = True  # overload shed, not a rate deny
+                    t.complete(0, False)  # never pinned, never queued
+                    out[i] = (t, False)
                 return out
         return self._submit_takes_batch_inner(
-            list(names), list(rates), list(counts), now, batch
+            list(names), list(rates), list(counts), now, batch, answer
         )
 
     def _submit_takes_batch_inner(
@@ -2441,6 +2474,7 @@ class DeviceEngine:
         counts: Sequence[int],
         now: int,
         batch: int = 0,
+        answer: Optional[tuple] = None,
     ) -> Optional[List[Tuple[TakeTicket, bool]]]:
         res = self._assign_many_pinned(list(names), now, with_fresh=True)
         if res is None:
@@ -2478,7 +2512,16 @@ class DeviceEngine:
         # DIRECTORY's bind-fresh signal — a cap==0 proxy would mis-host
         # rows that already carry replicated device lanes (cap-less raw
         # lane deltas never set the cap).
-        host_served: Dict[int, TakeTicket] = {}
+        tickets = [
+            _addressed(
+                TakeTicket(
+                    names[i], int(rows[i]), rates[i], int(counts[i]), now, batch
+                ),
+                answer, i,
+            )
+            for i in range(len(names))
+        ]
+        host_served: Set[int] = set()
         if HOST_FASTPATH:
             fresh_first = fresh_first_all
             # Candidates only — the device-only common case stays one
@@ -2490,24 +2533,15 @@ class DeviceEngine:
             bc: List[wire.WireState] = []
             for i in np.flatnonzero(self._hosted_flag[rows] | bind_fresh):
                 if self._hosted_flag[rows[i]] or fresh_first[i]:
-                    t = self._try_host_take(
-                        names[i], int(rows[i]), rates[i], int(counts[i]),
-                        now, bool(fresh_first[i]), out_broadcasts=bc,
-                        seed=seeds.get(int(rows[i])),
-                    )
-                    if t is not None:
-                        host_served[int(i)] = t
+                    if self._host_serve_ticket(
+                        tickets[i], bool(fresh_first[i]), bc,
+                        seeds.get(int(rows[i])),
+                    ):
+                        host_served.add(int(i))
                         if fresh_first[i]:
                             # Seed landed in the fresh host lanes.
                             seeds.pop(int(rows[i]), None)
             self._emit_broadcasts(bc)
-        tickets = [
-            host_served.get(i)
-            or TakeTicket(
-                names[i], int(rows[i]), rates[i], int(counts[i]), now, batch
-            )
-            for i in range(len(names))
-        ]
         queued = [t for i, t in enumerate(tickets) if i not in host_served]
         if host_served and not queued:
             # Fully host-served batch: no feeder work queued — kick the
@@ -3959,14 +3993,17 @@ class DeviceEngine:
             # through, so a row is never served by both paths at once.
             if HOST_FASTPATH and self._hosted and tickets:
                 bc: List[wire.WireState] = []
-                tickets = [
-                    t
-                    for t in tickets
-                    if not (
-                        self._hosted_flag[t.row]
-                        and self._host_serve_ticket(t, False, bc)
-                    )
-                ]
+                served: List[TakeTicket] = []
+                kept: List[TakeTicket] = []
+                for t in tickets:
+                    if self._hosted_flag[t.row] and self._host_serve_ticket(
+                        t, False, bc
+                    ):
+                        served.append(t)
+                    else:
+                        kept.append(t)
+                tickets = kept
+                hand_back(served)
                 self._emit_broadcasts(bc)
             t_tick0 = time.perf_counter_ns()
             try:
@@ -4108,6 +4145,7 @@ class DeviceEngine:
         unpin = [
             t.row for t in tickets if not t.deferred and t.complete(0, False)
         ]
+        hand_back(tickets)
         if unpin:
             self.directory.unpin_rows(unpin)
 
@@ -4164,12 +4202,15 @@ class DeviceEngine:
         self, keys, groups, have, admitted, own_a, own_t, elapsed, sum_a, sum_t
     ) -> None:
         """Fan per-group kernel results out to tickets + broadcast hook.
-        Completion releases each ticket's directory pin."""
+        Completion releases each ticket's directory pin. Every ticket
+        completes, and the native front's answers among them are handed
+        back, before the tick's audit notes and broadcasts are built."""
         broadcasts: List[wire.WireState] = []
         unpin: List[int] = []
         done_ns = time.perf_counter_ns()
         now_clock = self.clock()
         take_hist = self._take_service
+        admitted_nts: List[int] = []
         for i, key in enumerate(keys):
             ts = groups[key]
             c_nt = ts[0].count * NANO
@@ -4194,6 +4235,11 @@ class DeviceEngine:
                             t.trace_id, self.node_slot, "take", t.name,
                             t.t0_ns, done_ns - t.t0_ns,
                         )
+            admitted_nts.append(admitted_nt)
+        hand_back(t for key in keys for t in groups[key])
+        for i, key in enumerate(keys):
+            ts = groups[key]
+            admitted_nt = admitted_nts[i]
             # Replicate. The reference broadcasts full state on every take,
             # success or not (api.go:74, README.md:41-43) — even a failed
             # first take commits the lazy capacity init (bucket.go:194-196),

@@ -57,12 +57,15 @@ class TPURepo:
             self._maybe_incast(name)
         return ticket
 
-    def submit_takes_batch(self, names, rates, counts, batch: int = 0):
+    def submit_takes_batch(self, names, rates, counts, batch: int = 0, answer=None):
         """Batched :meth:`submit_take` (native HTTP pump): one engine
         directory pass, then the per-created incast solicitations.
-        ``batch`` is the pump batch id the tickets carry. → [(ticket,
+        ``batch`` is the pump batch id the tickets carry, ``answer`` their
+        answer addresses (engine ``submit_takes_batch``). → [(ticket,
         created), ...] or None on a fully-pinned pool."""
-        res = self.engine.submit_takes_batch(names, rates, counts, batch=batch)
+        res = self.engine.submit_takes_batch(
+            names, rates, counts, batch=batch, answer=answer
+        )
         if res is None:
             return None
         for (ticket, created), name in zip(res, names):

@@ -351,6 +351,11 @@ class CounterRegistry:
         "rx_walk_native_batches",
         "rx_walk_numpy_batches",
         "rx_walk_ns",
+        # Native-front take answers handed back to the C++ front by the
+        # thread that completed them, and by the pump's backstop flush
+        # (net/native_http.py AnswerSink): the backstop should find none.
+        "http_answers_direct",
+        "http_answers_backstop",
         # XLA backend compiles (and their ns) seen by the jax.monitoring
         # listener (count_jax_compiles): 0 in a warm serving window.
         "jit_compiles",

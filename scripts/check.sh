@@ -47,7 +47,7 @@
 #             interleavings of the epoll-seam protocol model
 #             (pt_http_poll park/drain, completion-ring (slot, gen)
 #             tags) checking lost wakeups and completion-ring token
-#             conservation (PTR001-002, 3 seeded mutations rejected),
+#             conservation (PTR001-002, 4 seeded mutations rejected),
 #             plus the guarded-state / lock-graph / condvar-predicate /
 #             buffer-ownership AST passes over the engine/net thread
 #             ensemble (PTR003-005); and the pytest -m race self-tests.

@@ -7,13 +7,15 @@ Stage 7 of the `scripts/check.sh` gate, runnable standalone. Two halves
 
   dynamic  exhaustive deterministic interleavings of the C++ HTTP
            front's epoll-seam protocol model (pt_http_poll park/drain,
-           completion-ring (slot, gen) tags, pt_http_complete_takes
-           fan-in) across epoll-script / pump / completer actors:
+           completion-ring (slot, gen) tags, the pt_http_answer_takes
+           queue and the epoll thread's eventfd drain) across
+           epoll-script / pump / completer actors:
     PTR001   lost wakeup / stalled completion (liveness)
     PTR002   completion-ring token conservation (safety)
-           with three seeded mutations (completion-before-park,
-           ring-slot reuse without fence, ack-without-holding-mutex)
-           that must each be demonstrably rejected.
+           with four seeded mutations (completion-before-park,
+           ring-slot reuse without fence, ack-without-holding-mutex,
+           drain-before-eventfd-read) that must each be demonstrably
+           rejected.
 
   static   over the engine/net thread-ensemble sources:
     PTR003   guarded attribute touched outside its declared lock

@@ -9,9 +9,12 @@
 //     host-store takes (hls_take_locked) contending the HostStore mutex
 //     against a drain thread (≙ the engine pump's drain_locked) and a
 //     probe thread (pt_hls_take_probe);
-//   * a pump thread on the ring path: pt_http_poll → complete_takes /
+//   * a pump thread on the ring path: pt_http_poll → answer_takes /
 //     complete_other, racing the epoll thread on the Server mutex (and,
-//     at shutdown, the registry teardown path);
+//     at shutdown, the registry teardown path); every other batch of
+//     take answers goes to a completer thread (≙ the engine's completer)
+//     that hands it over through pt_http_answer_takes concurrently with
+//     the pump, racing the epoll thread's drain on the answer queue;
 //   * the load clients pt_http_blast / pt_http_blast_h2 from multiple
 //     threads (closed-loop h1 pipelining and h2 multiplexing);
 //   * hostile inputs while the load runs: oversized/overflowing
@@ -30,9 +33,11 @@
 #include <unistd.h>
 
 #include <atomic>
+#include <chrono>
 #include <cstdint>
 #include <cstdio>
 #include <cstring>
+#include <mutex>
 #include <string>
 #include <thread>
 #include <vector>
@@ -46,13 +51,13 @@ int pt_http_poll(int h, int timeout_ms, uint64_t* tags, int32_t* streams,
                  uint64_t* otags,
                  int32_t* ostreams, uint8_t* otargets, int* otarget_lens,
                  uint8_t* omethods, int cap_o, int* n_other);
-int pt_http_complete_takes(int h, const uint64_t* tags,
-                           const int32_t* streams, const int* statuses,
-                           const int64_t* remaining, int n);
+int pt_http_answer_takes(int h, const uint64_t* tags, const int32_t* streams,
+                         const int* statuses, const int64_t* remaining,
+                         int n);
 int pt_http_complete_other(int h, uint64_t tag, int32_t stream, int status,
                            const char* ctype, const uint8_t* body,
                            int body_len);
-int pt_http_stats(int h, uint64_t* out8);
+int pt_http_stats(int h, uint64_t* out11);
 int pt_http_stop(int h);
 int pt_http_attach_host(int http_h, int hls_h, int dir_h);
 int pt_http_blast(const char* ip, uint16_t port, const char* target,
@@ -315,7 +320,39 @@ int main() {
 
   std::atomic<bool> stop{false};
 
-  // Ring pump (≙ net/native_http.py _pump + _completer, minus Python).
+  // Answer batches the pump leaves to the completer thread.
+  struct Answers {
+    std::vector<uint64_t> tags;
+    std::vector<int32_t> streams;
+    std::vector<int> statuses;
+    std::vector<int64_t> remaining;
+  };
+  std::mutex handoff_mu;
+  std::vector<Answers> handoff;
+  std::atomic<bool> pump_done{false};
+
+  // Completer (≙ the engine's completer handing answers back): answers
+  // each batch it is given through pt_http_answer_takes, concurrently
+  // with the pump's own answers and the epoll thread's drain.
+  std::thread completer([&] {
+    while (true) {
+      std::vector<Answers> q;
+      {
+        std::lock_guard<std::mutex> lk(handoff_mu);
+        q.swap(handoff);
+      }
+      for (Answers& a : q)
+        pt_http_answer_takes(hs, a.tags.data(), a.streams.data(),
+                             a.statuses.data(), a.remaining.data(),
+                             (int)a.tags.size());
+      if (q.empty()) {
+        if (pump_done.load()) return;
+        std::this_thread::sleep_for(std::chrono::microseconds(100));
+      }
+    }
+  });
+
+  // Ring pump (≙ net/native_http.py _pump, minus Python).
   std::thread pump([&] {
     constexpr int CT = 256, CO = 64;
     std::vector<uint64_t> tags(CT), otags(CO);
@@ -325,6 +362,7 @@ int main() {
     std::vector<int> nlens(CT), otlens(CO), statuses(CT);
     std::vector<int64_t> freqs(CT), pers(CT), counts(CT), req_ns(CT),
         remaining(CT);
+    uint64_t batches = 0;
     while (!stop.load()) {
       int n_other = 0;
       int nt = pt_http_poll(hs, 10, tags.data(), streams.data(),
@@ -338,9 +376,16 @@ int main() {
         statuses[i] = (freqs[i] > 0) ? 200 : 429;
         remaining[i] = freqs[i] > 0 ? freqs[i] - 1 : 0;
       }
-      if (nt > 0)
-        pt_http_complete_takes(hs, tags.data(), streams.data(),
-                               statuses.data(), remaining.data(), nt);
+      if (nt > 0 && (++batches & 1)) {
+        std::lock_guard<std::mutex> lk(handoff_mu);
+        handoff.push_back({{tags.begin(), tags.begin() + nt},
+                           {streams.begin(), streams.begin() + nt},
+                           {statuses.begin(), statuses.begin() + nt},
+                           {remaining.begin(), remaining.begin() + nt}});
+      } else if (nt > 0) {
+        pt_http_answer_takes(hs, tags.data(), streams.data(),
+                             statuses.data(), remaining.data(), nt);
+      }
       for (int j = 0; j < n_other; j++) {
         const char body[] = "ok\n";
         pt_http_complete_other(hs, otags[j], ostreams[j], 200, "text/plain",
@@ -403,22 +448,28 @@ int main() {
   prober.join();
   drainer.join();
   pump.join();
+  pump_done.store(true);
+  completer.join();
 
-  uint64_t stats[8];
+  uint64_t stats[11];
   pt_http_stats(hs, stats);
   pt_http_attach_host(hs, -1, -1);
   pt_http_stop(hs);
   pt_hls_destroy(hls);
   pt_dir_destroy(dir);
 
-  if (rc1 != 0 || rc2 != 0 || done_h1 == 0 || done_h2 == 0) {
+  // stats[9]: ring-path take answers the epoll thread drained.
+  if (rc1 != 0 || rc2 != 0 || done_h1 == 0 || done_h2 == 0 || stats[9] == 0) {
     fprintf(stderr,
-            "driver failed: rc1=%d rc2=%d h1=%llu h2=%llu\n", rc1, rc2,
-            (unsigned long long)done_h1, (unsigned long long)done_h2);
+            "driver failed: rc1=%d rc2=%d h1=%llu h2=%llu drained=%llu\n",
+            rc1, rc2, (unsigned long long)done_h1,
+            (unsigned long long)done_h2, (unsigned long long)stats[9]);
     return 1;
   }
-  printf("san http driver ok: h1=%llu h2=%llu requests=%llu accepted=%llu\n",
+  printf("san http driver ok: h1=%llu h2=%llu requests=%llu accepted=%llu "
+         "answers drained=%llu lock waits=%llu\n",
          (unsigned long long)done_h1, (unsigned long long)done_h2,
-         (unsigned long long)stats[1], (unsigned long long)stats[0]);
+         (unsigned long long)stats[1], (unsigned long long)stats[0],
+         (unsigned long long)stats[9], (unsigned long long)stats[8]);
   return 0;
 }

@@ -215,6 +215,124 @@ class TestRawWalkConformance:
         assert any("C definition 19" in x.message for x in f)
 
 
+# --- PTA001: the binding that keeps the interpreter lock -------------------
+
+
+class TestHeldBindingConformance:
+    class _Shim:
+        """Delegating facade over the real lib, one symbol rebound."""
+
+        def __init__(self, lib, name, fn):
+            self._lib = lib
+            setattr(self, name, fn)
+
+        def __getattr__(self, name):
+            return getattr(self._lib, name)
+
+    HTTP = "patrol_tpu/native/patrol_http.cpp"
+
+    def _check(self, lib):
+        return abi.check_held_binding_conformance(
+            OBS["held_binding_conformance"], lib
+        )
+
+    def _rebound(self, lib, dll, types=None):
+        import ctypes
+
+        fn = dll(lib._name).pt_http_answer_takes
+        fn.argtypes = types or lib.pt_http_answer_takes.argtypes
+        fn.restype = ctypes.c_int
+        return self._Shim(lib, "pt_http_answer_takes", fn)
+
+    def _edited(self, monkeypatch, old, new):
+        """The native sources with one edit in the HTTP front's."""
+        sources = abi._native_sources()
+        assert sources[self.HTTP].count(old) == 1
+        sources[self.HTTP] = sources[self.HTTP].replace(old, new)
+        monkeypatch.setattr(abi, "_native_sources", lambda: sources)
+
+    def test_shipped_binding_is_silent(self, lib):
+        assert self._check(lib) == []
+
+    def test_lock_releasing_hand_back_is_rejected(self, lib):
+        import ctypes
+
+        f = self._check(self._rebound(lib, ctypes.CDLL))
+        assert codes(f) == ["PTA001"]
+        assert any("drops the interpreter lock" in x.message for x in f)
+
+    def test_undeclared_lock_holding_binding_is_rejected(self, lib):
+        import ctypes
+
+        held = ctypes.PyDLL(lib._name).pt_http_stats
+        held.argtypes = lib.pt_http_stats.argtypes
+        f = self._check(self._Shim(lib, "pt_http_stats", held))
+        assert codes(f) == ["PTA001"]
+        assert any(
+            "pt_http_stats is bound through PyDLL" in x.message for x in f
+        )
+
+    def test_argtype_drift_is_rejected(self, lib):
+        import ctypes
+
+        types = list(lib.pt_http_answer_takes.argtypes)
+        types[3] = types[4]  # statuses declared int64
+        f = self._check(self._rebound(lib, ctypes.PyDLL, types))
+        assert codes(f) == ["PTA001"]
+        assert any("argument 3" in x.message for x in f)
+
+    def test_hand_back_under_the_server_mutex_is_rejected(self, lib, monkeypatch):
+        """The server's mu is held by the epoll thread while it waits on
+        the host-lane store mutex: keeping the interpreter lock while
+        waiting on it can deadlock the node."""
+        self._edited(
+            monkeypatch,
+            "    std::lock_guard<std::mutex> ak(s->answer_mu, std::adopt_lock);\n"
+            "    for (int i = 0; i < n; i++)\n"
+            "      s->answers.push_back(",
+            "    std::lock_guard<std::mutex> ak(s->mu);\n"
+            "    for (int i = 0; i < n; i++)\n"
+            "      s->answers.push_back(",
+        )
+        f = self._check(lib)
+        assert codes(f) == ["PTA001"]
+        assert any("takes mu, which" in x.message for x in f)
+
+    def test_answer_lock_that_is_no_leaf_is_rejected(self, lib, monkeypatch):
+        self._edited(
+            monkeypatch,
+            "    q.swap(s->answers);\n",
+            "    q.swap(s->answers);\n"
+            "    std::lock_guard<std::mutex> lk(s->mu);\n",
+        )
+        f = self._check(lib)
+        assert codes(f) == ["PTA001"]
+        assert any("takes answer_mu, which" in x.message for x in f)
+
+    def test_native_call_into_python_is_rejected(self, lib, monkeypatch):
+        self._edited(
+            monkeypatch,
+            "void serve_loop(Server* s) {",
+            "static void cb() { PyGILState_Ensure(); }\n"
+            "void serve_loop(Server* s) {",
+        )
+        f = self._check(lib)
+        assert codes(f) == ["PTA001"]
+        assert any("calls into Python" in x.message for x in f)
+
+    def test_blocking_declaration_is_rejected(self, monkeypatch, lib):
+        import patrol_tpu.native as native_mod
+
+        table = dict(NATIVE_EFFECTS)
+        table["pt_http_answer_takes"] = table["pt_http_answer_takes"]._replace(
+            blocks=True
+        )
+        monkeypatch.setattr(native_mod, "NATIVE_EFFECTS", table)
+        f = self._check(lib)
+        assert codes(f) == ["PTA001"]
+        assert any("declared to block" in x.message for x in f)
+
+
 # --- PTA002/PTA003: merge laws on the native side ---------------------------
 
 
@@ -469,7 +587,11 @@ class TestEffectsTable:
         assert not NATIVE_EFFECTS["pt_hls_events"].takes_host_mu
 
     @pytest.mark.parametrize(
-        "symbol", ["pt_http_poll", "pt_http_complete_takes", "pt_http_stats"]
+        "symbol",
+        [
+            "pt_http_poll", "pt_http_complete_other", "pt_http_answer_takes",
+            "pt_http_stats",
+        ],
     )
     def test_http_front_argtypes_match_the_c_definition(self, symbol):
         """The ctypes registration passes exactly the C definition's
@@ -544,6 +666,7 @@ class TestRepoAbiClean:
             "native.pt_fold_hybrid",
             "native.pt_rx_classify",
             "native.pt_raw_walk",
+            "native.pt_http_answer_takes",
             "native.hls_schedules",
             "native.effects_table",
         ):

@@ -389,24 +389,33 @@ class TestSeamTiling:
         from patrol_tpu.utils import trace as trace_mod
 
         front, hists = seam_front
-        seen = []
-        record = native_http.NativeHTTPFront._record_seam
+        seen = []  # (parse stamps, poll stamp, tickets) per pump batch
+        handed = {}  # ticket → its handed-back stamp
+        intake = native_http.NativeHTTPFront._record_intake
+        answers = native_http.AnswerSink._record_answers
 
-        def spy(self, req_ns, t_poll, tickets, t_handed):
+        def spy_intake(self, req_ns, t_poll, tickets):
             if self is front:
-                seen.append((
-                    np.array(req_ns), t_poll,
-                    [(t.t0_ns, t.done_ns, t.batch) for t in tickets], t_handed,
-                ))
-            record(self, req_ns, t_poll, tickets, t_handed)
+                seen.append((np.array(req_ns), t_poll, list(tickets)))
+            intake(self, req_ns, t_poll, tickets)
 
-        monkeypatch.setattr(native_http.NativeHTTPFront, "_record_seam", spy)
+        def spy_answers(self, tickets, t_handed):
+            if self is front._sink:
+                handed.update((t, t_handed) for t in tickets)
+            answers(self, tickets, t_handed)
+
+        monkeypatch.setattr(
+            native_http.NativeHTTPFront, "_record_intake", spy_intake
+        )
+        monkeypatch.setattr(native_http.AnswerSink, "_record_answers", spy_answers)
         t_send = time.perf_counter_ns()
         names = [f"seam{i}" for i in range(24)]
         statuses = _take_burst(front.port, names)
         assert all(s in (200, 429) for s in statuses)
         deadline = time.monotonic() + 5
-        while sum(len(b[2]) for b in seen) < len(names):
+        while (
+            sum(len(b[2]) for b in seen) < len(names) or len(handed) < len(names)
+        ):
             assert time.monotonic() < deadline, "batches never recorded"
             time.sleep(0.01)
         for name in SEAM:
@@ -415,17 +424,18 @@ class TestSeamTiling:
         # created ≤ complete ≤ handed back, so they tile exactly.
         want = 0
         service = 0
-        for req_ns, t_poll, tix, t_handed in seen:
-            for r, (t0, done, _) in zip(req_ns.tolist(), tix):
-                assert t_send <= r <= t_poll <= t0 <= done <= t_handed
+        for req_ns, t_poll, tix in seen:
+            for r, t in zip(req_ns.tolist(), tix):
+                t_handed = handed[t]
+                assert t_send <= r <= t_poll <= t.t0_ns <= t.done_ns <= t_handed
                 want += t_handed - r
-                service += done - t0
+                service += t.done_ns - t.t0_ns
         assert sum(hists[k].total for k in SEAM) == want
         assert hists["take_service_ns"].total == service
         # One request id across the seam: the answer span carries the
         # batch id its tickets carry, and a feeder tick names the oldest
         # batch it drained as its cause.
-        batches = {b for _, _, tix, _ in seen for _, _, b in tix}
+        batches = {t.batch for _, _, tix in seen for t in tix}
         assert 0 not in batches
         ring = trace_mod.TRACE.dump()
         assert batches <= {e["id"] for e in ring if e["type"] == "http.answer"}
@@ -441,14 +451,14 @@ class TestSeamTiling:
 
         front, _ = seam_front
         polled = []
-        record = native_http.NativeHTTPFront._record_seam
+        record = native_http.NativeHTTPFront._record_intake
 
-        def spy(self, req_ns, t_poll, tickets, t_handed):
+        def spy(self, req_ns, t_poll, tickets):
             if self is front:
                 polled.append((int(req_ns.min()), int(req_ns.max()), t_poll))
-            record(self, req_ns, t_poll, tickets, t_handed)
+            record(self, req_ns, t_poll, tickets)
 
-        monkeypatch.setattr(native_http.NativeHTTPFront, "_record_seam", spy)
+        monkeypatch.setattr(native_http.NativeHTTPFront, "_record_intake", spy)
         t_send = time.perf_counter_ns()
         _take_burst(front.port, ["clock-a", "clock-b"])
         deadline = time.monotonic() + 5
@@ -463,9 +473,9 @@ class TestCompleterStall:
     def test_stalled_tick_snapshots_take_stall_and_still_answers(
         self, front, monkeypatch
     ):
-        """The completer waits a bounded second at a time: a feeder held
-        past it fires the take-stall anomaly snapshot on the served path,
-        and the take is answered once the feeder resumes."""
+        """The pump checks the oldest unanswered take on every poll: a
+        feeder held past a second fires the take-stall anomaly snapshot
+        from the pump, and the take is answered once the feeder resumes."""
         import threading
         import time
 
@@ -473,6 +483,14 @@ class TestCompleterStall:
         from patrol_tpu.utils import trace as trace_mod
 
         monkeypatch.setattr(engine_mod, "HOST_FASTPATH", False)
+        fired = []
+        anomaly = trace_mod.anomaly
+
+        def spy(reason):
+            fired.append((reason, threading.current_thread().name))
+            anomaly(reason)
+
+        monkeypatch.setattr(trace_mod, "anomaly", spy)
         tr = trace_mod.TRACE
         with tr._snap_mu:
             tr._last_anomaly.pop("take-stall", None)
@@ -500,3 +518,327 @@ class TestCompleterStall:
                 eng._cond.notify_all()
             client.join(10)
         assert out == [200]
+        assert ("take-stall", "patrol-http-pump") in fired
+
+
+def _send_take(port: int, name: str) -> socket.socket:
+    s = socket.create_connection(("127.0.0.1", port), timeout=10)
+    s.sendall(b"POST /take/%s?rate=5:1h HTTP/1.1\r\nHost: x\r\n\r\n" % name.encode())
+    return s
+
+
+def _read_answer(s: socket.socket):
+    """One HTTP/1.1 response → (status, body, bytes read past it)."""
+    data = b""
+    while True:
+        he = data.find(b"\r\n\r\n")
+        if he >= 0:
+            clen = 0
+            for line in data[:he].split(b"\r\n"):
+                if line.lower().startswith(b"content-length:"):
+                    clen = int(line.split(b":")[1])
+            if len(data) >= he + 4 + clen:
+                body = data[he + 4 : he + 4 + clen]
+                return int(data.split(b" ", 2)[1]), body, data[he + 4 + clen :]
+        chunk = s.recv(65536)
+        assert chunk, "connection closed before an answer"
+        data += chunk
+
+
+def _until(pred, what: str, timeout: float = 10.0) -> None:
+    import time
+
+    deadline = time.monotonic() + timeout
+    while not pred():
+        assert time.monotonic() < deadline, what
+        time.sleep(0.01)
+
+
+@pytest.fixture
+def own_front():
+    """An engine and a native front of their own, for tests that plant
+    faults in them."""
+    from patrol_tpu.net.native_http import NativeHTTPFront
+
+    engine = DeviceEngine(LimiterConfig(buckets=64, nodes=4), node_slot=0)
+    f = NativeHTTPFront(API(TPURepo(engine), stats=lambda: {}), "127.0.0.1", 0)
+    yield engine, f
+    f.close()
+    engine.stop()
+
+
+class TestAnswerHandBack:
+    """Take answers go back to the C++ front from the thread that
+    completed them, one native call per completion batch."""
+
+    # Each completion site and the answer it gives a fresh 5:1h bucket.
+    SITES = {
+        "device_tick": (200, b"4"),
+        "host_in_submit": (200, b"4"),
+        "host_reroute": (200, b"4"),
+        "overload_shed": (429, b"0"),
+        "tick_failure": (429, b"0"),
+    }
+
+    @pytest.mark.parametrize("site", sorted(SITES))
+    def test_each_completion_site_answers_once(self, site, own_front, monkeypatch):
+        from patrol_tpu.runtime import engine as engine_mod
+        from patrol_tpu.utils.profiling import COUNTERS
+
+        engine, front = own_front
+        monkeypatch.setattr(engine_mod, "HOST_FASTPATH", site == "host_in_submit")
+        if site == "overload_shed":
+            engine._max_buckets = 1
+            monkeypatch.setattr(engine, "_shed_new_names", lambda now, n=1: True)
+        elif site == "tick_failure":
+
+            def fail(deltas, tickets):
+                raise RuntimeError("seeded tick failure")
+
+            monkeypatch.setattr(engine, "_apply", fail)
+        elif site == "host_reroute":
+            with engine._cond:
+                engine._tick_paused = True
+        c0 = COUNTERS.snapshot()
+        name = f"site-{site}"
+        with _send_take(front.port, name) as s:
+            if site == "host_reroute":
+                # Queued for the device, then its row turns host-resident
+                # before the tick drains it: the feeder serves it from
+                # the host model.
+                _until(lambda: engine._takes, "take never queued")
+                row = engine.directory.lookup(name)
+                with engine._host_mu:
+                    engine._hosted[row] = engine_mod.HostLanes(engine.config.nodes)
+                    engine._hosted_flag[row] = True
+                monkeypatch.setattr(engine_mod, "HOST_FASTPATH", True)
+                with engine._cond:
+                    engine._tick_paused = False
+                    engine._cond.notify_all()
+            status, body, rest = _read_answer(s)
+            assert (status, body) == self.SITES[site]
+            assert rest == b""
+            s.settimeout(0.3)
+            with pytest.raises(socket.timeout):
+                s.recv(1)  # nothing more: answered exactly once
+        c1 = COUNTERS.snapshot()
+        assert c1["http_answers_direct"] - c0["http_answers_direct"] == 1
+        assert c1["http_answers_backstop"] == c0["http_answers_backstop"]
+        assert front._sink.oldest() is None
+
+    def test_no_completer_thread_and_no_backstop(self, front):
+        import threading
+
+        from patrol_tpu.utils.profiling import COUNTERS
+
+        assert "patrol-http-complete" not in {
+            t.name for t in threading.enumerate()
+        }
+        c0 = COUNTERS.snapshot()
+        names = [f"burst{i % 5}" for i in range(60)]
+        assert _take_burst(front.port, names) == [200] * len(names)
+        c1 = COUNTERS.snapshot()
+        assert c1["http_answers_direct"] - c0["http_answers_direct"] == len(names)
+        assert c1["http_answers_backstop"] == c0["http_answers_backstop"]
+
+    def test_concurrent_completers_answer_each_take_once(self, monkeypatch):
+        """Threads completing and flushing one sink's tickets at once, a
+        backstop flushing beside them and batches whose submit is still
+        running: every take is handed back exactly once, never before its
+        batch's submit returned, and every batch closes one wait sample."""
+        import sys
+        import threading
+        import time
+
+        from patrol_tpu.net.native_http import AnswerSink
+        from patrol_tpu.ops.rate import Rate
+        from patrol_tpu.runtime.engine import TakeTicket, hand_back
+        from patrol_tpu.utils import histogram as hist
+
+        sent = []
+        submitted = set()
+        early = []
+
+        class Lib:
+            def pt_http_answer_takes(self, h, tags, streams, st, rem, n):
+                for tag in tags[:n].tolist():
+                    if tag // 1000 not in submitted:
+                        early.append(tag)
+                    sent.append(tag)
+                return 0
+
+        wait = hist.LatticeHistogram("http_front_wait_ns")
+        monkeypatch.setattr(hist, "FRONT_WAIT", wait)
+        sink = AnswerSink(Lib(), 0, 4)
+        batches, per = 40, 25
+        tickets = []
+        for b in range(1, batches + 1):
+            sink.open(b, per, time.perf_counter_ns())
+            for i in range(per):
+                t = TakeTicket(f"k{i}", 0, Rate(freq=1, per_ns=1), 1, 0, b)
+                t.sink, t.tag = sink, b * 1000 + i
+                tickets.append(t)
+        for b in range(1, batches + 1, 2):
+            submitted.add(b)
+            sink.submitted(b, time.perf_counter_ns())
+        done = threading.Event()
+
+        def complete(share):
+            for j in range(0, len(share), 3):
+                group = share[j : j + 3]
+                for t in group:
+                    t.complete(1, True)
+                hand_back(group)
+
+        def backstop():
+            while not done.is_set():
+                sink.flush(direct=False)
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            workers = [
+                threading.Thread(
+                    target=complete, args=(tickets[k::8],), daemon=True
+                )
+                for k in range(8)
+            ]
+            pump = threading.Thread(target=backstop, daemon=True)
+            for th in workers + [pump]:
+                th.start()
+            for b in range(2, batches + 1, 2):
+                submitted.add(b)
+                sink.submitted(b, time.perf_counter_ns())
+            for th in workers:
+                th.join(30)
+                assert not th.is_alive()
+            sink.flush(direct=False)
+        finally:
+            done.set()
+            sys.setswitchinterval(old)
+        pump.join(30)
+        assert not pump.is_alive()
+        assert sorted(sent) == sorted(t.tag for t in tickets)
+        assert early == []
+        assert sink.oldest() is None
+        assert wait.count == batches
+
+    def test_completion_after_close_sends_nothing(self, own_front, monkeypatch):
+        from patrol_tpu.net import native_http
+        from patrol_tpu.runtime import engine as engine_mod
+        from patrol_tpu.utils.profiling import COUNTERS
+
+        engine, front = own_front
+        monkeypatch.setattr(engine_mod, "HOST_FASTPATH", False)
+        flushed = []
+        flush = native_http.AnswerSink.flush
+
+        def spy(self, direct=True):
+            if self is not front._sink:
+                return flush(self, direct)  # another front's pump
+            try:
+                flush(self, direct)
+            except BaseException as exc:  # pragma: no cover - the failure
+                flushed.append(exc)
+                raise
+            flushed.append(None)
+
+        monkeypatch.setattr(native_http.AnswerSink, "flush", spy)
+        with engine._cond:
+            engine._tick_paused = True
+        with _send_take(front.port, "after-close") as s:
+            _until(lambda: engine._takes, "take never queued")
+            entry = engine._takes[0]
+            ticket = getattr(entry, "tickets", [entry])[0]
+            front.close()
+            c0 = COUNTERS.snapshot()
+            del flushed[:]
+            with engine._cond:
+                engine._tick_paused = False
+                engine._cond.notify_all()
+            assert ticket.wait(10) and ticket.ok
+            _until(lambda: flushed, "the completing thread never flushed")
+            assert flushed == [None]
+            assert s.recv(65536) == b""  # the stopped server sent nothing
+        c1 = COUNTERS.snapshot()
+        assert c1["http_answers_direct"] == c0["http_answers_direct"]
+        assert c1["http_answers_backstop"] == c0["http_answers_backstop"]
+
+    def test_failed_hand_back_keeps_the_tick_and_the_answer(
+        self, own_front, monkeypatch
+    ):
+        """A hand-back whose native call raises never reaches the engine:
+        the tick still unpins its row and broadcasts, the engine's threads
+        live on, and the answer waits for the pump's flush."""
+        import threading
+
+        from patrol_tpu.runtime import engine as engine_mod
+        from patrol_tpu.utils.profiling import COUNTERS
+
+        engine, front = own_front
+        monkeypatch.setattr(engine_mod, "HOST_FASTPATH", False)
+        sink = front._sink
+        real = sink._lib
+        calls = []
+
+        class Lib:
+            def pt_http_answer_takes(self, *args):
+                calls.append(threading.current_thread().name)
+                if len(calls) == 1:
+                    raise OSError("seeded hand-back failure")
+                return real.pt_http_answer_takes(*args)
+
+        monkeypatch.setattr(sink, "_lib", Lib())
+        sent = []
+        hook = engine.on_broadcast
+
+        def on_broadcast(states):
+            sent.extend(st.name for st in states)
+            if hook is not None:
+                hook(states)
+
+        monkeypatch.setattr(engine, "on_broadcast", on_broadcast)
+        c0 = COUNTERS.snapshot()
+        with _send_take(front.port, "hb-fail") as s:
+            status, body, _ = _read_answer(s)
+        assert (status, body) == (200, b"4")
+        assert calls[0] != "patrol-http-pump"  # the completing thread
+        assert calls[1:] == ["patrol-http-pump"]  # the pump's flush sent it
+        c1 = COUNTERS.snapshot()
+        assert c1["http_answers_direct"] == c0["http_answers_direct"]
+        assert c1["http_answers_backstop"] - c0["http_answers_backstop"] == 1
+        row = engine.directory.lookup("hb-fail")
+        _until(lambda: "hb-fail" in sent, "the tick never broadcast")
+        assert engine.directory.pins[row] == 0
+        assert sink.oldest() is None
+        # The engine lives on: the next take is handed back directly.
+        with _send_take(front.port, "hb-next") as s:
+            status, body, _ = _read_answer(s)
+        assert (status, body) == (200, b"4")
+        c2 = COUNTERS.snapshot()
+        assert c2["http_answers_direct"] - c1["http_answers_direct"] == 1
+
+    def test_failed_submit_answers_500_once(self, own_front, monkeypatch):
+        engine, front = own_front
+
+        def fail(*args, **kwargs):
+            raise RuntimeError("seeded submit failure")
+
+        monkeypatch.setattr(front.api.repo, "submit_takes_batch", fail)
+        with _send_take(front.port, "submit-fails") as s:
+            status, _, rest = _read_answer(s)
+            assert status == 500
+            assert rest == b""
+            s.settimeout(0.3)
+            with pytest.raises(socket.timeout):
+                s.recv(1)  # answered exactly once
+        assert front._sink.oldest() is None
+
+    def test_front_counts_the_answers_its_epoll_thread_drains(self, front):
+        s0 = front.stats()
+        names = [f"drained{i % 3}" for i in range(12)]
+        assert _take_burst(front.port, names) == [200] * len(names)
+        s1 = front.stats()
+        assert s1["http_answers_drained"] - s0["http_answers_drained"] == len(names)
+        assert s1["http_answer_drain_ns"] > s0["http_answer_drain_ns"]
+        assert s1["http_answer_lock_waits"] >= s0["http_answer_lock_waits"]

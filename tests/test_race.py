@@ -3,7 +3,7 @@ of the scripts/check.sh stage-7 gate.
 
 Every code is proven BOTH ways: the clean form of each fixture (and the
 real repo) passes, and a seeded violation of the same shape is flagged.
-The dynamic half's three seeded epoll-seam mutations must each be
+The dynamic half's four seeded epoll-seam mutations must each be
 rejected by the exact code they target; the static half's fixtures cover
 guarded-state, lock-graph, condvar-predicate, and buffer-ownership
 violations. The last tests run the whole stage over the real tree —
@@ -82,6 +82,13 @@ class TestSeamMutations:
             or "CLOSED conn" in f.message
             for f in findings
         )
+
+    def test_lost_kick_witness_names_the_stalled_answers(self):
+        sem, _ = race.SEAM_MUTATIONS["drain-before-eventfd-read"]
+        findings = race.check_seam(sem)
+        assert any("stalled answers" in f.message for f in findings)
+        # The clean drain order strands nothing over the same scenarios.
+        assert race.check_seam(race.SEAM_CLEAN) == []
 
     def test_unregistered_mutation_would_be_reported(self, monkeypatch):
         # A mutation the explorer cannot catch must surface as a finding
