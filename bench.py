@@ -14,9 +14,6 @@ end-to-end HTTP paths, measured separately by benchmarks/http_bench.py):
     applied in one call), counted as one bucket-merge per row per sweep;
   * scatter microbatch merge     — merge_batch of K uniform random deltas:
     the UDP replication-stream ingest class (config #3);
-  * pallas-vs-XLA scatter        — the block-sparse Pallas merge kernel
-    against the XLA scatter at K∈{8k, 131k}; the winner becomes the
-    engine's auto-mode default (ops/pallas_merge.py);
   * hot-key contention merge     — all K deltas target ONE bucket across
     256 node lanes (config #4: the reference serializes this on one mutex,
     bucket.go:240-263; here it is a single scatter-max);
@@ -495,8 +492,7 @@ def _run_stages(out) -> None:
     _log(f"scatter: {out['scatter_merges_per_s']:.3g} merges/s")
 
     # -- the PRODUCTION uniform-tick kernel: folded flagged scatter ---------
-    # On accelerator backends the engine tick always folds
-    # (PATROL_TICK_FOLD default 1): host fold → sorted UNIQUE
+    # The engine tick always folds: host fold → sorted UNIQUE
     # sentinel-padded pairs → merge_batch_folded with both scatter flags
     # + mode="drop". The plain stage above measures the unflagged scatter
     # class for r3/r4 continuity; THIS is what config #3 deltas actually
@@ -533,11 +529,6 @@ def _run_stages(out) -> None:
     _roofline(out, "scatter_folded", K * 128, dt_folded)
     _stage_done("scatter-folded")
     _log(f"folded scatter: {out['scatter_folded_merges_per_s']:.3g} merges/s")
-
-    # -- pallas-vs-XLA scatter (VERDICT r1 item 5; TPU only) ----------------
-    if _budget_out("pallas compare"):
-        return
-    state = _stage_pallas_compare(out, state, scatter, B, N)
 
     # -- hot-key contention: one bucket, all node lanes (config #4) ---------
     # Measures the ENGINE's hot-key path (r4 fold-to-dense hybrid), not a
@@ -881,8 +872,8 @@ def _stage_mesh_step(out, B, N) -> None:
 
 
 def _mk_merge_batch(K: int, B: int, N: int, as_numpy: bool = False):
-    """The shared deterministic delta pattern for the scatter and pallas
-    stages (same multipliers ⇒ their numbers stay comparable)."""
+    """The shared deterministic delta pattern for the scatter stages
+    (same multipliers ⇒ their numbers stay comparable)."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -904,47 +895,6 @@ def _mk_merge_batch(K: int, B: int, N: int, as_numpy: bool = False):
         taken_nt=jnp.asarray(taken),
         elapsed_ns=jnp.asarray(elapsed),
     )
-
-
-def _stage_pallas_compare(out, state, scatter, B, N):
-    """Pallas block-sparse scatter-merge vs XLA scatter at two batch sizes,
-    both through their deployment paths (donated buffers, engine-style).
-    Records per-K timings plus which kernel auto mode would pick; returns
-    the threaded state (both sides donate). No-op off-TPU."""
-    from patrol_tpu.ops import pallas_merge
-
-    refused = pallas_merge.compile_error(state)
-    if refused is not None:
-        out["pallas"] = "refused: " + refused.splitlines()[0][:300]
-        return state
-    result = {}
-    for K in (8_192, 131_072):
-        if _left() < 60:
-            out["truncated"] = True
-            break
-        rows, slots, added, taken, elapsed = _mk_merge_batch(K, B, N, as_numpy=True)
-        batch = _mk_merge_batch(K, B, N)
-        _log(f"pallas-vs-xla @K={K} (compiles)…")
-        dt_xla, state = _bench(scatter, state, batch, iters=2, iters_hi=12, indexed=True)
-
-        def pal(s, *_ignored):
-            return pallas_merge.merge_batch_pallas(s, rows, slots, added, taken, elapsed)
-
-        try:
-            dt_pal, state = _bench(pal, state, iters=2, iters_hi=12)
-        except Exception as e:
-            result[f"k{K}"] = {"xla_us": round(dt_xla * 1e6, 1), "pallas_error": str(e)[:200]}
-            continue
-        result[f"k{K}"] = {
-            "xla_us": round(dt_xla * 1e6, 1),
-            "pallas_us": round(dt_pal * 1e6, 1),
-            "winner": "pallas" if dt_pal < dt_xla else "xla",
-            "auto_picks_pallas": pallas_merge.auto_pick(rows, B),
-        }
-        _log(f"  K={K}: xla {dt_xla*1e6:.0f}µs vs pallas {dt_pal*1e6:.0f}µs")
-    out["pallas"] = result
-    _stage_done("pallas-compare")
-    return state
 
 
 def _encode_windows(n_windows: int, chunk: int, slot_mod: int):

@@ -494,7 +494,7 @@ def boot(args, label, buckets, lanes, extra=()) -> tuple:
         raise SmokeFailure(f"platform is {dev['platform']}, not tpu")
     phase(
         f"boot[{label}]", warmup_s=dev["warmup_s"],
-        compile_cache=dev["compile_cache_dir"], merge_kernel=repr(dev["merge_kernel"]),
+        compile_cache=dev["compile_cache_dir"],
         ingest_kernel=repr(dev["ingest_kernel"]), buckets=buckets, lanes=lanes,
     )
     return server, dev

@@ -18,7 +18,7 @@ Static half (AST, over the engine dispatch files and the serve graph):
   registry, machine-readable).
 * **PTD002 donation discipline** — (a) drift between a kernel's jit
   binding (``*_jit = partial(jax.jit, ...)``, the engine ``_jit_*``
-  factories, the pallas decorator) and its declared
+  factories) and its declared
   ``donate_argnums``/``static_argnames``; (b) use-after-donate at the
   dispatch sites: a donated buffer must be rebound by the dispatch's own
   assignment and must not ride along as a non-donated argument.
@@ -78,8 +78,7 @@ _ALL = ("PTD001", "PTD002", "PTD003", "PTD004", "PTD005")
 # factory in the engine files missing from here is a PTD002 finding.
 FACTORY_KERNELS: Dict[str, str] = {
     "_jit_take_packed": "take_n_batch",
-    "_jit_merge_packed": "merge_batch",
-    "_jit_merge_packed_folded": "merge_batch_folded",
+    "_jit_merge_folded": "merge_batch_folded",
     "_jit_commit_packed": "commit_blocks",
     "_jit_merge_rows_dense": "merge_rows_dense",
     "_jit_merge_scalar_packed": "merge_scalar_batch",
@@ -857,7 +856,6 @@ def check_transfers(mods: Sequence[Module]) -> List[Finding]:
 WITNESS_PATHS: Tuple[str, ...] = (
     "take",
     "take_n",
-    "merge_packed",
     "merge_folded",
     "commit_blocks",
     "merge_rows_dense",
@@ -1014,7 +1012,9 @@ def _witness_engine():
     from patrol_tpu.models.limiter import NANO, LimiterConfig
     from patrol_tpu.runtime.engine import DeviceEngine
 
-    cfg = LimiterConfig(buckets=256, nodes=2)
+    # Four lanes, so one bucket can fill a row-dense window, and enough
+    # rows that a drain can fold past one commit block.
+    cfg = LimiterConfig(buckets=4096, nodes=4)
     return DeviceEngine(cfg, node_slot=0, clock=lambda: NANO), cfg
 
 
@@ -1023,13 +1023,15 @@ def _witness_drives(eng, cfg):
     shapes; each runs once as the warm leg and once under the guard."""
     import numpy as np
 
+    import jax
     import jax.numpy as jnp
 
-    from patrol_tpu.models.limiter import NANO
+    from patrol_tpu.models.limiter import NANO, init_state
     from patrol_tpu.ops import lifecycle as lifecycle_ops
     from patrol_tpu.ops import wire
     from patrol_tpu.ops.rate import Rate
     from patrol_tpu.runtime import engine as engine_mod
+    from patrol_tpu.utils import profiling
 
     rate = Rate(freq=100, per_ns=3600 * NANO)
     names = [f"wit{i}" for i in range(8)]
@@ -1038,7 +1040,7 @@ def _witness_drives(eng, cfg):
         for i, nm in enumerate(names):
             eng.take(nm, rate, 1, now_ns=NANO + i)
 
-    def merge_packed():
+    def merge_folded():
         eng.ingest_deltas_batch(
             names,
             [1] * 8,
@@ -1137,55 +1139,37 @@ def _witness_drives(eng, cfg):
     def read_rows():
         eng.read_rows(np.zeros(4, np.int32))
 
-    # The accel-only pipeline kernels (folded fold, dense row windows,
-    # the coalesced commit ring) never run on a CPU engine tick — drive
-    # their factories directly at the warmup ladder's base shapes so
-    # the witness still pins their cache stability on every host.
-    def _scratch():
-        from patrol_tpu.models.limiter import init_state
-
-        return jax.device_put(init_state(cfg))
-
-    import jax
-
-    pad_row = engine_mod._FOLD_PAD_ROW
-
-    def merge_folded():
-        packed = np.zeros((6, 8), np.int64)
-        packed[0] = pad_row
-        packed[1] = np.arange(8)
-        packed[4] = pad_row + np.arange(8)
-        st = _scratch()
-        st = engine_mod._jit_merge_packed_folded()(st, jnp.asarray(packed))
-        jax.block_until_ready(st.pn)
-
     def merge_rows_dense():
-        st = _scratch()
-        st = engine_mod._jit_merge_rows_dense()(
-            st,
-            jnp.full((8,), pad_row, jnp.int64)
-            + jnp.arange(8, dtype=jnp.int64),
-            jnp.zeros((8, cfg.nodes, 2), jnp.int64),
-            jnp.zeros((8,), jnp.int64),
+        # One bucket on every lane: the tick folds it into a row window.
+        eng.ingest_deltas_batch(
+            ["dense"] * cfg.nodes,
+            list(range(cfg.nodes)),
+            [-1] * cfg.nodes,
+            [-1] * cfg.nodes,
+            [NANO] * cfg.nodes,
+            caps_nt=[-1] * cfg.nodes,
+            lane_added_nt=[100 + i for i in range(cfg.nodes)],
+            lane_taken_nt=[10 + i for i in range(cfg.nodes)],
         )
-        jax.block_until_ready(st.pn)
+        assert eng.flush(timeout=30)
 
     def commit_blocks():
-        from patrol_tpu.ops import commit as commit_mod
-
-        warm = commit_mod.pack_commit_blocks(
-            np.empty(0, np.int64),
-            np.empty(0, np.int64),
-            np.empty(0, np.int64),
-            np.empty(0, np.int64),
-            np.empty(0, np.int64),
-            np.empty(0, np.int64),
-            engine_mod.MAX_MERGE_ROWS,
-            out=np.empty((6, 2, engine_mod.MAX_MERGE_ROWS), np.int64),
+        # A drain whose fold spans two blocks, through the engine's own
+        # tick: it commits as one coalesced ring dispatch.
+        n = 2 * engine_mod.MAX_MERGE_ROWS
+        before = profiling.COUNTERS.get("commit_dispatches")
+        eng._apply(
+            engine_mod.DeltaArrays(
+                rows=np.arange(n, dtype=np.int64) % cfg.buckets,
+                slots=np.arange(n, dtype=np.int64) // cfg.buckets,
+                added_nt=np.full(n, 7, np.int64),
+                taken_nt=np.full(n, 3, np.int64),
+                elapsed_ns=np.full(n, NANO, np.int64),
+                scalar=np.zeros(n, bool),
+            ),
+            (),
         )
-        st = _scratch()
-        st = engine_mod._jit_commit_packed()(st, jnp.asarray(warm))
-        jax.block_until_ready(st.pn)
+        assert profiling.COUNTERS.get("commit_dispatches") == before + 1
 
     def take_n():
         # The coalesced serving dispatch at a hot-key shape: one packed
@@ -1199,7 +1183,7 @@ def _witness_drives(eng, cfg):
         packed[4] = NANO  # count_nt
         packed[5] = 3  # nreq: the coalesced crowd size
         packed[6] = 100 * NANO  # cap_base_nt
-        st = _scratch()
+        st = jax.device_put(init_state(cfg))
         st, out = engine_mod._jit_take_packed(0)(st, jnp.asarray(packed))
         jax.block_until_ready(st.pn)
         jax.block_until_ready(out)
@@ -1207,7 +1191,6 @@ def _witness_drives(eng, cfg):
     return {
         "take": take,
         "take_n": take_n,
-        "merge_packed": merge_packed,
         "merge_folded": merge_folded,
         "commit_blocks": commit_blocks,
         "merge_rows_dense": merge_rows_dense,
@@ -1283,8 +1266,7 @@ def _jit_cache_entries() -> int:
         conc_mod.conc_acquire_batch_jit,
         quota_mod.quota_take_batch_jit,
         engine_mod._jit_take_packed(0),
-        engine_mod._jit_merge_packed(),
-        engine_mod._jit_merge_packed_folded(),
+        engine_mod._jit_merge_folded(),
         engine_mod._jit_commit_packed(),
         engine_mod._jit_merge_rows_dense(),
         engine_mod._jit_merge_scalar_packed(),
@@ -1369,7 +1351,7 @@ def run_witness(mutate: Optional[str] = None) -> WitnessReport:
                     from patrol_tpu.runtime import engine as engine_mod
 
                     with eng._state_mu:
-                        eng.state = engine_mod._jit_merge_packed()(
+                        eng.state = engine_mod._jit_merge_scalar_packed()(
                             eng.state, jnp.zeros((5, 9), jnp.int64)
                         )
                         jax.block_until_ready(eng.state.pn)

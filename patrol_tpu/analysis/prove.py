@@ -1455,89 +1455,6 @@ def _model_wire_roundtrip(
     return findings
 
 
-def _model_pallas_interpret(
-    root: ProveRoot, fn: Callable, site: Tuple[str, int]
-) -> List[Finding]:
-    """The pallas scatter-merge, exercised through its interpret path:
-    PTP002 batch-order invariance + bit-agreement with the XLA scatter
-    join (whose algebra the other roots prove), PTP003 duplication."""
-    from patrol_tpu.models.limiter import LimiterConfig, init_state
-    from patrol_tpu.ops import pallas_merge
-    from patrol_tpu.ops.merge import MergeBatch, merge_batch
-
-    import jax.numpy as jnp
-
-    findings: List[Finding] = []
-    cfg = LimiterConfig(buckets=pallas_merge.ROWS_PER_BLOCK, nodes=2)
-
-    rows = np.array([0, 0, 1, 1, 5, 5, 511, 0], np.int64)
-    slots = np.array([0, 0, 1, 1, 0, 1, 1, 1], np.int64)
-    big = (5 << 32) + 1
-    added = np.array([9, 3, big, 1, 0, 7, 2, big - 1], np.int64)
-    taken = np.array([1, 8, 2, big, 5, 0, 3, 4], np.int64)
-    elapsed = np.array([4, 6, 2**40 + 3, 2**40 + 2, 0, 1, 9, 5], np.int64)
-
-    def run(r, s, a, t, e):
-        # Fresh zero state per call: the device path donates its input.
-        got = fn(init_state(cfg), r, s, a, t, e, interpret=True)
-        return np.asarray(got.pn), np.asarray(got.elapsed)
-
-    base = run(rows, slots, added, taken, elapsed)
-
-    ref = merge_batch(
-        init_state(cfg),
-        MergeBatch(
-            rows=jnp.asarray(rows, jnp.int32),
-            slots=jnp.asarray(slots, jnp.int32),
-            added_nt=jnp.asarray(added, jnp.int64),
-            taken_nt=jnp.asarray(taken, jnp.int64),
-            elapsed_ns=jnp.asarray(elapsed, jnp.int64),
-        ),
-    )
-    if "PTP002" in root.obligations:
-        if not (
-            np.array_equal(base[0], np.asarray(ref.pn))
-            and np.array_equal(base[1], np.asarray(ref.elapsed))
-        ):
-            findings.append(
-                Finding(
-                    "PTP002",
-                    *site,
-                    f"[{root.name}] pallas merge disagrees with the XLA "
-                    "scatter join on the same batch (bit-exactness contract)",
-                )
-            )
-        rev = run(rows[::-1], slots[::-1], added[::-1], taken[::-1], elapsed[::-1])
-        if not (np.array_equal(rev[0], base[0]) and np.array_equal(rev[1], base[1])):
-            findings.append(
-                Finding(
-                    "PTP002",
-                    *site,
-                    f"[{root.name}] pallas merge is batch-order dependent: "
-                    "reversed delta order produced a different state",
-                )
-            )
-
-    if "PTP003" in root.obligations:
-        dup = run(
-            np.concatenate([rows, rows]),
-            np.concatenate([slots, slots]),
-            np.concatenate([added, added]),
-            np.concatenate([taken, taken]),
-            np.concatenate([elapsed, elapsed]),
-        )
-        if not (np.array_equal(dup[0], base[0]) and np.array_equal(dup[1], base[1])):
-            findings.append(
-                Finding(
-                    "PTP003",
-                    *site,
-                    f"[{root.name}] pallas merge is not idempotent under "
-                    "batch duplication",
-                )
-            )
-    return findings
-
-
 def _model_delta_roundtrip(
     root: ProveRoot, fn: Callable, site: Tuple[str, int]
 ) -> List[Finding]:
@@ -2196,7 +2113,6 @@ _MODELS: Dict[str, Callable] = {
     "rate_algebra": _model_rate_algebra,
     "wire_roundtrip": _model_wire_roundtrip,
     "delta_roundtrip": _model_delta_roundtrip,
-    "pallas_interpret": _model_pallas_interpret,
     "raw_ingest": _model_raw_ingest,
     "gcra_laws": _model_gcra_laws,
     "conc_laws": _model_conc_laws,

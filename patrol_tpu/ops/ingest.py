@@ -34,18 +34,16 @@ checksum, version, ack-vector bounds, per-entry framing bounds, bit-63
 value guards, exact end-of-payload. The differential sweep in
 tests/test_ingest.py pins verdicts AND folded state against the Python
 decoder over the hostile corpus (truncations, flips, trailing garbage,
-mixed valid/invalid planes), for the XLA path and the Pallas twin.
+mixed valid/invalid planes).
 
-Kernel forms, same pattern as ops/pallas_merge.py:
+Kernel forms:
 
-* :func:`decode_fold_raw` — the XLA implementation (gathers + one
-  ``lax.scan`` over entry ordinals + one scatter-max). The production
-  path on every backend today.
-* :func:`decode_fold_raw_pallas` — the Pallas twin sharing the same
-  decode core inside a ``pallas_call``, run in interpret mode only: the
-  engine never dispatches it, since Mosaic cannot lower byte-granular
-  gathers and scalar VMEM stores (the verdict BENCH_r02 pinned for the
-  scatter-merge kernel).
+* :func:`decode_fold_raw` — the XLA implementation (wide vectorized
+  framing checks over [P, E] lanes + one scatter-max), the production
+  path on every backend.
+* :func:`host_walk` (numpy) and :func:`walk` (the native walk when the
+  library loads) — the host reference the kernel's verdicts are pinned
+  against; the host join is the reference for its folded state.
 
 Algebra: the fold leg is the identical lattice join as
 ``ops/delta.delta_fold`` (elementwise int64 max, ``mode="drop"``
@@ -414,8 +412,8 @@ def gather_name_rows(
 
 
 # ---------------------------------------------------------------------------
-# Device decode core — shared by the XLA path and the Pallas twin. Pure
-# jnp on values; the framing walk is a lax.scan over entry ordinals.
+# Device decode core. Pure jnp on values; the framing walk is wide
+# vectorized ops over [P, E] entry lanes.
 
 
 def _device_decode(planes: jax.Array, lengths: jax.Array, entry_off: jax.Array):
@@ -449,8 +447,6 @@ def _device_decode(planes: jax.Array, lengths: jax.Array, entry_off: jax.Array):
     ok = (lengths >= _MIN_LEN) & (lengths <= row)
     ok &= (pl32[:, :24] == 0).all(axis=1)
     ok &= pl32[:, 24] == len(_NAME)
-    # Scalar per-byte compares (not an array constant): pallas kernels
-    # may not capture closed-over arrays, and this core is shared.
     for k, b in enumerate(_NAME.tolist()):
         ok &= pl32[:, 25 + k] == b
     col = jnp.arange(row)
@@ -571,68 +567,3 @@ def decode_fold_raw(
 
 
 decode_fold_raw_jit = partial(jax.jit, donate_argnums=0)(decode_fold_raw)
-
-
-# ---------------------------------------------------------------------------
-# Pallas twin — same decode core inside a pallas_call, run in interpret
-# mode only (the engine dispatches the XLA form): Mosaic rejects
-# byte-granular gathers the same way it rejects the scatter-merge kernel
-# (pallas_merge.py notes). Kept as the differential check on the shared
-# decode core.
-
-from jax.experimental import pallas as pl  # noqa: E402,F401
-
-
-def decode_fold_raw_pallas(
-    state: LimiterState,
-    planes: jax.Array,
-    lengths: jax.Array,
-    entry_off: jax.Array,
-    rows: jax.Array,
-    hosted: jax.Array,
-    interpret: bool = True,
-):
-    """Pallas form of :func:`decode_fold_raw`: one program, every operand
-    resident, outputs aliased onto the state planes — the shape a future
-    Mosaic byte-gather lowering would fill in. Shares
-    :func:`_decode_fold_core` verbatim so the differential sweep pinning
-    it against the XLA path is a check on the pallas_call plumbing, not
-    a second decoder implementation to drift."""
-    P, E = rows.shape
-
-    def kernel(
-        planes_ref, lengths_ref, eoff_ref, rows_ref, hosted_ref, pn_in,
-        el_in, pn_out, el_out, ok_out, eok_out, hm_out, slot_out,
-        cap_out, a_out, t_out, e_out,
-    ):
-        st = LimiterState(pn=pn_in[...], elapsed=el_in[...])
-        out = _decode_fold_core(
-            st, planes_ref[...], lengths_ref[...], eoff_ref[...],
-            rows_ref[...], hosted_ref[...],
-        )
-        pn_out[...] = out[0].pn
-        el_out[...] = out[0].elapsed
-        ok_out[...] = out[1]
-        eok_out[...] = out[2]
-        hm_out[...] = out[3]
-        slot_out[...] = out[4]
-        cap_out[...] = out[5]
-        a_out[...] = out[6]
-        t_out[...] = out[7]
-        e_out[...] = out[8]
-
-    pe_i64 = jax.ShapeDtypeStruct((P, E), jnp.int64)
-    pe_b = jax.ShapeDtypeStruct((P, E), jnp.bool_)
-    outs = pl.pallas_call(
-        kernel,
-        out_shape=[
-            jax.ShapeDtypeStruct(state.pn.shape, state.pn.dtype),
-            jax.ShapeDtypeStruct(state.elapsed.shape, state.elapsed.dtype),
-            jax.ShapeDtypeStruct((P,), jnp.bool_),
-            pe_b, pe_b, pe_i64, pe_i64, pe_i64, pe_i64, pe_i64,
-        ],
-        # Flat inputs: planes, lengths, entry_off, rows, hosted, pn, el.
-        input_output_aliases={5: 0, 6: 1},
-        interpret=interpret,
-    )(planes, lengths, entry_off, rows, hosted, state.pn, state.elapsed)
-    return (LimiterState(pn=outs[0], elapsed=outs[1]), *outs[2:])

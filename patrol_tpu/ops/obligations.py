@@ -646,11 +646,6 @@ KERNEL_FAMILIES: Tuple[KernelFamily, ...] = (
                 ("PTP001", "PTP005"), structural="join",
                 tracer=_trace_read_rows,
             ),
-            ProveRoot(
-                "ops.pallas_merge.merge_batch_pallas",
-                "patrol_tpu.ops.pallas_merge", "merge_batch_pallas",
-                ("PTP002", "PTP003"), model="pallas_interpret",
-            ),
         ),
         absent={
             "ops.merge.read_rows:PTP002": (
@@ -663,19 +658,6 @@ KERNEL_FAMILIES: Tuple[KernelFamily, ...] = (
             ),
             "ops.merge.read_rows:PTP004": (
                 "reads don't move the lattice; monotonicity is vacuous"
-            ),
-            "ops.pallas_merge.merge_batch_pallas:PTP001": (
-                "pallas kernels lower to mosaic, not a lintable jaxpr; "
-                "the interpret-mode model checks it bit-exact against "
-                "merge_batch, which IS PTP001-linted"
-            ),
-            "ops.pallas_merge.merge_batch_pallas:PTP004": (
-                "monotonicity is inherited from the bit-exact twin "
-                "merge_batch via the pallas_interpret differential"
-            ),
-            "ops.pallas_merge.merge_batch_pallas:PTP005": (
-                "no traceable jaxpr in interpret-free mode; shape/dtype "
-                "stability rides the twin differential"
             ),
         },
         lin_exempt=(
@@ -1347,13 +1329,14 @@ DISPATCH_SPECS: Tuple[DispatchSpec, ...] = (
     ),
     DispatchSpec(
         "merge_batch", "patrol_tpu.ops.merge", "merge_batch",
-        witness="merge_packed",
-        note="packed [5,K] scatter-max join; promotion + CPU commit path",
+        witness="mesh_step",
+        note="scatter-max join inside the fused mesh step "
+        "(topology.cluster_step); the single-device tick folds first",
     ),
     DispatchSpec(
         "merge_batch_folded", "patrol_tpu.ops.merge", "merge_batch_folded",
         witness="merge_folded",
-        note="unique/sorted-asserted fold; accelerator tick commit",
+        note="unique/sorted-asserted fold; the merge tick's commit",
     ),
     DispatchSpec(
         "commit_blocks", "patrol_tpu.ops.commit", "commit_blocks",
@@ -1413,15 +1396,6 @@ DISPATCH_SPECS: Tuple[DispatchSpec, ...] = (
         witness="read_rows",
         note="eager (un-jitted) padded gather behind every "
         "snapshot/introspection read; donation-free by construction",
-    ),
-    DispatchSpec(
-        "merge_batch_pallas", "patrol_tpu.ops.pallas_merge",
-        "merge_batch_pallas",
-        static_argnames=("interpret",), buckets="fixed",
-        witness_absent="accelerator-only Pallas scatter-max, lazily "
-        "imported behind PATROL_PALLAS and unreachable on the CPU "
-        "witness host; interpret-mode tracing is minutes-class. Covered "
-        "by tests/test_pallas_merge.py interpret-mode equivalence.",
     ),
 )
 

@@ -8,7 +8,8 @@ limiter state happens on one engine thread that drains two queues — take
 tickets and replication deltas — into padded, fixed-shape kernel calls:
 
     submit_take()/ingest_delta()  →  queues  →  engine tick (feeder):
-        merge_batch(deltas)   one scatter-max call (async dispatch)
+        fold, then merge_batch_folded / merge_rows_dense / commit_blocks
+                              one scatter-max call (async dispatch)
         take_batch(groups)    one fused take call (async dispatch)
     completion pipeline (completer thread):
         read results, complete tickets, emit broadcast states
@@ -50,7 +51,6 @@ from patrol_tpu.ops import merge as merge_mod
 from patrol_tpu.ops import wire
 from patrol_tpu.ops.merge import (
     MergeBatch,
-    merge_batch,
     merge_scalar_batch,
     read_rows,
     zero_rows_jit,
@@ -85,10 +85,10 @@ def _take_fold_enabled() -> bool:
     """Hot-key take coalescing (rx-side fold): same-(row, rate, count)
     takes fold into ONE queue entry at submit time, so a Zipf crowd on a
     few buckets drains as a handful of take-n rows instead of eating the
-    whole per-tick row budget ticket-by-ticket. Read at call time (like
-    PATROL_TICK_FOLD) so the bench's per-ticket replay leg can flip it
-    without forking the engine; "0" also makes _group_tickets serve one
-    ticket per row per tick — the true pre-coalescing reference path."""
+    whole per-tick row budget ticket-by-ticket. Read at call time so the
+    bench's per-ticket replay leg can flip it without forking the
+    engine; "0" also makes _group_tickets serve one ticket per row per
+    tick — the true pre-coalescing reference path."""
     return os.environ.get("PATROL_TAKE_FOLD", "1") != "0"
 # Merge rows per engine tick. Bigger ticks amortize per-dispatch cost
 # (decisive where each execute carries a fixed transport cost: r3-r5
@@ -146,9 +146,8 @@ DEVICE_TIMING = os.environ.get("PATROL_DEVICE_TIMING", "1") != "0"
 _KERNEL_EVENTS = {
     k: trace_mod.event_type(k)
     for k in (
-        "take_packed", "merge_packed", "merge_folded", "merge_pallas",
-        "merge_scalar", "commit_blocks", "delta_fold", "decode_fold_raw",
-        "mesh_step",
+        "take_packed", "merge_folded", "merge_scalar", "commit_blocks",
+        "delta_fold", "decode_fold_raw", "mesh_step",
     )
 }
 profiling.count_jax_compiles()
@@ -686,12 +685,6 @@ class DeltaArrays(NamedTuple):
 # underscore alias keeps this module's historical name importable.
 _FOLD_PAD_ROW = merge_mod.FOLD_PAD_ROW
 
-# Fold-to-dense hybrid: a tick row touching at least this many lanes
-# commits its full lane plane as ONE row-window scatter update instead of
-# one update per lane (0 = auto: max(4, nodes // 3) — the point where the
-# row window's extra transfer bytes beat the per-update scatter cost on a
-# transfer-walled link; on a PCIe-attached chip 4 is already a win).
-ROW_DENSE_MIN = int(os.environ.get("PATROL_ROW_DENSE_MIN", 0))
 MAX_ROW_DENSE = 512  # padded-shape ceiling of the row-dense batch
 
 
@@ -883,22 +876,7 @@ def _jit_take_packed(node_slot: int):
 
 
 @lru_cache(maxsize=8)
-def _jit_merge_packed():
-    def merge_packed(state, packed):
-        batch = MergeBatch(
-            rows=packed[0].astype(jnp.int32),
-            slots=packed[1].astype(jnp.int32),
-            added_nt=packed[2],
-            taken_nt=packed[3],
-            elapsed_ns=packed[4],
-        )
-        return merge_batch(state, batch)
-
-    return jax.jit(merge_packed, donate_argnums=0)
-
-
-@lru_cache(maxsize=8)
-def _jit_merge_packed_folded():
+def _jit_merge_folded():
     """Scatter-max with unique/sorted flags asserted — only valid for
     batches prepared by :meth:`DeviceEngine._fold_lane_merges`."""
 
@@ -989,12 +967,15 @@ class DeviceEngine:
         self.node_slot = node_slot
         self.clock = clock
         self.on_broadcast = on_broadcast
-        self._row_dense_min = ROW_DENSE_MIN or max(4, config.nodes // 3)
+        # Fold-to-dense hybrid: a tick row touching at least this many
+        # lanes commits its full lane plane as ONE row-window scatter
+        # update instead of one update per lane — the point where the row
+        # window's extra transfer bytes beat the per-update scatter cost
+        # on a transfer-walled link; on a PCIe-attached chip 4 is already
+        # a win.
+        self._row_dense_min = max(4, config.nodes // 3)
         self.directory = BucketDirectory(config.buckets)
         self.state: LimiterState = init_state(config, device=device)
-        # Merge kernel, decided once against the real state (see
-        # _select_merge_kernel); "auto" leaves the per-batch pick on.
-        self.merge_kernel = self._select_merge_kernel()
 
         # Profiled sync primitives: contended-acquire wait time and
         # condition park time feed the REAL /debug/pprof/mutex and /block
@@ -1577,8 +1558,8 @@ class DeviceEngine:
 
     def device_vars(self) -> Dict[str, object]:
         """What this engine runs on, for /debug/vars: the backend as JAX
-        reports it, the kernels the engine selected, and each device's
-        peak memory (None where the backend keeps no stats, as the CPU)."""
+        reports it, its ingest kernel, and each device's peak memory
+        (None where the backend keeps no stats, as the CPU)."""
         from patrol_tpu.utils import compile_cache
 
         devs = jax.devices()
@@ -1589,7 +1570,6 @@ class DeviceEngine:
             "count": len(devs),
             "jax": jax.__version__,
             "compile_cache_dir": compile_cache.cache_dir(),
-            "merge_kernel": self.merge_kernel,
             "ingest_kernel": (
                 "decode_fold_raw (xla)"
                 if self._raw_ingest_capable
@@ -1907,38 +1887,25 @@ class DeviceEngine:
             self._promote_pending.clear()
         if not popped:
             return
-        rows_l: List[int] = []
-        slots_l: List[int] = []
-        added_l: List[int] = []
-        taken_l: List[int] = []
-        elapsed_l: List[int] = []
+        # One lane delta per nonzero lane (an own-lane carrier for an
+        # elapsed-only row), joined through the engine's own merge tick in
+        # slices of the feeder's drain budget: the tick's warmed shapes.
+        cols: List[Tuple[int, int, int, int, int]] = []
         for row, lanes in popped:
             slots = np.flatnonzero(lanes.added | lanes.taken)
             if slots.size == 0 and not lanes.elapsed_ns:
                 continue
             if slots.size == 0:
                 slots = np.array([self.node_slot])
-            for slot in slots:
-                rows_l.append(row)
-                slots_l.append(int(slot))
-                added_l.append(int(lanes.added[slot]))
-                taken_l.append(int(lanes.taken[slot]))
-                elapsed_l.append(lanes.elapsed_ns)
-        for lo in range(0, len(rows_l), MAX_MERGE_ROWS):
-            hi = lo + MAX_MERGE_ROWS
-            n = len(rows_l[lo:hi])
-            k = _pad_size(n)
-            packed = np.zeros((5, k), dtype=np.int64)
-            packed[0, :n] = rows_l[lo:hi]
-            packed[1, :n] = slots_l[lo:hi]
-            packed[2, :n] = added_l[lo:hi]
-            packed[3, :n] = taken_l[lo:hi]
-            packed[4, :n] = elapsed_l[lo:hi]
-            with self._state_mu:
-                self.state = _jit_merge_packed()(
-                    self.state, jnp.asarray(packed)
-                )
-            self._ticks += 1
+            cols += [
+                (row, int(slot), int(lanes.added[slot]),
+                 int(lanes.taken[slot]), lanes.elapsed_ns)
+                for slot in slots
+            ]
+        step = MAX_MERGE_ROWS * self._commit_blocks
+        for lo in range(0, len(cols), step):
+            mat = np.array(cols[lo : lo + step], np.int64).T.copy()
+            self._apply(DeltaArrays(*mat, np.zeros(mat.shape[1], bool)), ())
         # All chunk joins have landed: the staged lanes are now fully
         # represented in the device planes, so drop the snapshot aliases.
         # (pop, not clear — an eviction may have already dropped some.)
@@ -3603,42 +3570,37 @@ class DeviceEngine:
         calls: List[tuple] = []
         take = _jit_take_packed(self.node_slot)
         calls += [(take, (jnp.zeros((8, k), i64),), 0) for k in pow2(8, MAX_TAKE_ROWS)]
-        merge = _jit_merge_packed()
-        calls += [(merge, (jnp.zeros((5, k), i64),), "all") for k in pow2(8, MAX_MERGE_ROWS)]
-        if jax.default_backend() != "cpu":
-            # The accelerator tick path commits through the FOLDED kernel
-            # (flags asserted) and the fold-to-dense row windows — CPU
-            # ticks never reach either.
-            folded = _jit_merge_packed_folded()
-            for k in pow2(8, MAX_MERGE_ROWS):
-                packed = np.zeros((6, k), np.int64)
-                packed[0] = _FOLD_PAD_ROW
-                packed[1] = np.arange(k)
-                packed[4] = _FOLD_PAD_ROW + np.arange(k)
-                calls.append((folded, (jnp.asarray(packed),), "all"))
-            dense = _jit_merge_rows_dense()
-            calls += [
+        # The merge tick: the folded scatter, the fold-to-dense row
+        # windows, and the coalesced commit ring (one variant per
+        # power-of-two block count the drain can coalesce).
+        folded = _jit_merge_folded()
+        for k in pow2(8, MAX_MERGE_ROWS):
+            packed = np.zeros((6, k), np.int64)
+            packed[0] = _FOLD_PAD_ROW
+            packed[1] = np.arange(k)
+            packed[4] = _FOLD_PAD_ROW + np.arange(k)
+            calls.append((folded, (jnp.asarray(packed),), "all"))
+        dense = _jit_merge_rows_dense()
+        calls += [
+            (
+                dense,
                 (
-                    dense,
-                    (
-                        jnp.full((k,), _FOLD_PAD_ROW, i64) + jnp.arange(k, dtype=i64),
-                        jnp.zeros((k, self.config.nodes, 2), i64),
-                        jnp.zeros((k,), i64),
-                    ),
-                    "all",
-                )
-                for k in pow2(8, MAX_ROW_DENSE)
-            ]
-            # Coalesced commit ring: one variant per power-of-two block
-            # count the drain can coalesce.
-            commit = _jit_commit_packed()
-            for j in pow2(2, self._commit_blocks):
-                warm = commit_mod.pack_commit_blocks(
-                    *(np.empty(0, np.int64) for _ in range(6)),
-                    MAX_MERGE_ROWS,
-                    out=np.empty((6, j, MAX_MERGE_ROWS), np.int64),
-                )
-                calls.append((commit, (jnp.asarray(warm),), "all"))
+                    jnp.full((k,), _FOLD_PAD_ROW, i64) + jnp.arange(k, dtype=i64),
+                    jnp.zeros((k, self.config.nodes, 2), i64),
+                    jnp.zeros((k,), i64),
+                ),
+                "all",
+            )
+            for k in pow2(8, MAX_ROW_DENSE)
+        ]
+        commit = _jit_commit_packed()
+        for j in pow2(2, self._commit_blocks):
+            warm = commit_mod.pack_commit_blocks(
+                *(np.empty(0, np.int64) for _ in range(6)),
+                MAX_MERGE_ROWS,
+                out=np.empty((6, j, MAX_MERGE_ROWS), np.int64),
+            )
+            calls.append((commit, (jnp.asarray(warm),), "all"))
         # Snapshot/introspection gathers (engine.read_rows pads to these).
         calls += [(read_rows, (jnp.zeros(k, jnp.int32),), None) for k in pow2(1, 1024)]
         # Lifecycle sweep probes: the GC cadence must never JIT mid-serve
@@ -4300,36 +4262,6 @@ class DeviceEngine:
         if scalar_subset is not None:
             self._apply_scalar_merges(scalar_subset)
 
-    def _select_merge_kernel(self) -> str:
-        """PATROL_MERGE_KERNEL, settled at engine start: "scatter" (XLA,
-        the default), "pallas" (the block-sparse kernel for every batch)
-        or "auto" (pallas for block-sparse batches). Pallas counts only
-        once the backend's compiler has accepted it for THIS engine's
-        state shape; "pallas" fails the start with the compiler's
-        message instead of quietly serving through the scatter, and
-        "auto" logs once what it fell back to and why."""
-        mode = os.environ.get("PATROL_MERGE_KERNEL", "scatter")
-        if mode not in ("scatter", "pallas", "auto"):
-            raise ValueError(f"PATROL_MERGE_KERNEL={mode!r}: not scatter|pallas|auto")
-        if mode == "scatter":
-            return mode
-        from patrol_tpu.ops import pallas_merge
-
-        err = pallas_merge.compile_error(self.state)
-        if err is None:
-            log.info("merge kernel selected", extra={"mode": mode, "kernel": "pallas"})
-            return mode
-        if mode == "pallas":
-            raise RuntimeError(
-                "PATROL_MERGE_KERNEL=pallas: the Pallas merge kernel does not "
-                f"compile for state {tuple(self.state.pn.shape)}: {err}"
-            )
-        log.warning(
-            "merge kernel selected",
-            extra={"mode": mode, "kernel": "scatter", "pallas_refused": err.splitlines()[0]},
-        )
-        return "scatter"
-
     def _apply_lane_merges(self, deltas: DeltaArrays) -> None:
         if not len(deltas):  # a zero-length chunk is a no-op tick
             return
@@ -4340,90 +4272,35 @@ class DeviceEngine:
         if len(deltas) > MAX_MERGE_ROWS:
             self._commit_coalesced(deltas)
             return
-        if self.merge_kernel != "scatter":
-            from patrol_tpu.ops import pallas_merge
-
-            use_pallas = self.merge_kernel == "pallas" or pallas_merge.auto_pick(
-                deltas.rows, self.config.buckets
-            )
-            if use_pallas:
-                t0 = time.perf_counter_ns()
-                with self._state_mu, _dispatch("merge_pallas"):
-                    self.state = pallas_merge.merge_batch_pallas(
-                        self.state,
-                        deltas.rows,
-                        deltas.slots,
-                        deltas.added_nt,
-                        deltas.taken_nt,
-                        deltas.elapsed_ns,
-                    )
-                self._observe_device_commit("merge_pallas", t0, len(deltas))
-                self._ticks += 1
-                return
-        # Tick-level fold default: ON for accelerator backends, where the
-        # scatter serializes per update and asserted-unique/sorted indices
-        # measured +28% (scripts/probe_scatter.py); OFF for CPU, where the
-        # scatter is already cheap and the fold's host work + extra jit
-        # variants measured as a straight loss on the 1-vCPU cluster bench
-        # (2,999 rps / p99 60 ms unfolded vs 2,675 rps / p99 337 ms
-        # folded, benchmarks/cluster_bench.py, r3). Scope: this is the
-        # single-device engine's merge tick only — MeshEngine overrides
-        # _apply with a fused shard_map step whose per-block routing
-        # (topology.route_requests) does not fold, so PATROL_TICK_FOLD has
-        # no effect there.
-        fold_default = "0" if jax.default_backend() == "cpu" else "1"
-        if os.environ.get("PATROL_TICK_FOLD", fold_default) != "0":
-            t0 = time.perf_counter_ns()
-            packed, dense = self._fold_hybrid(deltas)
-            _obs_stage(hist.STAGE_FOLD, t0, trace_mod.EV_FOLD, len(deltas))
-            # Stage the operands on device BEFORE the state lock: the
-            # H2D transfer then overlaps the previous tick's compute
-            # instead of serializing inside the jit call (device-commit
-            # pipeline; the fold buffers are freshly allocated per tick,
-            # so jax owns them until the async transfer completes).
-            t0 = time.perf_counter_ns()
-            dense_dev = (
-                tuple(jax.device_put(x) for x in dense)
-                if dense is not None
-                else None
-            )
-            packed_dev = (
-                jax.device_put(packed) if packed is not None else None
-            )
-            _obs_stage(hist.STAGE_H2D, t0, trace_mod.EV_H2D_PUT, len(deltas))
-            t0 = time.perf_counter_ns()
-            with self._state_mu, _dispatch("merge_folded"):
-                if dense_dev is not None:
-                    self.state = _jit_merge_rows_dense()(
-                        self.state, *dense_dev
-                    )
-                if packed_dev is not None:
-                    self.state = _jit_merge_packed_folded()(
-                        self.state, packed_dev
-                    )
-            _obs_stage(
-                hist.STAGE_DISPATCH, t0, trace_mod.EV_COMMIT_DISPATCH,
-                len(deltas),
-            )
-            self._observe_device_commit("merge_folded", t0, len(deltas))
-            self._ticks += 1
-            return
-        n = len(deltas)
-        k = _pad_size(n)
-        packed = np.zeros((5, k), dtype=np.int64)
-        packed[0, :n] = deltas.rows
-        packed[1, :n] = deltas.slots
-        packed[2, :n] = deltas.added_nt
-        packed[3, :n] = deltas.taken_nt
-        packed[4, :n] = deltas.elapsed_ns
+        # Fold on the host (sort by (row, slot), max-join duplicates): the
+        # device scatter then asserts unique+sorted indices, measured +28%
+        # on v5e where the scatter serializes per update
+        # (scripts/probe_scatter.py), and hot rows commit as row windows.
         t0 = time.perf_counter_ns()
-        packed_dev = jax.device_put(packed)  # staged ahead of the lock
-        _obs_stage(hist.STAGE_H2D, t0, trace_mod.EV_H2D_PUT, n)
+        packed, dense = self._fold_hybrid(deltas)
+        _obs_stage(hist.STAGE_FOLD, t0, trace_mod.EV_FOLD, len(deltas))
+        # Stage the operands on device BEFORE the state lock: the H2D
+        # transfer then overlaps the previous tick's compute instead of
+        # serializing inside the jit call (device-commit pipeline; the
+        # fold buffers are freshly allocated per tick, so jax owns them
+        # until the async transfer completes).
         t0 = time.perf_counter_ns()
-        with self._state_mu, _dispatch("merge_packed"):
-            self.state = _jit_merge_packed()(self.state, packed_dev)
-        _obs_stage(hist.STAGE_DISPATCH, t0, trace_mod.EV_COMMIT_DISPATCH, n)
-        self._observe_device_commit("merge_packed", t0, n)
+        dense_dev = (
+            tuple(jax.device_put(x) for x in dense) if dense is not None else None
+        )
+        packed_dev = jax.device_put(packed) if packed is not None else None
+        _obs_stage(hist.STAGE_H2D, t0, trace_mod.EV_H2D_PUT, len(deltas))
+        t0 = time.perf_counter_ns()
+        with self._state_mu, _dispatch("merge_folded"):
+            if dense_dev is not None:
+                self.state = _jit_merge_rows_dense()(self.state, *dense_dev)
+            if packed_dev is not None:
+                self.state = _jit_merge_folded()(self.state, packed_dev)
+        _obs_stage(
+            hist.STAGE_DISPATCH, t0, trace_mod.EV_COMMIT_DISPATCH,
+            len(deltas),
+        )
+        self._observe_device_commit("merge_folded", t0, len(deltas))
         self._ticks += 1
 
     def _commit_coalesced(self, deltas: DeltaArrays) -> None:
@@ -4452,7 +4329,7 @@ class DeviceEngine:
             _obs_stage(hist.STAGE_H2D, t0, trace_mod.EV_H2D_PUT, len(ur))
             t0 = time.perf_counter_ns()
             with self._state_mu, _dispatch("merge_folded"):
-                self.state = _jit_merge_packed_folded()(
+                self.state = _jit_merge_folded()(
                     self.state, packed_dev
                 )
             _obs_stage(

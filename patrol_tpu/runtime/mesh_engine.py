@@ -40,7 +40,6 @@ single-device device-commit pipeline inherited intact —
 from __future__ import annotations
 
 import logging
-import os
 import threading
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -61,7 +60,6 @@ from patrol_tpu.runtime.engine import (
     DeviceEngine,
     TakeTicket,
     _dispatch,
-    _jit_merge_packed,
     _jit_merge_scalar_packed,
     _obs_stage,
     _pad_size,
@@ -571,8 +569,8 @@ class MeshEngine(DeviceEngine):
     def _warm_calls(self) -> list:
         """The fused step at each padded block size — the full diagonal
         through MESH_WARM_MAX, which _apply never exceeds (denser ticks
-        split into sub-dispatches) — plus the promotion-drain merge
-        diagonal, the SCALAR-INTEROP diagonal (the deficit-attribution
+        split into sub-dispatches; the promotion drain rides it too) —
+        plus the SCALAR-INTEROP diagonal (the deficit-attribution
         kernel otherwise compiles on its first reference-peer batch per
         pad size, a multi-second p99 spike), and the introspection
         gathers. After warmup() runs these, no reachable serve-path shape
@@ -590,32 +588,21 @@ class MeshEngine(DeviceEngine):
                 0,
             ))
             size <<= 1
-        # The host-fast-path promotion drain (engine._drain_promotions)
-        # batches ALL pending rows' lanes into _jit_merge_packed chunks of
-        # up to MAX_MERGE_ROWS entries; a mass promotion (rx storm,
-        # checkpoint-restore flush_hosted) can reach any power-of-two pad
-        # size. The scalar-interop kernel chunks and pads the same way
-        # (all-zero batches: deficit attribution of zero against
-        # non-negative lanes is a no-op, so warmed state is untouched).
+        # The scalar-interop kernel chunks batches of up to
+        # MAX_MERGE_ROWS entries and pads each to a power of two (all-zero
+        # batches: deficit attribution of zero against non-negative lanes
+        # is a no-op, so warmed state is untouched).
         hi = _pad_size(MAX_MERGE_ROWS)
-        for fn in (_jit_merge_packed(), _jit_merge_scalar_packed()):
-            size = 8
-            while size <= hi:
-                calls.append((fn, (jnp.zeros((5, size), jnp.int64),), "all"))
-                size <<= 1
+        scalar = _jit_merge_scalar_packed()
+        size = 8
+        while size <= hi:
+            calls.append((scalar, (jnp.zeros((5, size), jnp.int64),), "all"))
+            size <<= 1
         size = 1
         while size <= 1024:
             calls.append((read_rows, (jnp.zeros(size, jnp.int32),), None))
             size <<= 1
         return calls
-
-    def _select_merge_kernel(self) -> str:
-        """The fused mesh step merges through its own XLA scatter; there
-        is no Pallas form of it to select."""
-        mode = os.environ.get("PATROL_MERGE_KERNEL", "scatter")
-        if mode == "pallas":
-            raise RuntimeError("PATROL_MERGE_KERNEL=pallas: the mesh step has no Pallas merge")
-        return "mesh step (xla scatter)"
 
     def _held_bytes(self) -> Dict[str, int]:
         """What each device holds of the state: the bytes of its

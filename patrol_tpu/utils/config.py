@@ -56,16 +56,9 @@ _DECLARED: Tuple[Knob, ...] = (
          "Max in-flight device dispatches before the engine awaits."),
     Knob("PATROL_DEVICE_TIMING", "1",
          "Record per-kernel device timings into patrol-scope (0 = off)."),
-    Knob("PATROL_MERGE_KERNEL", "scatter",
-         "Merge kernel select: scatter | auto | pallas (compiled at engine start)."),
-    Knob("PATROL_TICK_FOLD", "1",
-         "Fold deltas before the merge tick (default: 0 on cpu, 1 on "
-         "accelerators)."),
     Knob("PATROL_TAKE_FOLD", "1",
          "Hot-key take coalescing (0 = per-ticket replay; differential/"
          "debug)."),
-    Knob("PATROL_ROW_DENSE_MIN", "0",
-         "Min distinct rows before the row-dense merge path engages."),
     Knob("PATROL_FOLD_NATIVE_MAX_DISTINCT", "4096",
          "Native-fold cutover: max distinct buckets per fold batch."),
     # --- runtime/engine.py + hoststore.py: host fastpath ---------------
@@ -105,11 +98,6 @@ _DECLARED: Tuple[Knob, ...] = (
          "Budget fraction at which GC turns eager before shedding."),
     Knob("PATROL_AUDIT_WINDOW_MS", "5000",
          "patrol-audit consistency-window length on the engine side."),
-    # --- ops/pallas_merge.py -------------------------------------------
-    Knob("PATROL_PALLAS_MIN_BATCH", "1024",
-         "Min batch before the pallas merge is preferred under 'auto'."),
-    Knob("PATROL_PALLAS_BLOCK_FRAC", "0.25",
-         "VMEM fraction the pallas merge may claim per block."),
     # --- net/: replication planes --------------------------------------
     Knob("PATROL_RAW_INGEST", "1",
          "Device-resident decode+fold of raw delta datagrams (0 = host)."),

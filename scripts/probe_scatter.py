@@ -170,8 +170,7 @@ def main():
     # sorted unique sentinel-padded pairs → flagged scatter. The plain
     # elem3 above is the unfolded class the r4 bench measured; if the
     # flags buy a material win, the bench's scatter stage should measure
-    # THIS (it is what the engine dispatches for uniform batches on
-    # accelerator backends, PATROL_TICK_FOLD default 1).
+    # THIS (it is what the engine tick dispatches for uniform batches).
     from patrol_tpu.ops.merge import FoldedMergeBatch, merge_batch_folded
     from patrol_tpu.runtime.engine import DeltaArrays, DeviceEngine
 

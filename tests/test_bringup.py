@@ -71,40 +71,6 @@ def test_host_twin_degenerate_rates():
     assert host_refill_grant_nt(2**63 - 1, 1, 1) == 2**62  # clipped
 
 
-class TestMergeKernelSelection:
-    def _engine(self):
-        from patrol_tpu.runtime.engine import DeviceEngine
-
-        return DeviceEngine(LimiterConfig(buckets=1024, nodes=4))
-
-    def test_default_is_scatter(self, monkeypatch):
-        monkeypatch.delenv("PATROL_MERGE_KERNEL", raising=False)
-        eng = self._engine()
-        try:
-            assert eng.merge_kernel == "scatter"
-            assert eng.device_vars()["merge_kernel"] == "scatter"
-        finally:
-            eng.stop()
-
-    def test_pallas_fails_start_with_the_reason(self, monkeypatch):
-        monkeypatch.setenv("PATROL_MERGE_KERNEL", "pallas")
-        with pytest.raises(RuntimeError, match="PATROL_MERGE_KERNEL=pallas"):
-            self._engine()
-
-    def test_auto_falls_back_to_scatter_on_cpu(self, monkeypatch):
-        monkeypatch.setenv("PATROL_MERGE_KERNEL", "auto")
-        eng = self._engine()
-        try:
-            assert eng.merge_kernel == "scatter"
-        finally:
-            eng.stop()
-
-    def test_unknown_mode_is_an_error(self, monkeypatch):
-        monkeypatch.setenv("PATROL_MERGE_KERNEL", "fast")
-        with pytest.raises(ValueError):
-            self._engine()
-
-
 def test_compile_cache_dir(monkeypatch):
     from patrol_tpu.utils import compile_cache
 

@@ -558,13 +558,274 @@ class TestTPURepo:
         assert ok and remaining == 8
 
 
+def _deltas(rows, slots, added, taken, elapsed, scalar=None):
+    import numpy as np
+
+    from patrol_tpu.runtime.engine import DeltaArrays
+
+    rows = np.asarray(rows, np.int64)
+    return DeltaArrays(
+        rows=rows,
+        slots=np.asarray(slots, np.int64),
+        added_nt=np.asarray(added, np.int64),
+        taken_nt=np.asarray(taken, np.int64),
+        elapsed_ns=np.asarray(elapsed, np.int64),
+        scalar=np.zeros(len(rows), bool) if scalar is None else np.asarray(scalar, bool),
+    )
+
+
+def _random_deltas(seed, n, rows_hi, nodes, hi=10**15):
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    return _deltas(
+        rng.integers(0, rows_hi, n), rng.integers(0, nodes, n),
+        rng.integers(0, hi, n), rng.integers(0, hi, n), rng.integers(0, hi, n),
+    )
+
+
+def _as_batch(d):
+    import jax.numpy as jnp
+
+    from patrol_tpu.ops.merge import MergeBatch
+
+    return MergeBatch(
+        rows=jnp.asarray(d.rows, jnp.int32),
+        slots=jnp.asarray(d.slots, jnp.int32),
+        added_nt=jnp.asarray(d.added_nt),
+        taken_nt=jnp.asarray(d.taken_nt),
+        elapsed_ns=jnp.asarray(d.elapsed_ns),
+    )
+
+
+def _merge_reference(state, d):
+    """The sequential reference for one tick's deltas: the plain
+    scatter-max join of the lane deltas, then the deficit-attribution
+    merge of the scalar ones (the engine applies lanes first)."""
+    from patrol_tpu.ops.merge import merge_batch, merge_scalar_batch
+    from patrol_tpu.runtime.engine import DeltaArrays
+
+    lanes = DeltaArrays(*(a[~d.scalar] for a in d))
+    scalars = DeltaArrays(*(a[d.scalar] for a in d))
+    if len(lanes):
+        state = merge_batch(state, _as_batch(lanes))
+    if len(scalars):
+        state = merge_scalar_batch(state, _as_batch(scalars))
+    return state
+
+
+def _tick_cases():
+    """case → (config, the ticks' deltas, the tick kernels they must reach)."""
+    import numpy as np
+
+    from patrol_tpu.runtime.engine import MAX_MERGE_ROWS
+
+    folded, dense = "_jit_merge_folded", "_jit_merge_rows_dense"
+    commit, scalar = "_jit_commit_packed", "_jit_merge_scalar_packed"
+    big, small = (5 << 32) + 1, (4 << 32) + 0xFFFFFFFF
+    over = np.arange(MAX_MERGE_ROWS + 1)
+    wide = np.arange(3 * MAX_MERGE_ROWS + 5)
+    rng = np.random.default_rng(12)
+    hot = np.repeat([3, 7], 12)
+    sparse = 10 + np.arange(200) // 2
+    cases = {
+        f"random-{seed}": (
+            LimiterConfig(buckets=2048, nodes=8),
+            [_random_deltas(seed, 300, 2048, 8)], {folded},
+        )
+        for seed in (1, 2, 3)
+    }
+    cases.update({
+        "nonzero-state": (
+            LimiterConfig(buckets=1024, nodes=4),
+            [_random_deltas(9, 100, 1024, 4), _random_deltas(10, 150, 1024, 4)],
+            {folded},
+        ),
+        "duplicate-row-slot": (
+            LimiterConfig(buckets=512, nodes=4),
+            [_deltas([5, 5, 5], [2, 2, 2], [9, 3, 7], [1, 8, 2], [4, 4, 6])],
+            {folded},
+        ),
+        "beyond-2^32": (
+            LimiterConfig(buckets=512, nodes=2),
+            [_deltas([1, 1], [0, 0], [small, big], [big, small],
+                     [2**40 + 3, 2**40 + 2])],
+            {folded},
+        ),
+        "single-delta": (
+            LimiterConfig(buckets=512, nodes=2),
+            [_deltas([0], [1], [42], [0], [0])], {folded},
+        ),
+        "pad-straddle": (
+            LimiterConfig(buckets=256, nodes=4),
+            [_deltas(np.arange(17), np.ones(17), 7 + np.arange(17),
+                     np.arange(17), np.full(17, NANO))],
+            {folded},
+        ),
+        # A drain just over one block whose fold stays past it: the ring.
+        "over-one-block": (
+            LimiterConfig(buckets=4096, nodes=4),
+            [_deltas(over % 4096, over // 4096, over + 1, over, over)],
+            {commit},
+        ),
+        # A drain over one block that the fold collapses back into one.
+        "over-one-block-collapses": (
+            LimiterConfig(buckets=64, nodes=8),
+            [_random_deltas(4, MAX_MERGE_ROWS + 1, 64, 8)], {folded},
+        ),
+        "multi-block": (
+            LimiterConfig(buckets=8192, nodes=4),
+            [_deltas(wide % 8192, wide // 8192, 3 * wide, wide, wide)],
+            {commit},
+        ),
+        "hot-row-every-lane": (
+            LimiterConfig(buckets=64, nodes=16),
+            [_random_deltas(5, 256, 1, 16)], {dense},
+        ),
+        "dense-and-sparse": (
+            LimiterConfig(buckets=256, nodes=12),
+            [_deltas(
+                np.concatenate([hot, sparse]),
+                np.concatenate([np.tile(np.arange(12), 2), np.arange(200) % 2]),
+                rng.integers(0, 1 << 50, 224), rng.integers(0, 1 << 50, 224),
+                rng.integers(0, 1 << 50, 224),
+            )],
+            {dense, folded},
+        ),
+        # Past the native fold's batch floor: clustered rows, every lane.
+        "clustered-native-fold": (
+            LimiterConfig(buckets=128, nodes=16),
+            [_random_deltas(6, 4096, 40, 16)], {dense},
+        ),
+        # More dense-eligible rows than the row-window batch holds: the
+        # overflow rows ride the folded scatter.
+        "dense-cap-spill": (
+            LimiterConfig(buckets=1024, nodes=4),
+            [_deltas(np.repeat(np.arange(600), 4), np.tile(np.arange(4), 600),
+                     rng.integers(0, 1 << 50, 2400), rng.integers(0, 1 << 50, 2400),
+                     rng.integers(0, 1 << 50, 2400))],
+            {dense, folded},
+        ),
+        "empty-tick": (
+            LimiterConfig(buckets=64, nodes=4),
+            [_deltas([], [], [], [], [])], set(),
+        ),
+        "scalar-only": (
+            LimiterConfig(buckets=64, nodes=4),
+            [_deltas(np.arange(12), np.full(12, 2), np.full(12, 3 * NANO),
+                     np.full(12, NANO), np.full(12, NANO), scalar=np.ones(12))],
+            {scalar},
+        ),
+        "scalar-and-lane": (
+            LimiterConfig(buckets=64, nodes=4),
+            [_deltas(
+                np.concatenate([rng.integers(0, 64, 50), np.arange(10)]),
+                np.concatenate([rng.integers(0, 4, 50), np.ones(10)]),
+                np.concatenate([rng.integers(0, 1 << 40, 50), np.full(10, 5 * NANO)]),
+                np.concatenate([rng.integers(0, 1 << 40, 50), np.full(10, NANO)]),
+                np.concatenate([rng.integers(0, 1 << 40, 50), np.full(10, NANO)]),
+                scalar=np.arange(60) >= 50,
+            )],
+            {folded, scalar},
+        ),
+    })
+    return cases
+
+
+_TICK_CASES = _tick_cases()
+
+
 class TestTickFold:
     """The tick-level merge fold (engine._fold_lane_merges): sorts by
     (row, slot), max-joins duplicate keys, folds elapsed per row, and pads
     with unique out-of-bounds sentinel keys the scatter drops — the
     preparation that lets the device scatter assert unique+sorted indices
-    truthfully. CPU CI never takes this path by default (the fold is
-    gated to accelerator backends), so these tests force it."""
+    truthfully. Every backend's merge tick folds, so these run the tick
+    the chip runs and hold it to the sequential ``merge_batch`` join."""
+
+    @pytest.mark.parametrize(
+        "case", [*_TICK_CASES, "promote-device", "promote-mesh"]
+    )
+    def test_tick_matches_merge_batch(self, case, monkeypatch):
+        import numpy as np
+
+        from patrol_tpu.models.limiter import init_state
+        from patrol_tpu.runtime import engine as engine_mod
+
+        if case.startswith("promote-"):
+            self._promotion_matches_merge_batch(case == "promote-mesh")
+            return
+        cfg, ticks, kernels = _TICK_CASES[case]
+        reached = set()
+        for name in ("_jit_merge_folded", "_jit_merge_rows_dense",
+                     "_jit_commit_packed", "_jit_merge_scalar_packed"):
+            factory = getattr(engine_mod, name)
+
+            def spy(factory=factory, name=name):
+                reached.add(name)
+                return factory()
+
+            monkeypatch.setattr(engine_mod, name, spy)
+        eng = DeviceEngine(cfg, node_slot=0)
+        ref = init_state(cfg)
+        try:
+            for d in ticks:
+                eng._apply(d, ())
+                ref = _merge_reference(ref, d)
+            assert reached == kernels
+            assert np.array_equal(np.asarray(eng.state.pn), np.asarray(ref.pn))
+            assert np.array_equal(
+                np.asarray(eng.state.elapsed), np.asarray(ref.elapsed)
+            )
+        finally:
+            eng.stop()
+
+    @staticmethod
+    def _promotion_matches_merge_batch(mesh: bool) -> None:
+        """Host-resident rows promoted through the feeder's drain land
+        in the device planes as the join of their lanes, on each engine."""
+        import numpy as np
+
+        from patrol_tpu.models.limiter import init_state
+        from patrol_tpu.runtime.engine import HostLanes
+        from patrol_tpu.runtime.mesh_engine import MeshEngine
+
+        cfg = LimiterConfig(buckets=64, nodes=4)
+        eng = (
+            MeshEngine(cfg, replicas=2, node_slot=0)
+            if mesh
+            else DeviceEngine(cfg, node_slot=0)
+        )
+        rng = np.random.default_rng(21)
+        rows = [3, 9, 17, 40, 63]
+        lanes_of = {}
+        for i, row in enumerate(rows):
+            lanes = HostLanes(cfg.nodes)
+            if i < 3:
+                lanes.added[:] = rng.integers(0, 1 << 50, cfg.nodes)
+                lanes.taken[:] = rng.integers(0, 1 << 50, cfg.nodes)
+            lanes.elapsed_ns = 0 if i == 4 else int(rng.integers(1, 1 << 40))
+            lanes_of[row] = lanes
+        try:
+            with eng._host_mu:
+                for row, lanes in lanes_of.items():
+                    eng._hosted[row] = lanes
+                    eng._hosted_flag[row] = True
+            assert eng.flush_hosted(timeout=60) == len(rows)
+            ref = _merge_reference(init_state(cfg), _deltas(
+                np.repeat(rows, cfg.nodes),
+                np.tile(np.arange(cfg.nodes), len(rows)),
+                np.concatenate([lanes_of[r].added for r in rows]),
+                np.concatenate([lanes_of[r].taken for r in rows]),
+                np.repeat([lanes_of[r].elapsed_ns for r in rows], cfg.nodes),
+            ))
+            assert np.array_equal(np.asarray(eng.state.pn), np.asarray(ref.pn))
+            assert np.array_equal(
+                np.asarray(eng.state.elapsed), np.asarray(ref.elapsed)
+            )
+            assert not eng._promoting and not eng._hosted_flag.any()
+        finally:
+            eng.stop()
 
     def test_fold_matches_unfolded_join(self):
         import numpy as np
@@ -807,12 +1068,7 @@ class TestTickFold:
             np.asarray(before.elapsed), np.asarray(after.elapsed)
         )
 
-    def test_engine_forced_fold_end_to_end(self, monkeypatch):
-        import numpy as np
-
-        from patrol_tpu.runtime.engine import DeviceEngine
-
-        monkeypatch.setenv("PATROL_TICK_FOLD", "1")
+    def test_engine_forced_fold_end_to_end(self):
         eng = DeviceEngine(LimiterConfig(buckets=32, nodes=4), node_slot=0)
         try:
             # Duplicate (row, slot) deltas across separate ingests land in
@@ -835,13 +1091,14 @@ class TestTickFold:
         finally:
             eng.stop()
 
-    def test_folded_and_unfolded_engines_reach_identical_state(self, monkeypatch):
-        """Same delta stream through a fold-forced engine and a fold-off
-        engine must produce bit-identical device state: the fold is pure
-        batch preparation, never semantics."""
+    def test_folded_and_unfolded_engines_reach_identical_state(self):
+        """A delta stream through the engine's folded tick reaches the
+        state the unfolded sequential join reaches (``merge_batch`` per
+        ingest batch, in order): the fold is pure batch preparation,
+        never semantics."""
         import numpy as np
 
-        from patrol_tpu.runtime.engine import DeviceEngine
+        from patrol_tpu.models.limiter import init_state
 
         rng = np.random.default_rng(9)
         streams = []
@@ -857,24 +1114,27 @@ class TestTickFold:
                 )
             )
 
-        states = {}
-        for fold in ("0", "1"):
-            monkeypatch.setenv("PATROL_TICK_FOLD", fold)
-            eng = DeviceEngine(LimiterConfig(buckets=32, nodes=4), node_slot=0)
-            try:
-                for names, slots, a, t, e in streams:
-                    eng.ingest_deltas_batch(
-                        names, slots.astype(np.int64), a.copy(), t.copy(), e.copy()
-                    )
-                assert eng.flush(timeout=30)
-                rows = [eng.directory.lookup(f"b{i}") for i in range(12)]
-                live = [r for r in rows if r is not None]
-                pn, el = eng.read_rows(live)
-                states[fold] = (pn.copy(), el.copy())
-            finally:
-                eng.stop()
-        assert np.array_equal(states["0"][0], states["1"][0])
-        assert np.array_equal(states["0"][1], states["1"][1])
+        cfg = LimiterConfig(buckets=32, nodes=4)
+        eng = DeviceEngine(cfg, node_slot=0)
+        try:
+            for names, slots, a, t, e in streams:
+                eng.ingest_deltas_batch(
+                    names, slots.astype(np.int64), a.copy(), t.copy(), e.copy()
+                )
+            assert eng.flush(timeout=30)
+            ref = init_state(cfg)
+            for names, slots, a, t, e in streams:
+                rows = [eng.directory.lookup(nm) for nm in names]
+                ref = _merge_reference(ref, _deltas(rows, slots, a, t, e))
+            live = [
+                r for r in (eng.directory.lookup(f"b{i}") for i in range(12))
+                if r is not None
+            ]
+            pn, el = eng.read_rows(live)
+            assert np.array_equal(pn, np.asarray(ref.pn)[live])
+            assert np.array_equal(el, np.asarray(ref.elapsed)[live])
+        finally:
+            eng.stop()
 
 
 class TestScalarMergeChunking:
@@ -1004,14 +1264,13 @@ class TestFoldHybrid:
             np.asarray(ref.elapsed), np.asarray(got.elapsed)
         ), shape
 
-    def test_engine_tick_with_forced_fold_uses_hybrid(self, monkeypatch):
-        """End-to-end through _apply_lane_merges with the fold forced on
-        (CPU default is off): a hot-key tick must land correctly."""
+    def test_engine_tick_with_forced_fold_uses_hybrid(self):
+        """End-to-end through _apply_lane_merges: a hot-key tick must
+        land correctly."""
         import numpy as np
 
         from patrol_tpu.runtime.engine import DeltaArrays
 
-        monkeypatch.setenv("PATROL_TICK_FOLD", "1")
         eng = DeviceEngine(LimiterConfig(buckets=32, nodes=8), node_slot=0)
         try:
             n = 256

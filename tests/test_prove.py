@@ -300,14 +300,6 @@ class TestMutationGate:
         assert "PTP004" in codes(f)
 
 
-# --- pallas interpret path -------------------------------------------------
-
-
-class TestPallasModel:
-    def test_shipped_pallas_merge_is_silent(self):
-        assert prove.prove_root(ROOTS["merge_batch_pallas"]) == []
-
-
 # --- suppression + drivers -------------------------------------------------
 
 
@@ -362,7 +354,6 @@ class TestRepoIsProven:
             "ops.wire.codec",
             "ops.wire.delta_codec",
             "ops.delta.delta_fold",
-            "ops.pallas_merge.merge_batch_pallas",
         ):
             assert required in names, required
         full = set(ROOTS["merge_batch"].obligations)
@@ -603,19 +594,6 @@ class TestRawIngestObligations:
         # Structural taint (scatter-add on a merged plane), decoder
         # disagreement, and duplicated-plane idempotence all fire.
         assert "PTP001" in got and "PTP003" in got
-
-    def test_pallas_twin_matches_xla_on_the_model_corpus(self):
-        """The pallas_call twin runs the same model suite clean (the
-        interpret path — the shape a future Mosaic lowering fills in)."""
-        from patrol_tpu.ops import ingest as ingest_ops
-
-        f = prove.prove_root(
-            ROOTS["decode_fold_raw"],
-            fn=lambda *a: ingest_ops.decode_fold_raw_pallas(*a, interpret=True),
-        )
-        # The tracer can't trace through pallas_call aliasing on every
-        # backend; the model findings are what we pin here.
-        assert [x for x in f if x.check in ("PTP002", "PTP003", "PTP004")] == []
 
 
 def tail_dropping_tree_reduce(pn, elapsed):

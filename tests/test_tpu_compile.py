@@ -93,17 +93,14 @@ def test_take_packed(one_chip):
     assert "f64" not in compiled.as_text()
 
 
-@pytest.mark.parametrize(
-    "factory,rows",
-    [("_jit_merge_packed", 5), ("_jit_merge_packed_folded", 6)],
-)
-def test_merge_packed(one_chip, factory, rows):
-    from patrol_tpu.runtime import engine
+def test_merge_packed(one_chip):
+    from patrol_tpu.runtime.engine import _jit_merge_folded
 
-    jitted = getattr(engine, factory)()
-    compiled = jitted.lower(
-        state_spec(one_chip), spec((rows, K_MERGE), jnp.int64, one_chip)
-    ).compile()
+    compiled = (
+        _jit_merge_folded()
+        .lower(state_spec(one_chip), spec((6, K_MERGE), jnp.int64, one_chip))
+        .compile()
+    )
     fits(compiled)
 
 
@@ -179,18 +176,3 @@ def test_mesh_step_four_chips(topo, replicas, buckets):
     # Each device's arguments are its shard of the state, not all of it.
     share = buckets // shards * (N * 2 + 1) * 8
     assert compiled.memory_analysis().argument_size_in_bytes < 1.01 * share
-
-
-def test_pallas_merge_refused_for_v5e(one_chip):
-    """Mosaic refuses the Pallas merge at the served shape — the reason
-    PATROL_MERGE_KERNEL=pallas fails the engine start. When a Mosaic
-    accepts it, this fails: time to measure it against the scatter."""
-    from patrol_tpu.ops.pallas_merge import _merge_pallas_device
-
-    i32 = jnp.int32
-    with pytest.raises(Exception, match="cannot statically prove"):
-        _merge_pallas_device.lower(
-            state_spec(one_chip),
-            *(spec((1,), i32, one_chip) for _ in range(5)),
-            *(spec((1, 2), i32, one_chip) for _ in range(3)),
-        ).compile()
