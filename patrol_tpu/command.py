@@ -194,6 +194,7 @@ class Command:
             from patrol_tpu.utils import histogram as hist_mod
             from patrol_tpu.utils import profiling
 
+            threads = profiling.thread_cpu_ns()
             return {
                 "engine_ticks": engine.ticks,
                 "engine_evictions": engine.evictions,
@@ -214,6 +215,11 @@ class Command:
                 # patrol.clock anchor; its difference over a window is
                 # the window's length on the node.
                 "mono_ns": time.monotonic_ns(),
+                # CPU ns of every live Python thread by name, and their
+                # sum: a difference over a window, against mono_ns's, is
+                # a thread's share of a core.
+                "thread_cpu_ns": threads,
+                "py_threads_cpu_ns": sum(threads.values()),
                 # Bucket lifecycle (idle-bucket GC + memory budget):
                 # live gauges — reclaim/shed/compaction counts, bytes in
                 # use vs budget, tombstones, pressure level.

@@ -158,6 +158,27 @@ def _dispatch(kernel: str):
     return trace_mod.TRACE.span(_KERNEL_EVENTS[kernel])
 
 
+def _record_take_stages(
+    n: int, t_drain: int, t_ask: int, t_locked: int, t_ret: int,
+    t_thunk: int, done_ns: int,
+) -> None:
+    """Record a device take tick's stages, one sample for each of the
+    ``n`` tickets whose queue wait its drain (``t_drain``) closed. With
+    ``take_queue_ns`` they tile each such ticket's ``take_service_ns``
+    exactly: drain → the state lock asked for (prep: the tick's demotion,
+    GC, re-route, promotions and merges, then grouping, packing and the
+    ``device_put``) → held (lock wait) → the dispatch returned (dispatch)
+    → the completer ran the tick's thunk (hand-off: the lock's release,
+    the completion queue's back-pressure and the completer's wake) →
+    ``done_ns`` (readback: the result's device wait and D2H copy, the
+    staging release)."""
+    hist.TAKE_PREP.record_n(t_ask - t_drain, n)
+    hist.TAKE_LOCK_WAIT.record_n(t_locked - t_ask, n)
+    hist.TAKE_DISPATCH.record_n(t_ret - t_locked, n)
+    hist.TAKE_HANDOFF.record_n(t_thunk - t_ret, n)
+    hist.TAKE_READBACK.record_n(done_ns - t_thunk, n)
+
+
 BroadcastFn = Callable[[List[wire.WireState]], None]
 
 
@@ -488,6 +509,7 @@ class TakeTicket:
         "shed",
         "t0_ns",
         "done_ns",
+        "drain_ns",
         "batch",
         "trace_id",
         "sink",
@@ -524,6 +546,11 @@ class TakeTicket:
         # unsampled).
         self.t0_ns = time.perf_counter_ns()
         self.done_ns = 0
+        # The feeder drain that closed its queue wait (take_queue_ns) and
+        # opened the take stages (take_prep_ns ... take_readback_ns): 0
+        # before it, and for a deferred ticket's later drain, which tiles
+        # nothing.
+        self.drain_ns = 0
         self.batch = batch
         self.trace_id = trace_mod.sample_take()
         # Where a native-front take's answer goes: the front's answer
@@ -3913,19 +3940,24 @@ class DeviceEngine:
                     MAX_MERGE_ROWS * self._commit_blocks
                 )
                 tickets = self._drain_takes(MAX_TAKE_ROWS)
+                # The tick's busy time and CPU run from here to its end
+                # (feeder_busy_ns, feeder_cpu_ns).
                 t_drain = time.perf_counter_ns()
+                cpu_drain = time.thread_time_ns()
                 # Clear the re-queue marker at drain time, not in
                 # _group_tickets: if the tick dies before grouping runs, a
                 # stale True from a prior tick would make _fail_tickets skip
                 # the ticket and hang its caller while leaking the row pin.
                 # A ticket drained for the first time closes its queue
-                # wait (take_queue_ns).
+                # wait (take_queue_ns) and opens its take stages.
                 queued_t0 = []
                 oldest = 0
                 for t in tickets:
                     if t.deferred:
                         t.deferred = False
+                        t.drain_ns = 0
                     else:
+                        t.drain_ns = t_drain
                         queued_t0.append(t.t0_ns)
                     if t.batch and (not oldest or t.batch < oldest):
                         oldest = t.batch
@@ -4004,6 +4036,12 @@ class DeviceEngine:
                     self.directory.unpin_rows(deltas.rows)
                 with self._cond:
                     self._busy = False
+                profiling.COUNTERS.inc(
+                    "feeder_busy_ns", time.perf_counter_ns() - t_drain
+                )
+                profiling.COUNTERS.inc(
+                    "feeder_cpu_ns", time.thread_time_ns() - cpu_drain
+                )
                 tick.end()
 
     @staticmethod
@@ -4162,13 +4200,17 @@ class DeviceEngine:
 
     def _complete_groups(
         self, keys, groups, have, admitted, own_a, own_t, elapsed, sum_a, sum_t
-    ) -> None:
+    ) -> Tuple[int, int]:
         """Fan per-group kernel results out to tickets + broadcast hook.
         Completion releases each ticket's directory pin. Every ticket
         completes, and the native front's answers among them are handed
-        back, before the tick's audit notes and broadcasts are built."""
+        back, before the tick's audit notes and broadcasts are built.
+        → (the completion stamp, how many tickets completed here had
+        their queue wait closed by this tick's drain: those whose take
+        stages tile their ``take_service_ns``)."""
         broadcasts: List[wire.WireState] = []
         unpin: List[int] = []
+        drained = 0
         done_ns = time.perf_counter_ns()
         now_clock = self.clock()
         take_hist = self._take_service
@@ -4192,6 +4234,8 @@ class DeviceEngine:
                 if t.complete(remaining, ok):
                     unpin.append(t.row)
                     take_hist.record(done_ns - t.t0_ns)
+                    if t.drain_ns:
+                        drained += 1
                     if t.trace_id:
                         trace_mod.SPANS.add(
                             t.trace_id, self.node_slot, "take", t.name,
@@ -4240,6 +4284,7 @@ class DeviceEngine:
         if unpin:
             self.directory.unpin_rows(unpin)
         self._emit_broadcasts(broadcasts)
+        return done_ns, drained
 
     def _apply_merges(self, deltas: DeltaArrays) -> None:
         # Scalar-semantics (reference-peer) deltas go through the
@@ -4574,26 +4619,33 @@ class DeviceEngine:
         t0 = time.perf_counter_ns()
         packed_dev = jax.device_put(packed)  # staged ahead of the lock
         _obs_stage(hist.STAGE_H2D, t0, trace_mod.EV_H2D_PUT, len(keys))
-        t0 = time.perf_counter_ns()
-        with self._state_mu, _dispatch("take_packed"):
-            self.state, out = _jit_take_packed(self.node_slot)(
-                self.state, packed_dev
-            )
+        # The take stages' stamps: the tick's drain (every ticket drained
+        # here for the first time carries it), the state lock asked for
+        # (t_ask) and held (t_locked), the dispatch returned (t_ret).
+        t_drain = max(t.drain_ns for t in tickets)
+        t_ask = time.perf_counter_ns()
+        with self._state_mu:
+            t_locked = time.perf_counter_ns()
+            with _dispatch("take_packed"):
+                self.state, out = _jit_take_packed(self.node_slot)(
+                    self.state, packed_dev
+                )
+            t_ret = time.perf_counter_ns()
         _obs_stage(
-            hist.STAGE_DISPATCH, t0, trace_mod.EV_COMMIT_DISPATCH, len(keys)
+            hist.STAGE_DISPATCH, t_ask, trace_mod.EV_COMMIT_DISPATCH, len(keys)
         )
         self._ticks += 1
-        t_dispatch = t0
         n_keys = len(keys)
 
         def complete() -> None:
+            t_thunk = time.perf_counter_ns()
             # THE sanctioned completer readback: one batched D2H per
             # take tick, on the completion pipeline by construction.
             res = np.asarray(out)  # patrol-lint: disable=PTD003
             if DEVICE_TIMING:
                 # Device-side take duration: dispatch → results readable
                 # (the completion-pipeline readback delta, patrol-fleet).
-                dur = time.perf_counter_ns() - t_dispatch
+                dur = time.perf_counter_ns() - t_ask
                 hist.STAGE_DEVICE_TAKE.record(dur)
                 hist.kernel_histogram("take_packed").record(dur)
                 tr = trace_mod.TRACE
@@ -4603,8 +4655,12 @@ class DeviceEngine:
             # backend: recycle it.
             self._staging.release(packed)
             have, admitted, own_a, own_t, elapsed, sum_a, sum_t = res
-            self._complete_groups(
+            done_ns, drained = self._complete_groups(
                 keys, groups, have, admitted, own_a, own_t, elapsed, sum_a, sum_t
             )
+            if drained:
+                _record_take_stages(
+                    drained, t_drain, t_ask, t_locked, t_ret, t_thunk, done_ns
+                )
 
         self._enqueue_completion(complete, keys, groups)

@@ -93,6 +93,14 @@ class LatticeHistogram:
                 total += v
             self._sums[self.node_slot] += total
 
+    def record_n(self, value: int, n: int) -> None:
+        """:meth:`record` ``value`` ``n`` times, in constant time: one
+        tick's stage, shared by each of its ``n`` tickets."""
+        v = max(int(value), 0)
+        with self._mu:
+            self._counts[self.node_slot][bucket_of(v)] += n
+            self._sums[self.node_slot] += v * n
+
     # -- lattice -------------------------------------------------------------
 
     def _grow(self, nodes: int) -> None:
@@ -255,6 +263,17 @@ FRONT_QUEUE = HISTOGRAMS.get("http_front_queue_ns")
 FRONT_SUBMIT = HISTOGRAMS.get("http_front_submit_ns")
 FRONT_ANSWER = HISTOGRAMS.get("http_front_answer_ns")
 TAKE_QUEUE = HISTOGRAMS.get("take_queue_ns")
+# The rest of a device take ticket's take_service_ns, one sample per
+# ticket of a DeviceEngine take tick (runtime/engine.py
+# _record_take_stages): feeder drain → state lock asked for (prep) → held
+# (lock wait) → dispatch returned (dispatch) → completer thunk started
+# (hand-off) → complete (readback). take_queue_ns plus these five is the
+# ticket's take_service_ns, exactly.
+TAKE_PREP = HISTOGRAMS.get("take_prep_ns")
+TAKE_LOCK_WAIT = HISTOGRAMS.get("take_lock_wait_ns")
+TAKE_DISPATCH = HISTOGRAMS.get("take_dispatch_ns")
+TAKE_HANDOFF = HISTOGRAMS.get("take_handoff_ns")
+TAKE_READBACK = HISTOGRAMS.get("take_readback_ns")
 # Device-side stage histograms (patrol-fleet, ROADMAP item 1's r06
 # capture): dispatch→ready wall time of the engine's commit and take
 # kernels, measured on the completion pipeline (block_until_ready /

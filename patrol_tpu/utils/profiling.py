@@ -360,6 +360,10 @@ class CounterRegistry:
         # listener (count_jax_compiles): 0 in a warm serving window.
         "jit_compiles",
         "jit_compile_ns",
+        # The engine feeder's ticks (runtime/engine.py _run_loop): wall
+        # ns and the thread's CPU ns from each drain to the tick's end.
+        "feeder_busy_ns",
+        "feeder_cpu_ns",
     )
 
     def __init__(self):
@@ -489,6 +493,32 @@ class ProfiledCondition:
 
     def __exit__(self, *exc):
         return self._cond.__exit__(*exc)
+
+
+def _thread_cpu_clock(native_id: int) -> int:
+    """The CPU-time clock of the thread with kernel id ``native_id``:
+    Linux's encoding of a per-thread CPU clock (MAKE_THREAD_CPUCLOCK(tid,
+    CPUCLOCK_SCHED)), what ``pthread_getcpuclockid`` returns. Built from
+    the kernel id, a thread that has just exited reads as an error, where
+    ``pthread_getcpuclockid`` would dereference its freed descriptor."""
+    return ((~native_id) << 3) | 6
+
+
+def thread_cpu_ns() -> Dict[str, int]:
+    """CPU ns each live ``threading`` thread has used, by thread name
+    (threads sharing a name are summed; one that exits mid-read is left
+    out). One clock read a thread: for ``/debug/vars``, never a serving
+    path. Native threads that Python did not start are not listed."""
+    out: Dict[str, int] = {}
+    for t in threading.enumerate():
+        if t.native_id is None:
+            continue
+        try:
+            ns = time.clock_gettime_ns(_thread_cpu_clock(t.native_id))
+        except OSError:
+            continue
+        out[t.name] = out.get(t.name, 0) + ns
+    return out
 
 
 def thread_dump() -> str:

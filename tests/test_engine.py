@@ -1291,3 +1291,198 @@ class TestFoldHybrid:
                     assert int(pn[0, s, 0]) == int(deltas.added_nt[sel].max())
         finally:
             eng.stop()
+
+
+class _SpyHist:
+    """A stage histogram's stand-in that keeps each record call's
+    samples: one ``record_n`` call per tick."""
+
+    def __init__(self):
+        self.calls = []
+
+    def record(self, value):
+        self.calls.append([int(value)])
+
+    def record_many(self, values):
+        self.calls.append([int(v) for v in values])
+
+    def record_n(self, value, n):
+        self.calls.append([int(value)] * n)
+
+    @property
+    def samples(self):
+        return [v for call in self.calls for v in call]
+
+
+STAGES = ("TAKE_PREP", "TAKE_LOCK_WAIT", "TAKE_DISPATCH", "TAKE_HANDOFF", "TAKE_READBACK")
+
+
+@pytest.fixture
+def staged(monkeypatch):
+    """An engine on an injected clock whose takes all ride the device
+    queue, with spies for take_service_ns, take_queue_ns and the five
+    take stages; → (engine, {attr: spy})."""
+    from patrol_tpu.runtime import engine as engine_mod
+    from patrol_tpu.utils import histogram as hist
+
+    monkeypatch.setattr(engine_mod, "HOST_FASTPATH", False)
+    spies = {a: _SpyHist() for a in ("TAKE_SERVICE", "TAKE_QUEUE") + STAGES}
+    for attr, spy in spies.items():
+        monkeypatch.setattr(hist, attr, spy)
+    eng = DeviceEngine(CFG, node_slot=0, clock=FakeClock())
+    yield eng, spies
+    eng.stop()
+
+
+def _paused(eng, submit):
+    """Run ``submit`` with the feeder paused, so one drain takes all it
+    queues; → what it returned."""
+    with eng._cond:
+        eng._tick_paused = True
+    try:
+        return submit()
+    finally:
+        with eng._cond:
+            eng._tick_paused = False
+            eng._cond.notify_all()
+
+
+class TestTakeStages:
+    """A device take ticket's take_service_ns tiled on the feeder and the
+    completer: take_queue_ns (ticket → drain), then prep, lock wait,
+    dispatch, hand-off and readback (drain → complete)."""
+
+    def test_six_stages_sum_to_each_tickets_service_exactly(self, staged):
+        eng, spies = staged
+        tickets = []
+        for _ in range(3):  # three ticks of many tickets, one a group of 3
+            names = [f"tile{i}" for i in range(9)] + ["tile0", "tile0"]
+            res = _paused(
+                eng, lambda: eng.submit_takes_batch(names, [RATE] * 11, [1] * 11)
+            )
+            tickets += [t for t, _ in res]
+            for t in tickets:
+                assert t.wait(10)
+        for i in range(4):  # and four ticks of one ticket
+            tickets.append(eng.submit_take(f"solo{i}", RATE, 1)[0])
+            assert tickets[-1].wait(10)
+        assert eng.flush()
+        n = len(tickets)
+        for attr in ("TAKE_SERVICE", "TAKE_QUEUE") + STAGES:
+            assert len(spies[attr].samples) == n, attr
+        # Per tick (ordered by its drain stamp), every ticket's five
+        # stages sum to drain → complete, and the queue wait before it
+        # makes the ticket's take_service_ns sample.
+        drains = sorted({t.drain_ns for t in tickets})
+        assert 0 not in drains and len(drains) == 7
+        for attr in STAGES:
+            assert len(spies[attr].calls) == len(drains), attr
+        for k, d in enumerate(drains):
+            mine = [t for t in tickets if t.drain_ns == d]
+            stages = [spies[a].calls[k] for a in STAGES]
+            assert all(len(s) == len(mine) for s in stages)
+            for i, t in enumerate(mine):
+                per = [s[i] for s in stages]
+                assert all(v >= 0 for v in per), per
+                assert sum(per) == t.done_ns - t.drain_ns
+        services = sorted(spies["TAKE_SERVICE"].samples)
+        assert services == sorted(t.done_ns - t.t0_ns for t in tickets)
+        assert sorted(spies["TAKE_QUEUE"].samples) == sorted(
+            t.drain_ns - t.t0_ns for t in tickets
+        )
+        tiled = sum(sum(spies[a].samples) for a in ("TAKE_QUEUE",) + STAGES)
+        assert tiled == sum(services)
+
+    def test_state_lock_held_elsewhere_is_lock_wait(self, staged):
+        eng, spies = staged
+        eng.take("warm", RATE, 1)  # compile the take tick first
+        real = eng._state_mu
+
+        class Watched:
+            """The state lock, telling when the feeder asks for it."""
+
+            def __init__(self):
+                self.asked = threading.Event()
+
+            def acquire(self, *a):
+                if threading.current_thread().name == "patrol-engine":
+                    self.asked.set()
+                return real.acquire(*a)
+
+            def release(self):
+                real.release()
+
+            def __enter__(self):
+                self.acquire()
+
+            def __exit__(self, *exc):
+                self.release()
+
+        watched = eng._state_mu = Watched()
+        held = threading.Event()
+
+        def holder():
+            with real:
+                held.set()
+                assert watched.asked.wait(10)
+                time.sleep(0.05)
+
+        th = threading.Thread(target=holder)
+        th.start()
+        try:
+            assert held.wait(10)
+            ticket, _ = eng.submit_take("held", RATE, 1)
+            assert ticket.wait(10)
+        finally:
+            th.join(10)
+        assert eng.flush()  # the stages are recorded after the tickets complete
+        waits = spies["TAKE_LOCK_WAIT"].calls[-1]
+        assert waits[0] >= 50_000_000
+        stages = sum(spies[a].calls[-1][0] for a in STAGES)
+        assert stages == ticket.done_ns - ticket.drain_ns
+        assert spies["TAKE_QUEUE"].samples[-1] + stages == (
+            spies["TAKE_SERVICE"].samples[-1]
+        )
+
+    def test_deferred_and_rerouted_tickets_record_no_stage(
+        self, staged, monkeypatch
+    ):
+        from patrol_tpu.runtime import engine as engine_mod
+
+        eng, spies = staged
+        # Two rates on one bucket in one drain: the second defers to the
+        # next tick, whose drain is its second.
+        first, later = _paused(
+            eng,
+            lambda: [
+                eng.submit_take("defer", RATE, 1)[0],
+                eng.submit_take("defer", Rate(freq=20, per_ns=NANO), 1)[0],
+            ],
+        )
+        assert first.wait(10) and later.wait(10)
+        assert eng.flush()
+        assert first.drain_ns and not later.drain_ns
+        assert len(spies["TAKE_SERVICE"].samples) == 2
+        assert len(spies["TAKE_QUEUE"].samples) == 2  # both drained once
+        for attr in STAGES:
+            assert len(spies[attr].samples) == 1, attr
+        # Queued for the device, then its row turns host-resident before
+        # the drain: the feeder serves it from the host model.
+
+        def reroute():
+            t = eng.submit_take("reroute", RATE, 1)[0]
+            row = eng.directory.lookup("reroute")
+            with eng._host_mu:
+                eng._hosted[row] = engine_mod.HostLanes(eng.config.nodes)
+                eng._hosted_flag[row] = True
+            monkeypatch.setattr(engine_mod, "HOST_FASTPATH", True)
+            return t
+
+        ticket = _paused(eng, reroute)
+        assert ticket.wait(10) and ticket.ok
+        assert eng.flush()
+        assert eng.host_takes == 1
+        assert len(spies["TAKE_SERVICE"].samples) == 3
+        assert len(spies["TAKE_QUEUE"].samples) == 3
+        for attr in STAGES:
+            assert len(spies[attr].samples) == 1, attr

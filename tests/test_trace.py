@@ -553,6 +553,14 @@ class TestSpans:
         many.record_many(np.array([], np.int64))
         assert one.to_lattice() == many.to_lattice()
 
+    @pytest.mark.parametrize("value, n", [(0, 3), (1023, 13), (2**62, 2), (-5, 4), (7, 0)])
+    def test_record_n_matches_record(self, value, n):
+        one, shared = LatticeHistogram("a"), LatticeHistogram("a")
+        for _ in range(n):
+            one.record(value)
+        shared.record_n(value, n)
+        assert one.to_lattice() == shared.to_lattice()
+
 
 def _capture_with(tmp_path, work):
     """Run ``work()`` inside a CPU ``jax_trace`` capture (from another
